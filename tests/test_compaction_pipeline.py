@@ -282,11 +282,11 @@ def test_prewarm_buckets_compiles_and_marks_seen():
     # prewarm the exact bucket this staging produced (staging records no
     # bucket; only launches do) — the real launch below must then be the
     # bucket's second sighting, i.e. a hit
-    n = run_merge.prewarm_buckets(
+    pw = run_merge.prewarm_buckets(
         [(staged.k_pad, staged.m, staged.w, staged.n_cmp)])
     # both is_major variants of the one merge shape, plus the chained
     # write-through programs (survivor scan, span gather, restage concat)
-    assert n == 5
+    assert pw.compiled == 5 and pw.failed == []
     before = hits.value()
     run_merge.merge_and_gc_runs(runs, GCParams(CUTOFF, True, False),
                                 staged=staged)
@@ -304,6 +304,50 @@ def test_prewarm_maintenance_op_one_shot():
     s2 = MaintenanceOpStats()
     op.update_stats(s2)
     assert not s2.runnable, "prewarm op must be one-shot"
+
+
+def test_prewarm_op_does_not_mark_refused_shapes(monkeypatch):
+    """A shape whose compile the compiler refuses stays COLD on the
+    board and is named in op.failed; its neighbour is marked warmed."""
+    from yugabyte_tpu.storage import bucket_health
+    from yugabyte_tpu.tserver.maintenance_manager import PrewarmKernelsOp
+    board = bucket_health.health_board()
+    board.reset()
+    real = run_merge._gather_staged_output.lower
+
+    class _Refusing:
+        def lower(self, *a, n_out_pad, **kw):
+            if n_out_pad == 1024:
+                raise RuntimeError("compiler refused")
+            return real(*a, n_out_pad=n_out_pad, **kw)
+
+    monkeypatch.setattr(run_merge, "_gather_staged_output", _Refusing())
+    op = PrewarmKernelsOp(shapes=[(2, 512, 4, 8), (2, 1024, 4, 8)],
+                          enabled_fn=lambda: True)
+    try:
+        op.perform()
+        assert op.done and len(op.failed) == 1 and "1024" in op.failed[0]
+        assert board.state("run_merge_fused", (2, 512)) == \
+            bucket_health.WARMING
+        assert board.state("run_merge_fused", (2, 1024)) == \
+            bucket_health.COLD
+    finally:
+        board.reset()
+
+
+def test_serve_path_prewarms_report_what_compiled(monkeypatch):
+    """The scan-pushdown and block-codec prewarms return what compiled
+    and name nothing refused (lattices cut to one tiny n_pad: the
+    declared ones are PrewarmKernelsOp's full-mode bill)."""
+    from yugabyte_tpu.ops import block_codec, scan
+    monkeypatch.setattr(scan, "_PREWARM_NPADS", (256,))
+    monkeypatch.setattr(block_codec, "_PREWARM_DECODE", ((256, 4),))
+    pw = scan.prewarm_scan_pushdown()
+    n_filtered = len(scan.PRED_SLOTS) * 2
+    assert pw.compiled == n_filtered * (1 + len(scan.AGG_SLOTS)) + 1
+    assert pw.failed == []
+    pw = block_codec.prewarm_block_codec()
+    assert (pw.compiled, pw.failed) == (2, [])
 
 
 # ------------------------------------------------------------ run packing

@@ -146,3 +146,23 @@ def test_outputs_reopen_and_read(tmp_path):
     assert total == rn.rows_out
     for r in readers:
         r.close()
+
+
+def test_foreign_host_stamp_forces_rebuild(tmp_path, monkeypatch):
+    """A binary built with -march=native for another CPU must not run
+    here (SIGILL): the `<lib>.host` stamp decides, not mtimes."""
+    import os
+    from yugabyte_tpu.utils import native_build as nb
+    monkeypatch.setattr(nb, "BUILD_DIR", str(tmp_path))
+
+    def build():
+        lib = nb.build_native_lib("compaction_baseline.cc", "libx.so")
+        return lib, os.stat(lib).st_mtime_ns
+
+    lib, m0 = build()
+    assert nb._built_for(lib) == nb._host_tag()
+    assert build()[1] == m0, "same host, fresh binary: no rebuild"
+    with open(lib + ".host", "w") as f:
+        f.write("some-other-cpu")
+    assert build()[1] != m0, "foreign stamp did not force a rebuild"
+    assert nb._built_for(lib) == nb._host_tag()

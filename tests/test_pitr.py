@@ -34,6 +34,9 @@ def cluster(tmp_path_factory):
     c = MiniCluster(MiniClusterOptions(
         num_masters=1, num_tservers=1,
         fs_root=str(tmp_path_factory.mktemp("pitr")))).start()
+    # every test below creates its tables in "db": the namespace belongs
+    # to the fixture so each test also passes when run alone
+    c.new_client().create_namespace("db")
     yield c
     c.shutdown()
     flags.set_flag("replication_factor", old_rf)
@@ -55,7 +58,6 @@ def _write(client, table, rows):
 
 def test_restore_to_time(cluster):
     client = cluster.new_client()
-    client.create_namespace("db")
     table = client.create_table("db", "events", SCHEMA, num_tablets=2)
     cluster.wait_all_replicas_running(table.table_id)
     admin = AdminClient([cluster.master_addrs()[0]])

@@ -126,3 +126,21 @@ def test_dedup_survives_restart_replay(tmp_path):
         assert _entry_count(peer2) == n
     finally:
         peer2.shutdown()
+
+
+def test_request_tag_from_a_sidecar_payload_is_hashable():
+    """A follower's raft payload arrives as the RPC sidecar's bytearray
+    (batches of 32 KB and up): the decoded (client_id, request_id) tag
+    keys the dedup registry's dicts, so it must come back as bytes — a
+    bytearray tag made every follower's apply raise TypeError and defer
+    forever (found by chip_smoke.py's three-replica read-back)."""
+    from yugabyte_tpu.tablet.retryable_requests import RetryableRequests
+    from yugabyte_tpu.tablet.tablet_peer import (decode_write_batch,
+                                                 encode_write_batch)
+    payload = bytearray(encode_write_batch(
+        [(b"k", b"v")], request=(b"c" * 16, 7)))
+    _pairs, _intents, request = decode_write_batch(payload)
+    assert request == (b"c" * 16, 7) and isinstance(request[0], bytes)
+    reg = RetryableRequests()
+    reg.track_appended(*request)
+    reg.replicated(request[0], request[1], 123)

@@ -2,7 +2,7 @@
 """bench_compare: key-by-key diff of two bench round JSONs, with a
 regression gate.
 
-    python tools/bench_compare.py BENCH_SELF_r09.json BENCH_SELF_r10.json
+    python tools/bench_compare.py OLD_ROUND.json NEW_ROUND.json
     python tools/bench_compare.py old.json new.json --check
     python tools/bench_compare.py cpu.json tpu.json --force
 

@@ -66,10 +66,10 @@ echo "== kernel-manifest drift check (committed JSON) =="
 python -m tools.analysis.kernel_manifest --check
 
 echo "== bench regression gate (tools/bench_compare.py) =="
-# the comparator itself must work on real committed rounds (same
-# backend label -> plain diff exits 0; disjoint-key rounds are fine)...
-python tools/bench_compare.py BENCH_SELF_r09.json BENCH_SELF_r10.json \
-    > /dev/null
+# the comparator itself must work (a round against itself -> plain
+# diff exits 0)...
+python tools/bench_compare.py tools/bench_fixtures/base.json \
+    tools/bench_fixtures/base.json > /dev/null
 # ...and the gate must actually GATE: the committed synthetic-
 # regression fixture pair has to fail --check. If it passes, the
 # tolerance file or the direction inference silently broke.

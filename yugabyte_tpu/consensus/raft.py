@@ -292,6 +292,11 @@ class RaftConsensus:
         # role-change deferred under thread exhaustion; fired by the
         # election timer loop (upper layers MUST learn about leadership)
         self._pending_role_change: Optional[Role] = None
+        # peers with a vote solicitation in flight: an election does not
+        # ask a peer again while the last request to it is still out, so
+        # vote threads stay bounded by the peer count however long a dead
+        # or slow peer takes to answer
+        self._votes_in_flight: set = set()  # guarded-by: _lock
         self.clock = clock
         self._meta = _ConsensusMetadata(meta_path)
         self._rng = random.Random(seed if seed is not None
@@ -490,11 +495,14 @@ class RaftConsensus:
             req = VoteReq(term, self.config.peer_id,
                           self._last_term, self._last_index, ignore_lease)
             votes = {self.config.peer_id}
+            ask = [p for p in self.config.remote_peers
+                   if p not in self._votes_in_flight]
+            self._votes_in_flight.update(ask)
         TRACE("raft %s: starting election for term %d", self.config.peer_id, term)
         if len(self.config.peer_ids) == 1:
             self._maybe_win(term, votes)
             return
-        for peer in self.config.remote_peers:
+        for peer in ask:
             try:
                 threading.Thread(target=self._solicit_vote,
                                  args=(peer, req, votes),
@@ -515,6 +523,9 @@ class RaftConsensus:
             resp = self.transport.request_vote(self.config.peer_id, peer, req)
         except PeerUnreachable:
             return
+        finally:
+            with self._lock:
+                self._votes_in_flight.discard(peer)
         with self._lock:
             if resp.term > self._meta.term:
                 self._step_down_unlocked(resp.term)

@@ -56,7 +56,29 @@ class MiniCluster:
             time.sleep(0.01)
         for i in range(self.opts.num_tservers):
             self.add_tablet_server()
+        self._wait_tservers_registered()
         return self
+
+    def _wait_tservers_registered(self, timeout_s: float = 30.0) -> None:
+        """Block until the master leader lists every tserver live: a
+        tserver's start-up heartbeat is one best-effort RPC, and on a
+        loaded machine the first DDL otherwise races it ("need 3 live
+        tservers for RF=3, have 0"). Paced by the synchronous re-beat
+        itself, not by a sleep."""
+        deadline = time.monotonic() + timeout_s
+        want = {ts.server_id for ts in self.tservers}
+        while True:
+            live = {d.server_id for d in self.leader_master()
+                    .catalog.ts_manager.live_descriptors()}
+            missing = want - live
+            if not missing:
+                return
+            if time.monotonic() > deadline:
+                raise StatusError(Status.TimedOut(
+                    f"tservers never registered: {sorted(missing)}"))
+            for ts in self.tservers:
+                if ts.server_id in missing:
+                    ts.heartbeater.heartbeat_now()
 
     def add_tablet_server(self) -> TabletServer:
         sid = f"ts{len(self.tservers)}"

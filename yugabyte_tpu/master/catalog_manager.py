@@ -126,6 +126,11 @@ class CatalogManager:
         self.ts_manager = TSManager()
         self._lock = lock_rank.tracked(threading.RLock(),
                                        "catalog._lock")
+        # one snapshot-schedule tick at a time: the master's bg loop and an
+        # explicit run_snapshot_schedules() that both read a schedule as
+        # due would each take its snapshot (taken BEFORE catalog._lock,
+        # never under it)
+        self._schedule_tick_lock = threading.Lock()
         self._loaded_term = -1  # guarded-by: _lock
         self.namespaces: Dict[str, dict] = {}  # guarded-by: _lock
         self.tables: Dict[str, dict] = {}  # guarded-by: _lock
@@ -1043,6 +1048,10 @@ class CatalogManager:
     def run_snapshot_schedules(self) -> int:
         """One bg-loop tick: take due snapshots, prune expired ones.
         Returns snapshots taken."""
+        with self._schedule_tick_lock:
+            return self._run_snapshot_schedules_tick()
+
+    def _run_snapshot_schedules_tick(self) -> int:
         import time as _time
         now = _time.time()
         taken = 0

@@ -49,7 +49,7 @@ import numpy as np
 from yugabyte_tpu.ops.merge_gc import (
     _ROW_WORDS, PAD_SENTINEL, StagedCols, bucket_size, build_sort_schedule)
 from yugabyte_tpu.storage import block_format
-from yugabyte_tpu.utils import jax_setup  # noqa: F401  (compilation cache)
+from yugabyte_tpu.utils.jax_setup import Prewarm  # also: compilation cache
 
 
 class BlockCodecUnsupported(Exception):
@@ -416,7 +416,9 @@ def encode_span(st: StagedCols, n_rows: int, w_out: int, values,
         (keys_d, kl2, dkl2, ht_hi_d, ht_lo_d, wid_d, fl4, ttl_d,
          h_hi_d, h_lo_d) = _block_encode_fused(st.cols_dev)
         device_faults.maybe_fault("result")
-        return (np.asarray(keys_d[:n_rows, :w_out]),
+        # contiguous: a TPU array's host copy keeps the device's tiled
+        # strides, and the byte view below needs a contiguous last axis
+        return (np.ascontiguousarray(keys_d[:n_rows, :w_out]),
                 np.asarray(kl2[: (n_rows + 1) // 2]),
                 np.asarray(dkl2[: (n_rows + 1) // 2]),
                 np.asarray(ht_hi_d[:n_rows]),
@@ -514,32 +516,21 @@ def encode_span(st: StagedCols, n_rows: int, w_out: int, values,
 _PREWARM_DECODE = ((1 << 16, 4), (1 << 18, 4))
 
 
-def prewarm_block_codec() -> int:
+def prewarm_block_codec() -> Prewarm:
     """Ahead-of-traffic compile of the codec buckets (mirrors
     run_merge.prewarm_buckets; called by PrewarmKernelsOp)."""
     from yugabyte_tpu.ops.run_merge import _donation_supported
-    compiled = 0
-
-    def _warm(what, lower_fn):
-        nonlocal compiled
-        try:
-            lower_fn().compile()
-            compiled += 1
-        except Exception as e:  # noqa: BLE001 — prewarm must never block
-            import sys as _sys                       # server startup
-            print(f"[block_codec] prewarm of {what} failed: {e!r}",
-                  file=_sys.stderr, flush=True)
-
+    pw = Prewarm("block_codec")
     sdt = jax.ShapeDtypeStruct
     donate = _donation_supported()
     fn = _block_decode_fused_donated if donate else _block_decode_fused
     for n_pad, w_pad in _PREWARM_DECODE:
-        _warm(f"block_decode (n_pad={n_pad} w_pad={w_pad})",
-              lambda: fn.lower(*decode_avals(n_pad, w_pad)))
-        _warm(f"block_encode (n_pad={n_pad} w_pad={w_pad})",
-              lambda: _block_encode_fused.lower(
-                  sdt((_ROW_WORDS + w_pad, n_pad), jnp.uint32)))
-    return compiled
+        pw.warm(f"block_decode (n_pad={n_pad} w_pad={w_pad})",
+                lambda: fn.lower(*decode_avals(n_pad, w_pad)).compile())
+        pw.warm(f"block_encode (n_pad={n_pad} w_pad={w_pad})",
+                lambda: _block_encode_fused.lower(
+                    sdt((_ROW_WORDS + w_pad, n_pad), jnp.uint32)).compile())
+    return pw
 
 
 def decode_avals(n_pad: int, w_pad: int):

@@ -24,7 +24,7 @@ Sites:
 
 A fourth kind, "slow", raises nothing at all: it sleeps `delay_s` at
 the injection site, modeling a degraded-but-alive accelerator (thermal
-throttle, contended PCIe tunnel, a straggling mesh shard). Nothing in
+throttle, a contended PCIe link, a straggling mesh shard). Nothing in
 the loud-fault containment sees it — the bucket-health board's rate
 race (storage/bucket_health.py) is the defense it tests, and it can be
 pinned to one shape bucket via arm(..., bucket=...) so a nemesis can

@@ -47,13 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from yugabyte_tpu.ops.merge_gc import (
     _ROW_HT_HI, _ROW_KEY_LEN, _ROW_WID, _ROW_WORDS, GCParams, PAD_SENTINEL,
@@ -330,7 +324,7 @@ def default_tile(rp_rows: int) -> int:
 
 def supported(staged) -> bool:
     """Pallas path preconditions: >=2 runs, tile-divisible power-of-two m."""
-    if not _HAS_PLTPU or staged.k_pad < 2:
+    if staged.k_pad < 2:
         return False
     rp = ((_ROW_WORDS + staged.w + 1 + 7) // 8) * 8
     tile = min(default_tile(rp), staged.m)

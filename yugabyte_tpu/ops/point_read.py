@@ -52,7 +52,7 @@ import numpy as np
 from yugabyte_tpu.ops.merge_gc import (
     _ROW_HT_HI, _ROW_HT_LO, _ROW_KEY_LEN, _ROW_WID, _ROW_WORDS, StagedCols,
     bucket_size)
-from yugabyte_tpu.utils import jax_setup  # noqa: F401  (compilation cache)
+from yugabyte_tpu.utils.jax_setup import Prewarm  # also: compilation cache
 
 # Learned-index lattice: segment count is a single static (the anchors
 # array shape), and the error bound must fit the fixed window search —
@@ -519,21 +519,14 @@ _PREWARM_WIDTHS = (4, 8)
 _PREWARM_MWORDS = (1 << 14, 1 << 18)
 
 
-def prewarm_point_read() -> int:
+def prewarm_point_read() -> Prewarm:
     """Ahead-of-traffic compile of the declared point-read buckets
     (mirrors ops/run_merge.prewarm_buckets; called by PrewarmKernelsOp).
-    Returns the number of executables compiled."""
-    compiled = 0
+    Returns what compiled and what the compiler refused."""
+    pw = Prewarm("point_read")
 
     def _warm(what, lower_fn):
-        nonlocal compiled
-        try:
-            lower_fn().compile()
-            compiled += 1
-        except Exception as e:  # noqa: BLE001 — prewarm must never block
-            import sys as _sys                       # server startup
-            print(f"[point_read] prewarm of {what} failed: {e!r}",
-                  file=_sys.stderr, flush=True)
+        pw.warm(what, lambda: lower_fn().compile())
 
     i32 = jax.ShapeDtypeStruct((), jnp.int32)
     u32 = jax.ShapeDtypeStruct((), jnp.uint32)
@@ -567,7 +560,7 @@ def prewarm_point_read() -> int:
                   lambda: _index_fit_fused.lower(
                       sdt((8 + w, n_pad), jnp.uint32), i32,
                       n_segments=LINDEX_SEGMENTS, w=w))
-    return compiled
+    return pw
 
 
 def point_read_snapshot() -> dict:

@@ -33,6 +33,7 @@ from yugabyte_tpu.ops.merge_gc import (
     _ROW_DKL, _ROW_KEY_LEN, _ROW_WORDS, PAD_SENTINEL, StagedCols,
     pack_bits_u32, sort_and_gc)
 from yugabyte_tpu.ops.slabs import KVSlab, _pad_keys_to_words
+from yugabyte_tpu.utils.jax_setup import Prewarm
 
 
 def _pack_bound(key: Optional[bytes], w: int) -> Tuple[np.ndarray, int]:
@@ -1038,21 +1039,14 @@ _PREWARM_NPADS = (1 << 16, 1 << 20)
 _PREWARM_W = 4
 
 
-def prewarm_scan_pushdown() -> int:
+def prewarm_scan_pushdown() -> Prewarm:
     """Ahead-of-traffic compile of the declared scan_filtered/scan_agg
-    buckets (mirrors ops/point_read.prewarm_point_read). Returns the
-    number of executables compiled."""
-    compiled = 0
+    buckets (mirrors ops/point_read.prewarm_point_read). Returns
+    what compiled and what the compiler refused."""
+    pw = Prewarm("scan_pushdown")
 
     def _warm(what, lower_fn):
-        nonlocal compiled
-        try:
-            lower_fn().compile()
-            compiled += 1
-        except Exception as e:  # noqa: BLE001  # yblint: contained(prewarm is advisory: a failed warm only costs the first real dispatch its compile; server startup must not block)
-            import sys as _sys
-            print(f"[scan_pushdown] prewarm of {what} failed: {e!r}",
-                  file=_sys.stderr, flush=True)
+        pw.warm(what, lambda: lower_fn().compile())
 
     sdt = jax.ShapeDtypeStruct
     w = _PREWARM_W
@@ -1099,7 +1093,7 @@ def prewarm_scan_pushdown() -> int:
                   *a, sdt((1,), jnp.uint32), sdt((1,), jnp.uint32),
                   sdt((1,), jnp.uint32), w=w, p_pad=1, c_pad=1,
                   has_vals=False))
-    return compiled
+    return pw
 
 
 def pushdown_snapshot() -> dict:

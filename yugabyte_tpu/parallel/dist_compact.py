@@ -54,12 +54,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.35 jax exports it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from yugabyte_tpu.ops import merge_gc
+from yugabyte_tpu.utils.jax_setup import Prewarm
 from yugabyte_tpu.ops.merge_gc import (
     _ROW_DKL, _ROW_FLAGS, _ROW_KEY_LEN, _ROW_WORDS, GCParams, PAD_SENTINEL,
     StagedCols, bucket_size, build_sort_schedule, column_stats, pack_cols,
@@ -659,11 +657,12 @@ def prewarm_dist_compact(mesh: Mesh,
                          capacities: Optional[Sequence[int]] = None,
                          pool_shapes: Optional[Sequence[Tuple[int, int,
                                                               int, int]]]
-                         = None) -> int:
+                         = None) -> Prewarm:
     """Ahead-of-traffic compile of the mesh families: the key-range
     sharded dist_compact step per (capacity, is_major) and the pool wave
     program per (bucket, is_major). Run by PrewarmKernelsOp when the
-    server resolved a >1-device mesh; returns executables compiled."""
+    server resolved a >1-device mesh; returns what compiled and what
+    the compiler refused."""
     from yugabyte_tpu.ops import run_merge
     caps = tuple(capacities) if capacities is not None \
         else _PREWARM_CAPACITIES
@@ -671,17 +670,7 @@ def prewarm_dist_compact(mesh: Mesh,
         else _PREWARM_POOL_SHAPES
     n_shards = mesh.devices.size
     lexsort = run_merge._use_lexsort()
-    compiled = 0
-
-    def _warm(what: str, lower_fn) -> int:
-        try:
-            lower_fn()
-            return 1
-        except Exception as e:  # noqa: BLE001 — prewarm must never block
-            import sys as _sys                       # server startup
-            print(f"[dist_compact] prewarm of {what} failed: {e!r}",
-                  file=_sys.stderr, flush=True)
-            return 0
+    pw = Prewarm("dist_compact")
 
     u32 = jax.ShapeDtypeStruct((), jnp.uint32)
     for capacity in caps:
@@ -689,7 +678,7 @@ def prewarm_dist_compact(mesh: Mesh,
         n_total = n_shards * max(capacity, _CAPACITY_MIN)
         cols = jax.ShapeDtypeStruct((r, n_total), jnp.uint32)
         for is_major in (True, False):
-            compiled += _warm(
+            pw.warm(
                 f"dist_compact (n_shards={n_shards} capacity={capacity} "
                 f"is_major={is_major})",
                 lambda: dist_compact_fn(mesh, capacity, is_major)
@@ -702,7 +691,7 @@ def prewarm_dist_compact(mesh: Mesh,
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 jax.ShapeDtypeStruct((n_shards, 4), jnp.uint32))
         for is_major in (True, False):
-            got = _warm(
+            got = pw.warm(
                 f"pool_wave (slots={n_shards} k_pad={k_pad} m={m} w={w} "
                 f"is_major={is_major})",
                 lambda: pool_wave_fn(mesh, k_pad, m, w, n_cmp, is_major,
@@ -712,5 +701,4 @@ def prewarm_dist_compact(mesh: Mesh,
                 run_merge._record_bucket(
                     ("pool_wave", n_shards, k_pad, m, w, n_cmp, is_major,
                      False, lexsort))
-            compiled += got
-    return compiled
+    return pw

@@ -146,12 +146,14 @@ class RpcTransport:  # yblint: disable=ybsan-coverage (stateless dispatch seam: 
     def unregister(self, peer_id: str) -> None:
         self._service.unregister(peer_id)
 
-    def _call(self, dst: str, mth: str, req: dict) -> dict:
+    def _call(self, dst: str, mth: str, req: dict,
+              timeout_s: Optional[float] = None) -> dict:
         addr = self._resolver(dst)
         if addr is None:
             raise PeerUnreachable(f"{dst}: no address known")
         try:
             return self._messenger.call(addr, SERVICE_NAME, mth,
+                                        timeout_s=timeout_s,
                                         dst=dst, req=req)
         except (RpcTimeout, ServiceUnavailable, RemoteError) as e:
             raise PeerUnreachable(f"{dst}@{addr}: {e}") from e
@@ -179,10 +181,19 @@ class RpcTransport:  # yblint: disable=ybsan-coverage (stateless dispatch seam: 
             last_received_index=w["last_received_index"])
 
     def request_vote(self, src: str, dst: str, request: VoteReq) -> VoteResp:
+        # A vote is only worth the candidate's current election: its timer
+        # starts the next one (term + 1) within two failure periods, and an
+        # answer for an older term is discarded. Waiting the default RPC
+        # deadline instead left one parked thread per (tablet, peer,
+        # election) behind every dead or slow peer — tens of thousands in
+        # a loaded test run, up to the host's thread limit.
+        from yugabyte_tpu.utils import flags as _flags
+        timeout_s = 2e-3 * _flags.get_flag("raft_heartbeat_interval_ms") \
+            * _flags.get_flag("leader_failure_max_missed_heartbeat_periods")
         w = self._call(dst, "request_vote", {
             "term": request.term, "candidate_id": request.candidate_id,
             "last_log_term": request.last_log_term,
             "last_log_index": request.last_log_index,
-            "ignore_lease": request.ignore_lease})
+            "ignore_lease": request.ignore_lease}, timeout_s=timeout_s)
         return VoteResp(responder_id=w["responder_id"], term=w["term"],
                         granted=w["granted"])

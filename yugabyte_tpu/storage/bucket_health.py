@@ -18,9 +18,11 @@ The board keys records by the kernel manifest's declared
                           ^                        |
                           +------ PROBATION <------+  (timed decay)
 
-  COLD        never dispatched; routes native at policy sites until
-              prewarmed or first observed (compile cost not yet
-              amortized), and feeds AOT prewarm priority.
+  COLD        never dispatched. On a TPU the first job goes to the
+              device and pays its compile once into the persistent
+              cache; on any other backend it routes native at policy
+              sites until prewarmed or first observed, and feeds AOT
+              prewarm priority.
   WARMING     device observations accumulating; after `warmup_obs`
               results the rates decide HEALTHY vs DEGRADED.
   HEALTHY     device wins on measured rows/s EWMA; route device.
@@ -38,7 +40,8 @@ The board keys records by the kernel manifest's declared
 Two gates, matching how dispatch sites differ:
 
   use_device()   policy sites (inline/pool/dist compaction) — COLD
-                 routes native; forced `device_offload_mode` honored.
+                 routes native off-TPU; forced `device_offload_mode`
+                 honored.
   allow_device() containment sites (point read, pushdown, codec, and
                  the device-native entry inside a job) — COLD/WARMING
                  pass (those kernels are the job), only QUARANTINED /
@@ -100,6 +103,14 @@ STATES = (COLD, WARMING, HEALTHY, DEGRADED, QUARANTINED, PROBATION)
 _PROBE_TIMEOUT_S = 600.0
 _PROBE_HISTORY = 16
 _TRANSITION_LOG = 64
+
+
+def _on_tpu() -> bool:
+    """The process's JAX backend is a TPU: the one place a COLD bucket's
+    compile is worth paying on its first job (it lands in the persistent
+    cache and the native path is what the chip is there to replace)."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def _health_counter(what: str):
@@ -256,9 +267,10 @@ class BucketHealthBoard:
 
     def use_device(self, family: str, bucket, est_rows: int = 0,
                    cached: bool = False, probe: bool = True) -> bool:
-        """Policy-site gate (inline/pool/dist compaction): COLD routes
-        native until prewarmed/observed; forced modes honored; otherwise
-        defers to allow_device().
+        """Policy-site gate (inline/pool/dist compaction): off-TPU a
+        COLD bucket routes native until prewarmed/observed, on a TPU its
+        first job is dispatched to the device; forced modes honored;
+        otherwise defers to allow_device().
 
         probe=False is for DECISION-ONLY sites that hand the job to a
         different thread (the mesh pool submitter): a DEGRADED bucket
@@ -281,7 +293,7 @@ class BucketHealthBoard:
             r = self._rec(key)
             r.traffic += 1
             cold = r.state == COLD
-        if cold:
+        if cold and not _on_tpu():
             # compile cost not amortized yet: stay native, let the
             # prewarm op (fed by prewarm_priorities) pay the compile
             c["cold"].increment()

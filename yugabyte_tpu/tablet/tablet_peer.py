@@ -116,7 +116,9 @@ def decode_write_batch(payload: bytes
             pairs.append((k, v))
     request = None
     if flag & 4:
-        cid = payload[off: off + 16]
+        # bytes(): a follower's payload is the RPC sidecar's bytearray,
+        # and the tag keys the dedup registry's dicts
+        cid = bytes(payload[off: off + 16])
         (rid,) = struct.unpack_from("<Q", payload, off + 16)
         request = (cid, rid)
     return pairs, target_intents, request
@@ -354,7 +356,7 @@ class TabletPeer:
         if msg.op_type != OP_WRITE or not msg.payload:
             return
         if msg.payload[0] & 4:
-            cid = msg.payload[-24:-8]
+            cid = bytes(msg.payload[-24:-8])
             (rid,) = struct.unpack("<Q", msg.payload[-8:])
             self.tablet.retryable.track_appended(cid, rid)
 

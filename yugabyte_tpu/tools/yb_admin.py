@@ -25,6 +25,12 @@ from yugabyte_tpu.utils import jsonutil
 from yugabyte_tpu.utils.status import StatusError
 
 
+# flush_tablet / compact_tablet return when the work is done: a major
+# compaction outlasts the default RPC deadline by its nature, and its
+# first run of a shape bucket on a TPU pays the kernel compile besides
+_TABLET_OP_TIMEOUT_S = 1800.0
+
+
 def _p(obj) -> None:
     print(json.dumps(obj, indent=2, default=lambda b: b.hex()
                      if isinstance(b, bytes) else str(b)))
@@ -62,6 +68,7 @@ class AdminClient:
                      if r["server_id"] == loc["leader"]]
             if addrs and addrs[0]:
                 self.m.call(addrs[0], "tserver", mth,
+                            timeout_s=_TABLET_OP_TIMEOUT_S,
                             tablet_id=loc["tablet_id"])
         print(f"{mth} issued to {len(locs)} tablets")
 

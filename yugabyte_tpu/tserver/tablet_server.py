@@ -265,6 +265,9 @@ class TabletServer:
         # L0->L1->L2 compaction path — per-level entries/bytes, pins and
         # eviction pressure (storage/device_cache.py snapshot)
         ctx = self.exec_context
+        if ctx is not None:
+            # what the kernels run on: a run asserts platform == "tpu"
+            out["device"] = ctx.device_info()
         if ctx is not None and ctx.device_cache is not None:
             out["device_cache"] = ctx.device_cache.snapshot()
         # mesh-sharded compaction pool: queue depth, per-tablet
