@@ -376,6 +376,12 @@ def test_endpoint_smoke_and_compactionz(tmp_path):
         assert totals["compaction_bytes_read"] > 0
         assert totals["compaction_bytes_written"] > 0
         assert totals["write_amplification"] > 1.0
+        # the job's span rail: its wall, its named stages, and what no
+        # stage names (the residual)
+        stages = cz["pipeline"]
+        assert stages["stage_job_ms"] > 0
+        assert "stage_job_other_ms" in stages
+        assert "stage_version_install_ms" in stages
 
         prom = _get(addr, "/prometheus-metrics").decode()
         errs = validate_prometheus_text(prom)

@@ -136,8 +136,10 @@ class JitTraceSafetyPass(AnalysisPass):
 
         statics_of: Dict[str, Set[str]] = {}
         jit_wrappers: Dict[str, str] = {}  # wrapper name -> function name
-        self._const_env = self._module_str_constants(ctx)
-        self._find_roots(ctx, fns, statics_of, jit_wrappers)
+        # a local, not an attribute: one pass instance runs many files
+        # on the analyzer's worker threads at once
+        const_env = self._module_str_constants(ctx)
+        self._find_roots(ctx, fns, statics_of, jit_wrappers, const_env)
         if not any(i.is_root for i in fns.values()):
             return []
 
@@ -169,7 +171,8 @@ class JitTraceSafetyPass(AnalysisPass):
 
     def _find_roots(self, ctx: FileContext, fns: Dict[str, _FnInfo],
                     statics_of: Dict[str, Set[str]],
-                    jit_wrappers: Dict[str, str]) -> None:
+                    jit_wrappers: Dict[str, str],
+                    const_env: Dict[str, Set[str]]) -> None:
         for name, info in fns.items():
             for dec in info.node.decorator_list:
                 statics: Optional[Set[str]] = None
@@ -177,11 +180,11 @@ class JitTraceSafetyPass(AnalysisPass):
                     statics = set()
                 elif isinstance(dec, ast.Call) and _is_jit_callable(dec.func):
                     statics = _static_names_from_call(dec, info.params,
-                                                     self._const_env)
+                                                     const_env)
                 elif _jit_partial_call(dec) is not None:
                     statics = _static_names_from_call(
                         _jit_partial_call(dec), info.params,
-                        self._const_env)
+                        const_env)
                 if statics is not None:
                     info.is_root = True
                     info.tainted_params |= (
@@ -199,7 +202,7 @@ class JitTraceSafetyPass(AnalysisPass):
                 target_fn = v.args[0].id
                 if target_fn in fns:
                     statics = _static_names_from_call(
-                        v, fns[target_fn].params, self._const_env)
+                        v, fns[target_fn].params, const_env)
             elif isinstance(v, ast.Call) \
                     and _jit_partial_call(v.func) is not None \
                     and v.args and isinstance(v.args[0], ast.Name):
@@ -207,7 +210,7 @@ class JitTraceSafetyPass(AnalysisPass):
                 if target_fn in fns:
                     statics = _static_names_from_call(
                         _jit_partial_call(v.func), fns[target_fn].params,
-                        self._const_env)
+                        const_env)
             if target_fn and target_fn in fns:
                 info = fns[target_fn]
                 info.is_root = True

@@ -27,7 +27,7 @@ from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils import latency
 from yugabyte_tpu.utils.backoff import Backoff, RetryBudget
 from yugabyte_tpu.utils.status import Code, Status, StatusError
-from yugabyte_tpu.utils.trace import TRACE, Trace
+from yugabyte_tpu.utils.trace import TRACE, Trace, span
 
 flags.define_flag("client_rpc_retries", 12,
                   "per-operation retry budget (leader changes, restarts)")
@@ -573,52 +573,58 @@ class YBClient:
 
         follower_read: see read_row — bounded-staleness batch served by
         any vouched replica, spreading read load across the raft group."""
-        groups: Dict[str, Tuple[RemoteTablet, bytes, List[int]]] = {}
-        for i, dk in enumerate(doc_keys):
-            pk = table.partition_key_for(dk)
-            tablet = self.meta_cache.lookup_tablet(table.table_id, pk)
-            groups.setdefault(tablet.tablet_id,
-                              (tablet, pk, []))[2].append(i)
-        if follower_read and read_ht is None:
-            read_ht = follower_read_ht()
-        out: List = [None] * len(doc_keys)
-        errors: List[Exception] = []
+        # the whole batch on the caller's thread; the per-tablet calls
+        # (fan-out threads) are its children
+        with span("client/multi_read_batch") as batch:
+            groups: Dict[str, Tuple[RemoteTablet, bytes, List[int]]] = {}
+            for i, dk in enumerate(doc_keys):
+                pk = table.partition_key_for(dk)
+                tablet = self.meta_cache.lookup_tablet(table.table_id, pk)
+                groups.setdefault(tablet.tablet_id,
+                                  (tablet, pk, []))[2].append(i)
+            if follower_read and read_ht is None:
+                read_ht = follower_read_ht()
+            out: List = [None] * len(doc_keys)
+            errors: List[Exception] = []
 
-        def fetch(tablet, pk, idxs) -> None:
-            try:
-                # serve-path attribution: one budget per tablet group —
-                # each group is one RPC, so the per-group e2e decomposes
-                # cleanly into its own server's stage map (a fan-out
-                # batch records one attribution sample per tablet)
-                with latency.budget_scope(latency.OP_MULTI_READ):
-                    resp = self._tablet_call(
-                        table, tablet, "multi_read", refresh_key=pk,
-                        spread_replicas=follower_read,
-                        doc_keys=[doc_key_to_wire(doc_keys[i])
-                                  for i in idxs],
-                        read_ht=read_ht.value if read_ht else None,
-                        projection=list(projection) if projection else None,
-                        allow_follower=follower_read,
-                        schema_version=table.schema_version)
-            except Exception as e:  # noqa: BLE001 — re-raised below
-                errors.append(e)
-                return
-            for i, w in zip(idxs, resp["rows"]):
-                out[i] = None if w is None else row_from_wire(w)
+            def fetch(tablet, pk, idxs) -> None:
+                try:
+                    # serve-path attribution: one budget per tablet group —
+                    # each group is one RPC, so the per-group e2e decomposes
+                    # cleanly into its own server's stage map (a fan-out
+                    # batch records one attribution sample per tablet)
+                    with latency.budget_scope(latency.OP_MULTI_READ,
+                                              parent=batch):
+                        resp = self._tablet_call(
+                            table, tablet, "multi_read", refresh_key=pk,
+                            spread_replicas=follower_read,
+                            doc_keys=[doc_key_to_wire(doc_keys[i])
+                                      for i in idxs],
+                            read_ht=read_ht.value if read_ht else None,
+                            projection=(list(projection) if projection
+                                        else None),
+                            allow_follower=follower_read,
+                            schema_version=table.schema_version)
+                except Exception as e:  # noqa: BLE001 — re-raised below
+                    errors.append(e)
+                    return
+                for i, w in zip(idxs, resp["rows"]):
+                    out[i] = None if w is None else row_from_wire(w)
 
-        grps = list(groups.values())
-        if len(grps) == 1:
-            fetch(*grps[0])
-        else:
-            # per-tablet fan-out: the batch's wall time is the slowest
-            # tablet's RPC, not the sum (mirrors the session batcher)
-            import threading as _threading
-            threads = [_threading.Thread(target=fetch, args=g, daemon=True)
-                       for g in grps]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
+            grps = list(groups.values())
+            if len(grps) == 1:
+                fetch(*grps[0])
+            else:
+                # per-tablet fan-out: the batch's wall time is the slowest
+                # tablet's RPC, not the sum (mirrors the session batcher)
+                import threading as _threading
+                threads = [_threading.Thread(target=fetch, args=g, daemon=True)
+                           for g in grps]
+                for t in threads:
+                    t.start()
+                with span("client/await_fanout"):
+                    for t in threads:
+                        t.join()
         if errors:
             raise errors[0]
         return out

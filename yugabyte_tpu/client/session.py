@@ -34,6 +34,7 @@ from yugabyte_tpu.docdb.doc_operations import QLWriteOp
 from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils import latency
 from yugabyte_tpu.utils.status import Code, Status, StatusError
+from yugabyte_tpu.utils.trace import AMBIENT, span
 
 flags.define_flag("ybsession_max_batch_ops", 512,
                   "a per-tablet group reaching this many buffered ops "
@@ -229,7 +230,8 @@ class YBSession:
     # --------------------------------------------------------------- sending
     def _send_group(self, group: _TabletGroup,
                     errors: List[Tuple[YBTable, QLWriteOp, Exception]],
-                    errors_lock: threading.Lock) -> None:
+                    errors_lock: threading.Lock, parent=AMBIENT) -> None:
+        """parent: the flush's span, when a fan-out thread sends."""
         try:
             # serve-path attribution: the budget's clock starts when the
             # group's first op buffered, so the time the batch waited in
@@ -237,8 +239,8 @@ class YBSession:
             # (wire encode, service queue, raft, WAL, apply) records its
             # slice into the same ambient budget, and on success the
             # scope exit feeds the serve_path histograms
-            with latency.budget_scope(latency.OP_WRITE,
-                                      t0=group.created) as budget:
+            with latency.budget_scope(latency.OP_WRITE, t0=group.created,
+                                      parent=parent) as budget:
                 budget.record(latency.STAGE_CLIENT_QUEUE,
                               (time.monotonic() - group.created) * 1e3)
                 self._client.write(group.table, group.ops,
@@ -297,18 +299,23 @@ class YBSession:
         errors: List[Tuple[YBTable, QLWriteOp, Exception]] = []
         errors_lock = threading.Lock()
         try:
-            if len(groups) == 1:
-                # single-tablet batch (the overwhelmingly common case
-                # under key-grouped load): skip the thread spawn
-                self._send_group(groups[0], errors, errors_lock)
-            elif groups:
-                threads = [threading.Thread(
-                    target=self._send_group, args=(g, errors, errors_lock),
-                    daemon=True) for g in groups]
-                for t in threads:
-                    t.start()
-                for t in threads:
-                    t.join()
+            # the whole flush on the caller's thread; the per-tablet sends
+            # (fan-out threads) are its children
+            with span("client/flush") as flush:
+                if len(groups) == 1:
+                    # single-tablet batch (the overwhelmingly common case
+                    # under key-grouped load): skip the thread spawn
+                    self._send_group(groups[0], errors, errors_lock)
+                elif groups:
+                    threads = [threading.Thread(
+                        target=self._send_group,
+                        args=(g, errors, errors_lock, flush),
+                        daemon=True) for g in groups]
+                    for t in threads:
+                        t.start()
+                    with span("client/await_fanout"):
+                        for t in threads:
+                            t.join()
         finally:
             with self._inflight_cv:
                 self._inflight_bytes -= moved_bytes

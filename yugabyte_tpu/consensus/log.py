@@ -33,7 +33,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils.latency import STAGE_WAL_FSYNC
 from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
-from yugabyte_tpu.utils.trace import TRACE, LongOperationTracker
+from yugabyte_tpu.utils.trace import TRACE, LongOperationTracker, span
 
 flags.define_flag("log_segment_size_bytes", 64 * 1024 * 1024,
                   "roll the WAL segment after it exceeds this size "
@@ -302,13 +302,14 @@ class Log:
                 h_append.increment((t1 - t0) * 1e3)
                 # a slow fsync dumps its trace (LongOperationTracker armed
                 # on the WAL durability path, ref read_query.cc:500 usage)
-                with LongOperationTracker(
+                fsync = span("serve/" + STAGE_WAL_FSYNC)
+                with fsync, LongOperationTracker(
                         "wal.fsync",
                         flags.get_flag("wal_slow_fsync_threshold_ms")):
                     for f in files_to_sync:
                         f.flush(fsync=bool(
                             flags.get_flag("durable_wal_write")))
-                fsync_ms = (_time.monotonic() - t1) * 1e3
+                fsync_ms = fsync.ms
                 h_fsync.increment(fsync_ms)
                 c_commits.increment()
                 # Attribute the group fsync to every op in the batch:

@@ -41,7 +41,7 @@ from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils import latency as _latency
 from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
 from yugabyte_tpu.utils.trace import (TRACE, LongOperationTracker, Trace,
-                                      current_trace_context)
+                                      current_trace_context, span)
 
 flags.define_flag("raft_heartbeat_interval_ms", 50,
                   "leader heartbeat period (ref raft_heartbeat_interval_ms)")
@@ -744,20 +744,20 @@ class RaftConsensus:
                   timeout_s: float = 30.0) -> OpId:
         """Leader: append + replicate + wait for commit AND local apply
         (ref raft_consensus.cc:1140 ReplicateBatch)."""
-        t0 = time.monotonic()
         budget = _latency.current_budget()
         fs0 = ap0 = 0.0
         if budget is not None:
             fs0 = budget.stages.get(_latency.STAGE_WAL_FSYNC, 0.0)
             ap0 = budget.stages.get(_latency.STAGE_APPLY, 0.0)
+        replicate = span("serve/" + _latency.STAGE_RAFT_REPLICATE)
         try:
-            with LongOperationTracker(
+            with replicate, LongOperationTracker(
                     "raft.replicate",
                     flags.get_flag("raft_slow_replicate_threshold_ms")):
                 return self._replicate_inner(op_type, ht_value, payload,
                                              timeout_s)
         finally:
-            wall_ms = (time.monotonic() - t0) * 1e3
+            wall_ms = replicate.ms
             _consensus_metrics()[0].increment(wall_ms)
             if budget is not None:
                 # attribution: the replicate wall MINUS the fsync/apply
@@ -1210,9 +1210,10 @@ class RaftConsensus:
                     # Consensus-internal; committed config may remove us.
                     self._on_config_committed(msg)
                 elif msg.op_type != OP_NOOP:
-                    apply_t0 = time.monotonic()
+                    applied = span("serve/" + _latency.STAGE_APPLY)
                     try:
-                        self.apply_cb(msg)
+                        with applied:
+                            self.apply_cb(msg)
                     except Exception as e:  # noqa: BLE001 — contained
                         # A parked storage engine (background error) rejects
                         # the apply. last_applied MUST NOT advance past an
@@ -1225,9 +1226,7 @@ class RaftConsensus:
                               self.config.peer_id, msg.op_id, e)
                         return
                     if budget is not None:
-                        budget.record(
-                            _latency.STAGE_APPLY,
-                            (time.monotonic() - apply_t0) * 1e3)
+                        budget.record(_latency.STAGE_APPLY, applied.ms)
                 with self._lock:
                     self.last_applied = idx
                     self._commit_cv.notify_all()

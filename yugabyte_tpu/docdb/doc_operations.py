@@ -160,14 +160,17 @@ def prepare_and_assemble(ops: Sequence[QLWriteOp], schema: Schema,
     write batch from the same KV pairs (ref: docdb.h:109
     PrepareDocWriteOperation + :127 AssembleDocWriteBatch). The index in the
     returned list becomes the intra-batch write_id."""
+    from yugabyte_tpu.utils.latency import sub_span
     entries: List[Tuple[bytes, IntentType]] = []
     all_pairs: List[Tuple[bytes, bytes]] = []
-    for op in ops:
-        pairs = op.to_kv_pairs(schema)
-        entries.extend(op.lock_entries(schema, pairs))
-        if op.backfill_ht:
-            all_pairs.extend((k, v, op.backfill_ht) for k, v in pairs)
-        else:
-            all_pairs.extend(pairs)
-    batch = lock_manager.lock(LockBatch(entries), timeout_s=timeout_s)
+    with sub_span("docop_encode"):
+        for op in ops:
+            pairs = op.to_kv_pairs(schema)
+            entries.extend(op.lock_entries(schema, pairs))
+            if op.backfill_ht:
+                all_pairs.extend((k, v, op.backfill_ht) for k, v in pairs)
+            else:
+                all_pairs.extend(pairs)
+    with sub_span("write_lock_wait"):
+        batch = lock_manager.lock(LockBatch(entries), timeout_s=timeout_s)
     return batch, all_pairs

@@ -280,15 +280,18 @@ def decode_file_to_staged(rfb: RawFileBlocks, device=None) -> StagedCols:
     """Upload one file's raw fixed regions and decode them on device into
     the StagedCols matrix stage_slab would have produced (bit-identical;
     differential-tested in tests/test_block_codec.py)."""
-    import time as _time
+    from yugabyte_tpu.utils.metrics import pipeline_span
+    if rfb.n == 0:
+        raise BlockCodecUnsupported("empty file has nothing to stage")
+    with pipeline_span("decode") as sp:
+        return _decode_file_to_staged(rfb, device, sp)
+
+
+def _decode_file_to_staged(rfb: RawFileBlocks, device, sp) -> StagedCols:
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.ops.run_merge import _donation_supported
-    from yugabyte_tpu.utils.metrics import (record_kernel_dispatch,
-                                            record_pipeline_stage)
+    from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     n = rfb.n
-    if n == 0:
-        raise BlockCodecUnsupported("empty file has nothing to stage")
-    t0 = _time.monotonic()
     n_pad = bucket_size(n)
     from yugabyte_tpu.storage.bucket_health import health_board
     _board = health_board()
@@ -375,11 +378,9 @@ def decode_file_to_staged(rfb: RawFileBlocks, device=None) -> StagedCols:
                     reason=f"decode {type(e2).__name__}: {e2}")
             raise
     sort_rows, n_sort = build_sort_schedule(w_pad, is_const)
-    record_kernel_dispatch("kernel_block_decode", n, n_pad,
-                           (_time.monotonic() - t0) * 1e3)
-    record_pipeline_stage("decode", (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_block_decode", n, n_pad)
     _board.record_device("block_decode", (1, n_pad), n,
-                         _time.monotonic() - t0)
+                         sp.elapsed_ms() / 1e3)
     codec_metrics()["decode_blocks"].increment(len(rfb.bodies))
     return StagedCols(cols, sort_rows, n_sort, n, n_pad, w_pad,
                       is_const, first)
@@ -395,19 +396,24 @@ def encode_span(st: StagedCols, n_rows: int, w_out: int, values,
     host-side value rows (tombstone rewrite already applied).
     Returns (blocks, index_items, bloom_hashes, first_key, last_key) in
     the exact write_base_file vocabulary."""
-    import time as _time
-    import zlib as _zlib
-    from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.storage.bucket_health import health_board
-    from yugabyte_tpu.utils.metrics import (record_kernel_dispatch,
-                                            record_pipeline_stage)
+    from yugabyte_tpu.utils.metrics import pipeline_span
     _board = health_board()
     if not _board.allow_device("block_encode", (1, st.n_pad)):
         # parked encode bucket: the job unwinds its partial outputs and
         # re-runs through the native byte shell, byte-identically
         raise BlockCodecUnsupported("encode bucket parked by the "
                                     "health board")
-    t0 = _time.monotonic()
+    with pipeline_span("encode") as sp:
+        return _encode_span(st, n_rows, w_out, values, block_entries,
+                            compress, _board, sp)
+
+
+def _encode_span(st: StagedCols, n_rows: int, w_out: int, values,
+                 block_entries: int, compress: bool, _board, sp):
+    import zlib as _zlib
+    from yugabyte_tpu.ops import device_faults
+    from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     device_faults.maybe_fault("dispatch")
 
     def _download():
@@ -498,11 +504,9 @@ def encode_span(st: StagedCols, n_rows: int, w_out: int, values,
         data_off += len(blk)
     first_key = key_at(0) if n_rows else b""
     last_key = key_at(n_rows - 1) if n_rows else b""
-    record_kernel_dispatch("kernel_block_encode", n_rows, st.n_pad,
-                           (_time.monotonic() - t0) * 1e3)
-    record_pipeline_stage("encode", (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_block_encode", n_rows, st.n_pad)
     _board.record_device("block_encode", (1, st.n_pad), n_rows,
-                         _time.monotonic() - t0)
+                         sp.elapsed_ms() / 1e3)
     codec_metrics()["encode_blocks"].increment(len(blocks))
     return blocks, index_items, hashes, first_key, last_key
 

@@ -375,7 +375,6 @@ def merge_and_gc_device(slab: Optional[KVSlab], params: GCParams, device=None,
     staged: pre-staged device cols (device-resident slab cache path) —
     skips the host pack + upload entirely.
     """
-    import time as _time
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     if staged is None:
         if slab.n == 0:
@@ -387,7 +386,6 @@ def merge_and_gc_device(slab: Optional[KVSlab], params: GCParams, device=None,
     n, n_pad, w = staged.n, staged.n_pad, staged.w
     cutoff = params.history_cutoff_ht
     cutoff_phys = cutoff >> 12
-    t0 = _time.monotonic()
     perm, keep_p, mk_p = _merge_gc_fused(
         cols_dev, jnp.asarray(sort_rows), jnp.int32(n_sort),
         jnp.uint32(cutoff >> 32), jnp.uint32(cutoff & 0xFFFFFFFF),
@@ -397,10 +395,7 @@ def merge_and_gc_device(slab: Optional[KVSlab], params: GCParams, device=None,
     perm = np.asarray(perm)
     keep = _unpack_bits(np.asarray(keep_p), n_pad) & (perm < n)
     mk = _unpack_bits(np.asarray(mk_p), n_pad)
-    # the np.asarray transfers above block on the device, so this wall
-    # time covers dispatch + compute + decision download
-    record_kernel_dispatch("kernel_merge_gc", n, n_pad,
-                           (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_merge_gc", n, n_pad)
     return perm, keep, mk
 
 

@@ -424,31 +424,33 @@ def bloom_device_words(reader, device=None):
 def hash_batch(qwords: np.ndarray, dkls: np.ndarray
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Device FNV over the doc-key prefix of each padded query."""
-    import time as _time
     from yugabyte_tpu.ops.run_merge import quantize_width
+    from yugabyte_tpu.utils.latency import sub_span
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
-    t0 = _time.monotonic()
     # the batch is packed at a quantize_width point already; re-routing
     # the static through the quantizer keeps the lattice explicit
-    h1, h2 = _fnv64_fused(jnp.asarray(qwords),
-                          jnp.asarray(dkls, dtype=np.int32),
-                          w=quantize_width(int(qwords.shape[1])))
+    with sub_span("device_enqueue"):
+        h1, h2 = _fnv64_fused(jnp.asarray(qwords),
+                              jnp.asarray(dkls, dtype=np.int32),
+                              w=quantize_width(int(qwords.shape[1])))
     record_kernel_dispatch("kernel_point_hash", int(qwords.shape[0]),
-                           int(qwords.shape[0]),
-                           (_time.monotonic() - t0) * 1e3)
+                           int(qwords.shape[0]))
     return h1, h2
 
 
 def probe_bloom(reader, h1, h2, device=None) -> Optional[np.ndarray]:
     """Probe one SST's bloom for the batch; None = no usable filter
     (treat every key as a maybe — the bloom is advisory)."""
-    bd = bloom_device_words(reader, device)
-    if bd is None:
-        return None
-    words, m_bits, k = bd
-    ok = _bloom_probe_fused(h1, h2, words, jnp.uint32(m_bits),
-                            jnp.int32(k))
-    return np.asarray(ok)
+    from yugabyte_tpu.utils.latency import SUB_DEVICE_WAIT, sub_span
+    with sub_span("device_enqueue"):
+        bd = bloom_device_words(reader, device)
+        if bd is None:
+            return None
+        words, m_bits, k = bd
+        ok = _bloom_probe_fused(h1, h2, words, jnp.uint32(m_bits),
+                                jnp.int32(k))
+    with sub_span(SUB_DEVICE_WAIT):
+        return np.asarray(ok)
 
 
 def locate_batch(staged: StagedCols, qwords: np.ndarray,
@@ -461,8 +463,8 @@ def locate_batch(staged: StagedCols, qwords: np.ndarray,
     None for the exact full binary seek. Returns numpy
     (idx, hit, ht_hi, ht_lo, wid, miss).
     """
-    import time as _time
     from yugabyte_tpu.ops import device_faults
+    from yugabyte_tpu.utils.latency import SUB_DEVICE_WAIT, sub_span
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     b = int(qwords.shape[0])
     use_model = model_ops is not None
@@ -474,20 +476,20 @@ def locate_batch(staged: StagedCols, qwords: np.ndarray,
         anchor_pos = np.zeros(LINDEX_SEGMENTS + 1, dtype=np.int32)
         p = 0
         max_err = 0
-    t0 = _time.monotonic()
     device_faults.maybe_fault("dispatch")
-    out = _locate_gather_fused(
-        staged.cols_dev, jnp.int32(staged.n), jnp.asarray(qwords),
-        jnp.asarray(qlens, dtype=np.int32),
-        jnp.uint32(read_ht_value >> 32),
-        jnp.uint32(read_ht_value & 0xFFFFFFFF),
-        jnp.asarray(a_hi), jnp.asarray(a_lo), jnp.asarray(anchor_pos),
-        jnp.int32(p), jnp.int32(max_err), w=staged.w,
-        use_model=use_model)
+    with sub_span("device_enqueue"):
+        out = _locate_gather_fused(
+            staged.cols_dev, jnp.int32(staged.n), jnp.asarray(qwords),
+            jnp.asarray(qlens, dtype=np.int32),
+            jnp.uint32(read_ht_value >> 32),
+            jnp.uint32(read_ht_value & 0xFFFFFFFF),
+            jnp.asarray(a_hi), jnp.asarray(a_lo), jnp.asarray(anchor_pos),
+            jnp.int32(p), jnp.int32(max_err), w=staged.w,
+            use_model=use_model)
     device_faults.maybe_fault("result")
-    idx, hit, ht_hi, ht_lo, wid, miss = (np.asarray(x) for x in out)
-    record_kernel_dispatch("kernel_point_locate", b, b,
-                           (_time.monotonic() - t0) * 1e3)
+    with sub_span(SUB_DEVICE_WAIT):
+        idx, hit, ht_hi, ht_lo, wid, miss = (np.asarray(x) for x in out)
+    record_kernel_dispatch("kernel_point_locate", b, b)
     return idx, hit, ht_hi, ht_lo, wid, miss
 
 

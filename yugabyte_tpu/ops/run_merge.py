@@ -768,15 +768,11 @@ class MergeGCHandle:
         # each separate np.asarray pays a full device round-trip)
 
     def _download(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        from yugabyte_tpu.utils.metrics import record_pipeline_stage
-        import time as _time
-        t0 = _time.monotonic()
-        packed = np.asarray(self._packed_dev)  # [n_pad//32, 2+b]
-        t1 = _time.monotonic()
-        out = _decode_packed(packed, self._staged)
-        record_pipeline_stage("device", (t1 - t0) * 1e3)
-        record_pipeline_stage("host", (_time.monotonic() - t1) * 1e3)
-        return out
+        from yugabyte_tpu.utils.metrics import pipeline_span
+        with pipeline_span("device"):   # the host blocked on the device
+            packed = np.asarray(self._packed_dev)  # [n_pad//32, 2+b]
+        with pipeline_span("decision_unpack", inclusive="host"):
+            return _decode_packed(packed, self._staged)
 
     def result(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """(perm, keep, make_tombstone) host arrays over the merged order.
@@ -1165,21 +1161,18 @@ class _ChunkedMergeGCHandle:
             # concat would bypass both
             return [self._result_with_retry(i) for i in range(len(hs))]
         try:
-            import time as _time
-            from yugabyte_tpu.utils.metrics import record_pipeline_stage
+            from yugabyte_tpu.utils.metrics import pipeline_span
             devs = [h._packed_dev for h in hs]
             if len({d.shape[1] for d in devs}) == 1:
                 rows = [d.shape[0] for d in devs]
-                t0 = _time.monotonic()
-                cat = np.asarray(jnp.concatenate(devs, axis=0))
-                t1 = _time.monotonic()
-                record_pipeline_stage("device", (t1 - t0) * 1e3)
-                out, off = [], 0
-                for h, r in zip(hs, rows):
-                    out.append(_decode_packed(cat[off:off + r], h._staged))
-                    off += r
-                record_pipeline_stage("host",
-                                      (_time.monotonic() - t1) * 1e3)
+                with pipeline_span("device"):
+                    cat = np.asarray(jnp.concatenate(devs, axis=0))
+                with pipeline_span("decision_unpack", inclusive="host"):
+                    out, off = [], 0
+                    for h, r in zip(hs, rows):
+                        out.append(_decode_packed(cat[off:off + r],
+                                                  h._staged))
+                        off += r
                 return out
         except Exception as e:  # noqa: BLE001 — degrade, never fail here
             import sys as _sys
@@ -1210,14 +1203,16 @@ class _ChunkedMergeGCHandle:
     def result(self):
         if self._result is not None:
             return self._result
+        from yugabyte_tpu.utils.metrics import pipeline_span
         perms, keeps, mks = [], [], []
-        for (p, keep, mk), (starts, lens) in zip(self._chunk_results(),
-                                                 self._metas):
-            perms.append(self._remap_perm(p, starts, lens))
-            keeps.append(keep)
-            mks.append(mk)
-        self._result = (np.concatenate(perms), np.concatenate(keeps),
-                        np.concatenate(mks))
+        chunks = self._chunk_results()
+        with pipeline_span("decision_remap"):
+            for (p, keep, mk), (starts, lens) in zip(chunks, self._metas):
+                perms.append(self._remap_perm(p, starts, lens))
+                keeps.append(keep)
+                mks.append(mk)
+            self._result = (np.concatenate(perms), np.concatenate(keeps),
+                            np.concatenate(mks))
         return self._result
 
     def result_iter(self):
@@ -1239,16 +1234,20 @@ class _ChunkedMergeGCHandle:
                     pd.copy_to_host_async()
                 except (AttributeError, NotImplementedError):
                     pass
+        from yugabyte_tpu.utils.metrics import pipeline_span
         perms, keeps, mks = [], [], []
         for i, (starts, lens) in enumerate(self._metas):
             p, keep, mk = self._result_with_retry(i)
-            perm_g = self._remap_perm(p, starts, lens)
+            # (no span is held open across the yield)
+            with pipeline_span("decision_remap"):
+                perm_g = self._remap_perm(p, starts, lens)
             perms.append(perm_g)
             keeps.append(keep)
             mks.append(mk)
             yield perm_g, keep, mk
-        self._result = (np.concatenate(perms), np.concatenate(keeps),
-                        np.concatenate(mks))
+        with pipeline_span("decision_remap"):
+            self._result = (np.concatenate(perms), np.concatenate(keeps),
+                            np.concatenate(mks))
 
     def to_parent_products(self) -> None:
         """Build the parent-domain device arrays gather_staged_outputs
@@ -1556,7 +1555,6 @@ def merge_and_gc_runs(slabs: Sequence[KVSlab], params: GCParams, device=None,
     single bucket) falls back to the radix kernel.
     """
     import os as _os
-    import time as _time
     from yugabyte_tpu.utils.metrics import kernel_metrics
     if staged is None:
         live = [s for s in slabs if s.n]
@@ -1579,13 +1577,7 @@ def merge_and_gc_runs(slabs: Sequence[KVSlab], params: GCParams, device=None,
             real = perm < merged.n
             return perm[real].astype(np.int64), keep[real], mk[real]
         staged = stage_runs_from_slabs(live, device)
-    t0 = _time.monotonic()
-    out = launch_merge_gc(staged, params, snapshot=snapshot).result()
-    kernel_metrics().histogram(
-        "kernel_run_merge_duration_ms",
-        "run-merge launch-to-decisions wall time").increment(
-        (_time.monotonic() - t0) * 1e3)
-    return out
+    return launch_merge_gc(staged, params, snapshot=snapshot).result()
 
 
 def run_layout_inflation(run_ns: Sequence[int]) -> float:

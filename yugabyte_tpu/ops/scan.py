@@ -100,14 +100,12 @@ def scan_visible(staged: StagedCols, read_ht_value: int,
     perm[i] of the staged input survives iff keep[i]; surviving entries are
     exactly the versions visible at read_ht within [lower_key, upper_key).
     """
-    import time as _time
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     w_bytes_cap = staged.w  # key words available
     lo_w, lo_l = _pack_bound(lower_key, w_bytes_cap)
     hi_w, hi_l = _pack_bound(upper_key, w_bytes_cap)
     cutoff = read_ht_value
     cutoff_phys = cutoff >> 12
-    t0 = _time.monotonic()
     perm, keep_p = _scan_fused(
         staged.cols_dev, jnp.asarray(staged.sort_rows), jnp.int32(staged.n_sort),
         jnp.uint32(cutoff >> 32), jnp.uint32(cutoff & 0xFFFFFFFF),
@@ -118,10 +116,7 @@ def scan_visible(staged: StagedCols, read_ht_value: int,
     perm = np.asarray(perm)
     keep = merge_gc._unpack_bits(np.asarray(keep_p), staged.n_pad)
     keep = keep & (perm < staged.n)
-    # the np.asarray transfers block, so the wall time covers compute +
-    # keep-mask download
-    record_kernel_dispatch("kernel_scan", staged.n, staged.n_pad,
-                           (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_scan", staged.n, staged.n_pad)
     return perm, keep
 
 
@@ -867,7 +862,6 @@ def filtered_entries_sources(sources, read_ht_value: int, spec,
     EAGERLY, before the first yield — a device fault surfaces here,
     where the caller can still fall back to the host path without having
     emitted a single row."""
-    import time as _time
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
 
@@ -879,7 +873,6 @@ def filtered_entries_sources(sources, read_ht_value: int, spec,
     (lo_w, lo_l, hi_w, hi_l, up_inf, up_trunc,
      lo_exact, hi_exact) = _bound_operands(staged, lower_key, upper_key)
     bkey = _check_pushdown_bucket(staged.n_pad, "scan_filtered")
-    t0 = _time.monotonic()
     try:
         device_faults.maybe_fault("dispatch")
         perm, keep_p = _scan_filtered_fused(
@@ -896,8 +889,7 @@ def filtered_entries_sources(sources, read_ht_value: int, spec,
         raise
     keep = merge_gc._unpack_bits(keep_p, staged.n_pad)
     keep = keep & (perm < staged.n)
-    record_kernel_dispatch("kernel_scan_filtered", staged.n, staged.n_pad,
-                           (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_scan_filtered", staged.n, staged.n_pad)
     _record_bucket_dispatch("filtered", staged.n_pad)
     m = pushdown_metrics()
     m["filtered"].increment()
@@ -930,7 +922,6 @@ def aggregate_sources(sources, read_ht_value: int, spec,
     {"rows": <count of passing rows>, "cols": {cid: {"nonnull", "sum",
     "min", "max"}}}. Sums/extremes are exact arbitrary-precision ints
     reconstructed from the device's byte-column sums / biased limbs."""
-    import time as _time
     from yugabyte_tpu.docdb.scan_spec import PushdownUnsupported
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
@@ -956,7 +947,6 @@ def aggregate_sources(sources, read_ht_value: int, spec,
     (lo_w, lo_l, hi_w, hi_l, up_inf, up_trunc,
      _lo_exact, _hi_exact) = _bound_operands(staged, lower_key, upper_key)
     bkey = _check_pushdown_bucket(staged.n_pad, "scan_agg")
-    t0 = _time.monotonic()
     try:
         device_faults.maybe_fault("dispatch")
         out = _scan_agg_fused(
@@ -973,8 +963,7 @@ def aggregate_sources(sources, read_ht_value: int, spec,
     except Exception as e:  # noqa: BLE001 — classified below
         _contain_pushdown_fault(e, bkey, "scan_agg")
         raise
-    record_kernel_dispatch("kernel_scan_agg", staged.n, staged.n_pad,
-                           (_time.monotonic() - t0) * 1e3)
+    record_kernel_dispatch("kernel_scan_agg", staged.n, staged.n_pad)
     _record_bucket_dispatch("agg", staged.n_pad)
     m = pushdown_metrics()
     m["agg"].increment()

@@ -317,23 +317,22 @@ def distributed_compact_with_outputs(slab, params: GCParams, mesh: Mesh,
 def _distributed_compact_impl(slab, params: GCParams, mesh: Mesh,
                               axis: str, capacity_factor: float,
                               want_outputs: bool):
-    import time as _time
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.ops.run_merge import _donation_supported
-    from yugabyte_tpu.utils.metrics import (record_kernel_dispatch,
-                                            record_pipeline_stage)
-    t0 = _time.monotonic()
+    from yugabyte_tpu.utils.metrics import (pipeline_span,
+                                            record_kernel_dispatch)
     n_shards = mesh.devices.size
-    cols_dev, n_local = stage_sharded_cols(slab, mesh, axis)
-    cutoff = params.history_cutoff_ht
-    cutoff_phys = cutoff >> 12
-    cut_args = (jnp.uint32(cutoff >> 32), jnp.uint32(cutoff & 0xFFFFFFFF),
-                jnp.uint32(cutoff_phys >> 20),
-                jnp.uint32(cutoff_phys & 0xFFFFF))
     # ONE host stage per job: pack + upload happen once, regardless of
     # how many capacity-doubling retries follow (the old recursive form
     # re-packed per attempt and double-counted this stage)
-    record_pipeline_stage("host", (_time.monotonic() - t0) * 1e3)
+    with pipeline_span("merge_stage", inclusive="host"):
+        cols_dev, n_local = stage_sharded_cols(slab, mesh, axis)
+        cutoff = params.history_cutoff_ht
+        cutoff_phys = cutoff >> 12
+        cut_args = (jnp.uint32(cutoff >> 32),
+                    jnp.uint32(cutoff & 0xFFFFFFFF),
+                    jnp.uint32(cutoff_phys >> 20),
+                    jnp.uint32(cutoff_phys & 0xFFFFF))
     factor = capacity_factor
     while True:
         capacity = _quantized_capacity(n_local, n_shards, factor)
@@ -345,27 +344,27 @@ def _distributed_compact_impl(slab, params: GCParams, mesh: Mesh,
         donate = no_retry and _donation_supported()
         fn = dist_compact_fn(mesh, capacity, params.is_major_compaction,
                              params.retain_deletes, axis, donate)
-        t_dev = _time.monotonic()
-        # fault-injection site: a real XLA compile/dispatch failure of the
-        # sharded program surfaces here (containment in storage/compaction)
-        device_faults.maybe_fault("dispatch")
-        out, keep, mk, overflow, src_idx = fn(cols_dev, *cut_args)
-        if donate:
-            cols_dev = None   # consumed by the launch
-        # kick every shard output's D2H in one async wave (the overflow
-        # word decides retry first, so the big buffers ride the link
-        # while the host inspects the small one)
-        for a in ((keep, mk, src_idx) if want_outputs
-                  else (out, keep, mk, src_idx)):
-            try:
-                a.copy_to_host_async()
-            except (AttributeError, NotImplementedError):  # yblint: contained(backend lacks async D2H; the sync download below covers it)
-                pass
-        device_faults.maybe_fault("result")
-        ovf = bool(np.any(np.asarray(overflow)))
         # the device stage is recorded per ATTEMPT — a failed (overflowed)
         # attempt burns real device wall and must show in the profile
-        record_pipeline_stage("device", (_time.monotonic() - t_dev) * 1e3)
+        with pipeline_span("device"):
+            # fault-injection site: a real XLA compile/dispatch failure of
+            # the sharded program surfaces here (containment in
+            # storage/compaction)
+            device_faults.maybe_fault("dispatch")
+            out, keep, mk, overflow, src_idx = fn(cols_dev, *cut_args)
+            if donate:
+                cols_dev = None   # consumed by the launch
+            # kick every shard output's D2H in one async wave (the overflow
+            # word decides retry first, so the big buffers ride the link
+            # while the host inspects the small one)
+            for a in ((keep, mk, src_idx) if want_outputs
+                      else (out, keep, mk, src_idx)):
+                try:
+                    a.copy_to_host_async()
+                except (AttributeError, NotImplementedError):  # yblint: contained(backend lacks async D2H; the sync download below covers it)
+                    pass
+            device_faults.maybe_fault("result")
+            ovf = bool(np.any(np.asarray(overflow)))
         if not ovf:
             break
         if factor >= _MAX_CAPACITY_FACTOR:
@@ -374,19 +373,17 @@ def _distributed_compact_impl(slab, params: GCParams, mesh: Mesh,
                 f"{_MAX_CAPACITY_FACTOR}x")
         _overflow_retry_counter().increment()
         factor *= 2
-    t_host = _time.monotonic()
-    keep_h = np.asarray(keep)
-    mk_h = np.asarray(mk)
-    src_h = np.asarray(src_idx).astype(np.int64)
-    outputs = None
-    if want_outputs:
-        outputs = DistOutputs(out, keep, mk,
-                              w=int(out.shape[0]) - _ROW_WORDS,
-                              capacity=capacity, n_shards=n_shards)
-    record_pipeline_stage("host", (_time.monotonic() - t_host) * 1e3)
+    with pipeline_span("decision_unpack", inclusive="host"):
+        keep_h = np.asarray(keep)
+        mk_h = np.asarray(mk)
+        src_h = np.asarray(src_idx).astype(np.int64)
+        outputs = None
+        if want_outputs:
+            outputs = DistOutputs(out, keep, mk,
+                                  w=int(out.shape[0]) - _ROW_WORDS,
+                                  capacity=capacity, n_shards=n_shards)
     record_kernel_dispatch("kernel_dist_compact", slab.n,
-                           n_shards * n_local,
-                           (_time.monotonic() - t0) * 1e3)
+                           n_shards * n_local)
     return (out, keep_h, mk_h, src_h), outputs
 
 
@@ -538,11 +535,9 @@ def pooled_merge_gc(mesh: Mesh, jobs: Sequence[Tuple[object, GCParams]],
     launch_merge_gc of the same staged runs: each slot runs the same
     fused program with the same comparator, schedule quantization and
     packed-decision encoding."""
-    import time as _time
     from yugabyte_tpu.ops import device_faults, run_merge
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
 
-    t0 = _time.monotonic()
     n_slots = mesh.devices.size
     assert 0 < len(jobs) <= n_slots, (len(jobs), n_slots)
     k_pad, m, w = (jobs[0][0].k_pad, jobs[0][0].m, jobs[0][0].w)
@@ -635,8 +630,7 @@ def pooled_merge_gc(mesh: Mesh, jobs: Sequence[Tuple[object, GCParams]],
     decisions = [run_merge._decode_packed(packed_h[i], st)
                  for i, (st, _p) in enumerate(jobs)]
     record_kernel_dispatch("kernel_pool_wave",
-                           sum(st.n for st, _p in jobs), n_slots * n,
-                           (_time.monotonic() - t0) * 1e3)
+                           sum(st.n for st, _p in jobs), n_slots * n)
     return PoolWaveHandle(decisions, [st for st, _p in jobs], cols_dev,
                           perm, keep, mk, w, n)
 

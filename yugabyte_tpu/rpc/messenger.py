@@ -34,7 +34,8 @@ from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils import latency as _latency
 from yugabyte_tpu.utils.metrics import ROOT_REGISTRY, MetricRegistry
 from yugabyte_tpu.utils.status import Code, Status, StatusError
-from yugabyte_tpu.utils.trace import TRACE, Trace, current_trace_context
+from yugabyte_tpu.utils.trace import (TRACE, Trace, current_trace_context,
+                                      span)
 
 flags.define_flag("rpc_use_tls", False,
                   "mutual TLS on every RPC connection (ref "
@@ -436,17 +437,18 @@ class _ClientConnection:
                 req_msg[LAT_HEADER_KEY] = lat_hdr
             if budget.trace_id is None and trace_ctx is not None:
                 budget.trace_id = trace_ctx.get("trace_id")
-        t_enc = time.monotonic()
         try:
-            _send_message(self.sock, self.write_lock, req_msg)
+            with _latency.stage_span(_latency.STAGE_WIRE_ENCODE):
+                _send_message(self.sock, self.write_lock, req_msg)
         except OSError as e:
             with self.lock:
                 self.pending.pop(call_id, None)
             raise ServiceUnavailable(f"{self.addr}: {e}") from e
-        if budget is not None:
-            budget.record(_latency.STAGE_WIRE_ENCODE,
-                          (time.monotonic() - t_enc) * 1e3)
-        if not waiter["event"].wait(timeout=timeout_s):
+        # the caller blocked on the response: named, so a trace does not
+        # read a waiting client as unnamed host work
+        with span("rpc/await_response"):
+            answered = waiter["event"].wait(timeout=timeout_s)
+        if not answered:
             with self.lock:
                 self.pending.pop(call_id, None)
             raise RpcTimeout(f"{svc}.{mth} to {self.addr} "
@@ -823,7 +825,11 @@ class Messenger:
                             queue_ms=queue_ms)
         resp["id"] = req["id"]
         try:
-            _send_message(conn, write_lock, resp)
+            # after the stage map was frozen into the response: the
+            # response's encode + send is on the profiler only (for the
+            # client it is part of wire_transfer)
+            with span("rpc/respond"):
+                _send_message(conn, write_lock, resp)
         except OSError as e:
             # Caller gone (closed its connection / died mid-call): the
             # response is dropped like an expired call. NOT silent — the
@@ -881,17 +887,18 @@ class Messenger:
             budget.record(_latency.STAGE_RPC_QUEUE, queue_ms)
             token = _latency.use_budget(budget)
         resp = None
-        t0 = time.monotonic()
+        # the handler's whole call: "yb/rpc/handler" on the profiler
+        handler = span("rpc/handler")
         try:
             # request-scoped trace: handler TRACE() calls land in /tracez.
             # An inbound trace header is ADOPTED, stitching this handler
             # span into the caller's distributed trace.
-            with Trace.from_wire_context(trace_ctx,
-                                         f"{svc}.{mth}") as span:
-                entry["trace_id"] = span.trace_id
+            with handler, Trace.from_wire_context(
+                    trace_ctx, f"{svc}.{mth}") as req_trace:
+                entry["trace_id"] = req_trace.trace_id
                 resp = self._invoke_inner(svc, mth, args)
         finally:
-            wall_ms = (time.monotonic() - t0) * 1e3
+            wall_ms = handler.ms
             if token is not None:
                 _latency.clear_budget(token)
             if budget is not None and resp is not None:
@@ -900,8 +907,12 @@ class Messenger:
                 # always sums to queue wait + handler wall
                 in_handler = budget.measured_ms() - budget.stages.get(
                     _latency.STAGE_RPC_QUEUE, 0.0)
-                budget.record(_latency.STAGE_SERVER_OTHER,
-                              wall_ms - in_handler)
+                other_ms = wall_ms - in_handler
+                budget.record(_latency.STAGE_SERVER_OTHER, other_ms)
+                # ...and what the residual's own sub-stages leave of it
+                budget.record_sub(
+                    _latency.SUB_SERVER_REST,
+                    other_ms - budget.sub_ms(_latency.STAGE_SERVER_OTHER))
                 resp[LAT_HEADER_KEY] = budget.to_wire()
             self._method_histogram(svc, mth).increment(wall_ms)
             # entry is fully populated BEFORE it is published — rpcz()

@@ -171,34 +171,14 @@ class CompactionResult:
     tombstones_written: int = 0
 
 
-def run_compaction_job(inputs: Sequence[SSTReader], out_dir: str,
-                       new_file_id, history_cutoff_ht: int, is_major: bool,
-                       retain_deletes: bool = False, device=None,
-                       block_entries: Optional[int] = None, device_cache=None,
-                       input_ids: Optional[Sequence[int]] = None,
-                       mesh=None, offload_policy=None, run_cache=None,
-                       _no_combined: bool = False,
-                       cancel=None) -> CompactionResult:
-    """The compaction job (ref: CompactionJob::Run, compaction_job.cc:442).
-
-    new_file_id: callable returning the next file id (VersionSet.new_file_id).
-    device_cache + input_ids: when set, input key columns come from (or are
-    written through to) the HBM-resident slab cache — host->device upload is
-    skipped for cache hits; values always stream from disk on the host side.
-    mesh: a jax.sharding.Mesh over >1 device — jobs at or above
-    distributed_compaction_min_rows fan their subcompactions across it
-    (parallel/dist_compact.py), the mesh analog of the reference's
-    subcompaction threads (compaction_job.cc:456-468).
-    cancel: a utils/cancellation.CancellationToken — DB shutdown or a
-    tablet-FAILED transition aborts the job at the next stage boundary
-    (OperationCancelled; partial outputs are cleaned up, nothing is
-    installed).
-    """
-    if cancel is not None:
-        cancel.check()
-    all_inputs = list(inputs)
-    orig_input_ids = list(input_ids) if input_ids is not None else None
-    board_key = None  # (board, family, qkey) when the board gated native
+def _route_job(all_inputs, input_ids, device, device_cache, mesh,
+               offload_policy, _no_combined: bool):
+    """run_compaction_job's routing prelude: (device, board_key,
+    combined) — `device` turned "native" where the health board says so,
+    `board_key` = (board, family, qkey) when the board gated native,
+    `combined` = "dist" / "device_native" when a device+native job takes
+    this one."""
+    board_key = None
     if (offload_policy is not None and device is not None
             and device != "native" and not _no_combined):
         # Measured device-vs-native routing (VERDICT r3 #2): auto-offload
@@ -247,21 +227,59 @@ def run_compaction_job(inputs: Sequence[SSTReader], out_dir: str,
         if (native_engine.available() and not get_env().encrypted
                 and not force_radix
                 and not any(r.props.has_deep for r in all_inputs)):
-            if wants_dist:
-                # mesh-sized job: distributed decisions + the SAME native
-                # byte shell / streaming writer as the single-device path,
-                # so sharded outputs stay byte-identical
-                return run_compaction_job_dist_native(
-                    all_inputs, out_dir, new_file_id, history_cutoff_ht,
-                    is_major, retain_deletes, device=device,
-                    block_entries=block_entries, device_cache=device_cache,
-                    input_ids=orig_input_ids, mesh=mesh, cancel=cancel)
-            return run_compaction_job_device_native(
+            return device, board_key, "dist" if wants_dist \
+                else "device_native"
+    return device, board_key, None
+
+
+def run_compaction_job(inputs: Sequence[SSTReader], out_dir: str,
+                       new_file_id, history_cutoff_ht: int, is_major: bool,
+                       retain_deletes: bool = False, device=None,
+                       block_entries: Optional[int] = None, device_cache=None,
+                       input_ids: Optional[Sequence[int]] = None,
+                       mesh=None, offload_policy=None, run_cache=None,
+                       _no_combined: bool = False,
+                       cancel=None) -> CompactionResult:
+    """The compaction job (ref: CompactionJob::Run, compaction_job.cc:442).
+
+    new_file_id: callable returning the next file id (VersionSet.new_file_id).
+    device_cache + input_ids: when set, input key columns come from (or are
+    written through to) the HBM-resident slab cache — host->device upload is
+    skipped for cache hits; values always stream from disk on the host side.
+    mesh: a jax.sharding.Mesh over >1 device — jobs at or above
+    distributed_compaction_min_rows fan their subcompactions across it
+    (parallel/dist_compact.py), the mesh analog of the reference's
+    subcompaction threads (compaction_job.cc:456-468).
+    cancel: a utils/cancellation.CancellationToken — DB shutdown or a
+    tablet-FAILED transition aborts the job at the next stage boundary
+    (OperationCancelled; partial outputs are cleaned up, nothing is
+    installed).
+    """
+    from yugabyte_tpu.utils.metrics import pipeline_span
+    if cancel is not None:
+        cancel.check()
+    all_inputs = list(inputs)
+    orig_input_ids = list(input_ids) if input_ids is not None else None
+    with pipeline_span("routing"):
+        device, board_key, combined = _route_job(
+            all_inputs, input_ids, device, device_cache, mesh,
+            offload_policy, _no_combined)
+    if combined is not None:
+        if combined == "dist":
+            # mesh-sized job: distributed decisions + the SAME native
+            # byte shell / streaming writer as the single-device path,
+            # so sharded outputs stay byte-identical
+            return run_compaction_job_dist_native(
                 all_inputs, out_dir, new_file_id, history_cutoff_ht,
                 is_major, retain_deletes, device=device,
                 block_entries=block_entries, device_cache=device_cache,
-                input_ids=orig_input_ids, run_cache=run_cache,
-                cancel=cancel)
+                input_ids=orig_input_ids, mesh=mesh, cancel=cancel)
+        return run_compaction_job_device_native(
+            all_inputs, out_dir, new_file_id, history_cutoff_ht,
+            is_major, retain_deletes, device=device,
+            block_entries=block_entries, device_cache=device_cache,
+            input_ids=orig_input_ids, run_cache=run_cache,
+            cancel=cancel)
     inputs, dropped = filter_expired_inputs(
         inputs, history_cutoff_ht, is_major, retain_deletes)
     dropped_rows = sum(r.props.n_entries for r in dropped)
@@ -457,34 +475,36 @@ class _StreamingNativeWriter:
         self.ranges: List[Tuple[int, int]] = []
 
     def _write_span(self, start: int, end: int, more_coming: bool) -> None:
-        import time as _time
         from yugabyte_tpu.storage.sst import data_file_name, write_base_file
-        from yugabyte_tpu.utils.metrics import record_pipeline_stage
+        from yugabyte_tpu.utils.metrics import pipeline_span
         if self._cancel is not None:
             # file-split boundary: the clean abort point of stage C —
             # already-written files are swept by the caller's unwind
             self._cancel.check()
-        t0 = _time.monotonic()
-        fid = self._new_file_id()
-        base_path = os.path.join(self._out_dir, f"{fid:06d}.sst")
-        size, index, hashes, fk, lk = self._job.write_output(
-            start, end, data_file_name(base_path), self._block_entries,
-            compress=sst_compression_enabled(),
-            tombstone_value=self._tombstone_value)
-        lindex = (self._lindex_for_span(start, end)
-                  if self._lindex_for_span is not None else None)
-        props = write_base_file(base_path, index, end - start, hashes,
-                                fk, lk, self._fr, size,
-                                has_deep=self._has_deep, lindex=lindex)
-        self.outputs.append((fid, base_path, props))
-        self.ranges.append((start, end))
-        record_pipeline_stage("write", (_time.monotonic() - t0) * 1e3)
+        with pipeline_span("write"):
+            fid = self._new_file_id()
+            base_path = os.path.join(self._out_dir, f"{fid:06d}.sst")
+            size, index, hashes, fk, lk = self._job.write_output(
+                start, end, data_file_name(base_path), self._block_entries,
+                compress=sst_compression_enabled(),
+                tombstone_value=self._tombstone_value)
+            # (the learned-index gather and fit are spans of their own
+            # inside: `write` is the shell encode + file I/O around them)
+            lindex = (self._lindex_for_span(start, end)
+                      if self._lindex_for_span is not None else None)
+            props = write_base_file(base_path, index, end - start, hashes,
+                                    fk, lk, self._fr, size,
+                                    has_deep=self._has_deep, lindex=lindex)
+            self.outputs.append((fid, base_path, props))
+            self.ranges.append((start, end))
         if self._on_span is not None:
-            self._on_span(fid, base_path, start, end)
+            with pipeline_span("cache_install"):
+                self._on_span(fid, base_path, start, end)
         if self._limiter is not None and more_coming:
             # pace between files; no debt-sleep after the last one (it
             # would only delay install while writing nothing)
-            self._limiter.acquire(props.data_size + props.base_size)
+            with pipeline_span("pace"):
+                self._limiter.acquire(props.data_size + props.base_size)
 
     def feed(self, n_available: int) -> None:
         # strictly >: an exactly-full final span must come from finish()
@@ -538,15 +558,19 @@ def _run_native_job(inputs: Sequence[SSTReader], out_dir: str, new_file_id,
     C++ (native/compaction_engine.cc); Python assembles base files and
     frontiers. Same outputs as the Python shell, ~10x less wall."""
     from yugabyte_tpu.storage import native_engine
+    from yugabyte_tpu.utils.metrics import pipeline_span
 
     with native_engine.NativeCompactionJob() as job:
-        for r in inputs:
-            if cancel is not None:
-                cancel.check()
-            with open(r.data_path, "rb") as f:
-                job.add_input(f.read(), r.block_handles)
-        rows_in = job.prepare()
-        rows_out = job.merge(history_cutoff_ht, is_major, retain_deletes)
+        with pipeline_span("native_ingest"):
+            for r in inputs:
+                if cancel is not None:
+                    cancel.check()
+                with open(r.data_path, "rb") as f:
+                    job.add_input(f.read(), r.block_handles)
+            rows_in = job.prepare()
+        with pipeline_span("native_merge"):
+            rows_out = job.merge(history_cutoff_ht, is_major,
+                                 retain_deletes)
         fr = _merge_frontiers(
             [r.props.frontier for r in (frontier_inputs or inputs)],
             history_cutoff_ht)
@@ -555,6 +579,55 @@ def _run_native_job(inputs: Sequence[SSTReader], out_dir: str, new_file_id,
             has_deep=any(r.props.has_deep for r in inputs),
             cancel=cancel)
     return CompactionResult(outputs, rows_in, rows_out)
+
+
+def _route_device_native(all_inputs, orig_input_ids,
+                         history_cutoff_ht: int, is_major: bool,
+                         retain_deletes: bool):
+    """run_compaction_job_device_native's routing prelude: (verdict,
+    inputs, input_ids, dropped_rows, qkey, board). `verdict` is "device"
+    (go on: the filtered inputs with their re-aligned cache ids), "empty"
+    (nothing left after expiry filtering), "encrypted" / "skewed" (another
+    job form takes it) or "parked" (the health board holds the bucket)."""
+    from yugabyte_tpu.ops import run_merge
+    from yugabyte_tpu.utils.env import get_env
+    if get_env().encrypted:
+        return "encrypted", all_inputs, orig_input_ids, 0, None, None
+    id_of = ({id(r): fid for r, fid in zip(all_inputs, orig_input_ids)}
+             if orig_input_ids is not None else None)
+    inputs, dropped = filter_expired_inputs(
+        all_inputs, history_cutoff_ht, is_major, retain_deletes)
+    dropped_rows = sum(r.props.n_entries for r in dropped)
+    inputs = [r for r in inputs if r.props.n_entries]
+    if not inputs:
+        return "empty", inputs, None, dropped_rows, None, None
+    # cache ids re-aligned to the filtered list (see run_compaction_job)
+    input_ids = ([id_of[id(r)] for r in inputs]
+                 if id_of is not None else None)
+    if run_merge.run_layout_inflation(
+            [r.props.n_entries for r in inputs]) > 2.0:
+        return "skewed", inputs, input_ids, dropped_rows, None, None
+    from yugabyte_tpu.storage import offload_policy as offload_policy_mod
+    from yugabyte_tpu.utils.trace import TRACE
+    qkey = offload_policy_mod.bucket_key(
+        run_merge.packed_run_ns([r.props.n_entries for r in inputs]))
+    surface = offload_policy_mod.declared_surface_keys()
+    if surface and qkey not in surface:
+        # reachable shape the committed manifest never declared: count it
+        # (the compile-surface budget reviews growth; this is the live
+        # signal that the lattice and reality have diverged)
+        from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
+        ROOT_REGISTRY.entity("server", "offload_policy").counter(
+            "compaction_offsurface_bucket_total",
+            "device-native compactions whose shape bucket is outside "
+            "the declared kernel compile surface").increment()
+        TRACE("compaction: bucket k_pad=%d m=%d is outside the declared "
+              "compile surface", *qkey)
+    from yugabyte_tpu.storage.bucket_health import health_board
+    board = health_board()
+    verdict = ("device" if board.allow_device("run_merge_fused", qkey)
+               else "parked")
+    return verdict, inputs, input_ids, dropped_rows, qkey, board
 
 
 def run_compaction_job_device_native(
@@ -577,40 +650,23 @@ def run_compaction_job_device_native(
     Caller contract: inputs must not contain deep documents (FLAG_DEEP —
     depth > row+column); run_compaction_job routes those to the native
     merge, which carries the full overwrite stack."""
-    from yugabyte_tpu.ops import run_merge
-    from yugabyte_tpu.ops.merge_gc import stage_slab
-    from yugabyte_tpu.storage import native_engine
-    from yugabyte_tpu.utils.env import get_env
-
-    if get_env().encrypted:
-        # C++ shell bypasses the Env: under encryption take the Env-aware
-        # device path instead
-        return run_compaction_job(inputs, out_dir, new_file_id,
-                                  history_cutoff_ht, is_major,
-                                  retain_deletes, device=device,
-                                  block_entries=block_entries,
-                                  device_cache=device_cache,
-                                  input_ids=input_ids,
-                                  _no_combined=True, cancel=cancel)
+    from yugabyte_tpu.utils.metrics import pipeline_span
+    from yugabyte_tpu.utils.trace import TRACE
 
     all_inputs = list(inputs)
     orig_input_ids = list(input_ids) if input_ids is not None else None
-    id_of = ({id(r): fid for r, fid in zip(all_inputs, input_ids)}
-             if input_ids is not None else None)
-    inputs, dropped = filter_expired_inputs(
-        inputs, history_cutoff_ht, is_major, retain_deletes)
-    dropped_rows = sum(r.props.n_entries for r in dropped)
-    inputs = [r for r in inputs if r.props.n_entries]
-    if not inputs:
+    with pipeline_span("routing"):
+        verdict, inputs, input_ids, dropped_rows, qkey, board = \
+            _route_device_native(all_inputs, orig_input_ids,
+                                 history_cutoff_ht, is_major,
+                                 retain_deletes)
+    if verdict == "empty":
         return CompactionResult([], dropped_rows, 0)
-    # cache ids re-aligned to the filtered list (see run_compaction_job)
-    input_ids = ([id_of[id(r)] for r in inputs]
-                 if id_of is not None else None)
-    if run_merge.run_layout_inflation(
-            [r.props.n_entries for r in inputs]) > 2.0:
-        # skewed run sizes would pad every run to the largest bucket on
-        # device — take the radix-kernel job instead (same outputs;
-        # original input list with its ORIGINAL id pairing)
+    if verdict in ("encrypted", "skewed"):
+        # encrypted: the C++ shell bypasses the Env, so take the Env-aware
+        # device path; skewed run sizes would pad every run to the largest
+        # bucket on device, so take the radix-kernel job (same outputs).
+        # Either way: the original input list with its ORIGINAL id pairing
         return run_compaction_job(all_inputs, out_dir, new_file_id,
                                   history_cutoff_ht, is_major,
                                   retain_deletes, device=device,
@@ -618,26 +674,7 @@ def run_compaction_job_device_native(
                                   device_cache=device_cache,
                                   input_ids=orig_input_ids,
                                   _no_combined=True, cancel=cancel)
-
-    from yugabyte_tpu.storage import offload_policy as offload_policy_mod
-    from yugabyte_tpu.utils.trace import TRACE
-    qkey = offload_policy_mod.bucket_key(
-        run_merge.packed_run_ns([r.props.n_entries for r in inputs]))
-    surface = offload_policy_mod.declared_surface_keys()
-    if surface and qkey not in surface:
-        # reachable shape the committed manifest never declared: count it
-        # (the compile-surface budget reviews growth; this is the live
-        # signal that the lattice and reality have diverged)
-        from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
-        ROOT_REGISTRY.entity("server", "offload_policy").counter(
-            "compaction_offsurface_bucket_total",
-            "device-native compactions whose shape bucket is outside "
-            "the declared kernel compile surface").increment()
-        TRACE("compaction: bucket k_pad=%d m=%d is outside the declared "
-              "compile surface", *qkey)
-    from yugabyte_tpu.storage.bucket_health import health_board
-    board = health_board()
-    if not board.allow_device("run_merge_fused", qkey):
+    if verdict == "parked":
         # QUARANTINED (recent fault / sticky mismatch) or DEGRADED with
         # no probe slot: native-only until the board re-opens the bucket
         # (surfaced on /healthz and /compactionz)
@@ -807,15 +844,18 @@ class _ResidentSpanInstaller:
 
     def _gather_span(self, start: int, end: int):
         from yugabyte_tpu.ops import run_merge
+        from yugabyte_tpu.utils.metrics import pipeline_span
         st = self._span_cache.pop((start, end), None)
         if st is not None:
             return st
         if self._pos_all is None:
             # one survivor-position scan per job; consumes (donates) the
             # keep mask on backends that honor donation
-            self._pos_all = run_merge.survivor_positions(self.handle)
-        return run_merge.gather_staged_output_span(
-            self.handle, self._pos_all, start, end)
+            with pipeline_span("survivor_positions"):
+                self._pos_all = run_merge.survivor_positions(self.handle)
+        with pipeline_span("span_gather"):
+            return run_merge.gather_staged_output_span(
+                self.handle, self._pos_all, start, end)
 
     def lindex_for_span(self, start: int, end: int):
         """Learned-index fit over the survivor span's staged columns —
@@ -827,11 +867,13 @@ class _ResidentSpanInstaller:
         model (it is advisory)."""
         from yugabyte_tpu.ops import point_read
         from yugabyte_tpu.utils import flags as _flags
+        from yugabyte_tpu.utils.metrics import pipeline_span
         if not _flags.get_flag("sst_learned_index") or not self._ready():
             return None
         st = self._gather_span(start, end)
         self._span_cache[(start, end)] = st
-        return point_read.fit_learned_index_device(st)
+        with pipeline_span("lindex_fit"):
+            return point_read.fit_learned_index_device(st)
 
     def on_span(self, fid: int, base_path: str, start: int, end: int
                 ) -> None:
@@ -949,16 +991,16 @@ def _device_native_body(
     from yugabyte_tpu.storage import integrity, native_engine
 
     import threading
-    import time as _time
-    from yugabyte_tpu.utils.metrics import record_pipeline_stage
+    from yugabyte_tpu.utils.metrics import pipeline_span
 
     # Online shadow verification (sampled): the native heap-merge oracle
     # re-derives this job's survivor decisions on its own thread
     # (overlapping the device work below); every decision chunk is
     # compared before its bytes can install. A mismatch unwinds the
     # attempt, quarantines the bucket and re-runs the job natively.
-    shadow = integrity.maybe_shadow_verifier(
-        inputs, history_cutoff_ht, is_major, retain_deletes)
+    with pipeline_span("shadow_setup"):
+        shadow = integrity.maybe_shadow_verifier(
+            inputs, history_cutoff_ht, is_major, retain_deletes)
 
     with native_engine.NativeCompactionJob() as job:
         # -- stage A (host): the native shell ingests the input bytes on
@@ -969,7 +1011,14 @@ def _device_native_body(
         ingest = {"rows_in": None, "err": None}
 
         def _ingest_inputs():
-            t0 = _time.monotonic()
+            # a root of its own thread, under the legacy `host` only: its
+            # wall overlaps the job thread's stages (which name the wait
+            # for it `ingest_join`)
+            with pipeline_span("shell_ingest", inclusive="host",
+                               stage=None, parent=None):
+                _ingest_inputs_inner()
+
+        def _ingest_inputs_inner():
             try:
                 pinned = False
                 if cached_ids is not None:
@@ -998,9 +1047,6 @@ def _device_native_body(
                     ingest["rows_in"] = job.prepare()
             except BaseException as e:  # noqa: BLE001  # yblint: contained(parked in ingest['err'], re-raised on the join path)
                 ingest["err"] = e
-            finally:
-                record_pipeline_stage(
-                    "host", (_time.monotonic() - t0) * 1e3)
 
         ingest_thread = None
         if pipeline:
@@ -1015,65 +1061,69 @@ def _device_native_body(
             # fused merge+GC — asynchronously, chunked and double-buffered
             # inside launch_merge_gc, with the carved chunk buffers
             # donated so XLA reuses their HBM in place.
-            t_stage = _time.monotonic()
-            misses = [i for i, (r, fid) in enumerate(
-                zip(inputs, input_ids or [None] * len(inputs)))
-                if not (device_cache is not None and fid is not None
-                        and device_cache.contains(fid))]
-            slabs_by_idx = {}
-            if pipeline and len(misses) > 1:
-                # cold inputs: decode SST blocks in parallel host threads
-                # (read_all is numpy + file I/O, GIL-light); uploads stay
-                # serial below — device_put ordering is the staging order
-                def _read(i):
-                    try:
-                        slabs_by_idx[i] = inputs[i].read_all()
-                    except Exception as e:  # noqa: BLE001  # yblint: contained(decode retried serially below; a persistent fault raises there)
-                        # a dead reader thread must not take the whole
-                        # job down with a bare stderr traceback — the
-                        # serial fallback re-reads this input and is the
-                        # path that surfaces a real disk fault
-                        from yugabyte_tpu.utils.trace import TRACE
-                        TRACE("compaction: cold-miss decode of %s failed "
-                              "on the reader thread (%s); serial path "
-                              "will retry", inputs[i].data_path, e)
-                readers = [threading.Thread(target=_read, args=(i,),
-                                            daemon=True) for i in misses]
-                for t in readers:
-                    t.start()
-                for t in readers:
-                    t.join()
-            staged_list = []
-            for i, (r, fid) in enumerate(
-                    zip(inputs, input_ids or [None] * len(inputs))):
-                if cancel is not None:
-                    cancel.check()  # before each per-input device upload
-                st = device_cache.get(fid) if (device_cache is not None
-                                               and fid is not None) else None
-                if st is None:
-                    slab = slabs_by_idx.get(i)
-                    if slab is None:
-                        slab = r.read_all()
-                    st = (device_cache.stage(fid, slab)
-                          if device_cache is not None and fid is not None
-                          else stage_slab(slab, device))
-                if device_cache is not None and fid is not None \
-                        and device_cache.pin(fid):
-                    # pinned for the whole attempt (released in the
-                    # attempt's finally): capacity eviction can never
-                    # race this running merge off its inputs
-                    state["pins"].append(fid)
-                staged_list.append(st)
-            staged_runs = run_merge.stage_runs_from_staged(staged_list)
-            params = GCParams(history_cutoff_ht, is_major, retain_deletes)
-            handle = run_merge.launch_merge_gc(staged_runs, params)
-            record_pipeline_stage("host",
-                                  (_time.monotonic() - t_stage) * 1e3)
+            with pipeline_span("ingest", inclusive="host"):
+                misses = [i for i, (r, fid) in enumerate(
+                    zip(inputs, input_ids or [None] * len(inputs)))
+                    if not (device_cache is not None and fid is not None
+                            and device_cache.contains(fid))]
+                slabs_by_idx = {}
+                if pipeline and len(misses) > 1:
+                    # cold inputs: decode SST blocks in parallel host threads
+                    # (read_all is numpy + file I/O, GIL-light); uploads stay
+                    # serial below — device_put ordering is the staging order
+                    def _read(i):
+                        try:
+                            slabs_by_idx[i] = inputs[i].read_all()
+                        except Exception as e:  # noqa: BLE001  # yblint: contained(decode retried serially below; a persistent fault raises there)
+                            # a dead reader thread must not take the whole
+                            # job down with a bare stderr traceback — the
+                            # serial fallback re-reads this input and is the
+                            # path that surfaces a real disk fault
+                            from yugabyte_tpu.utils.trace import TRACE
+                            TRACE("compaction: cold-miss decode of %s failed "
+                                  "on the reader thread (%s); serial path "
+                                  "will retry", inputs[i].data_path, e)
+                    readers = [threading.Thread(target=_read, args=(i,),
+                                                daemon=True) for i in misses]
+                    for t in readers:
+                        t.start()
+                    for t in readers:
+                        t.join()
+                staged_list = []
+                for i, (r, fid) in enumerate(
+                        zip(inputs, input_ids or [None] * len(inputs))):
+                    if cancel is not None:
+                        cancel.check()  # before each per-input device upload
+                    with pipeline_span("stage_input"):
+                        st = device_cache.get(fid) if (
+                            device_cache is not None
+                            and fid is not None) else None
+                        if st is None:
+                            slab = slabs_by_idx.get(i)
+                            if slab is None:
+                                slab = r.read_all()
+                            st = (device_cache.stage(fid, slab)
+                                  if device_cache is not None
+                                  and fid is not None
+                                  else stage_slab(slab, device))
+                        if device_cache is not None and fid is not None \
+                                and device_cache.pin(fid):
+                            # pinned for the whole attempt (released in the
+                            # attempt's finally): capacity eviction can never
+                            # race this running merge off its inputs
+                            state["pins"].append(fid)
+                    staged_list.append(st)
+                with pipeline_span("merge_stage"):
+                    staged_runs = run_merge.stage_runs_from_staged(staged_list)
+                params = GCParams(history_cutoff_ht, is_major, retain_deletes)
+                with pipeline_span("merge_launch"):
+                    handle = run_merge.launch_merge_gc(staged_runs, params)
         finally:
             # the thread calls into the C++ job; it MUST finish before any
             # unwind can free the job (use-after-free otherwise)
             if ingest_thread is not None:
-                ingest_thread.join()
+                with pipeline_span("ingest_join"):
+                    ingest_thread.join()
         if ingest_thread is None:
             _ingest_inputs()
         if ingest["err"] is not None:
@@ -1111,29 +1161,33 @@ def _device_native_body(
             for perm_c, keep_c, mk_c in handle.result_iter():
                 if cancel is not None:
                     cancel.check()  # chunk boundary: abort in-flight job
-                surv = perm_c[keep_c]
-                mk_surv = mk_c[keep_c]
-                # silent-corruption injection point (tests): a flipped
-                # decision lands in the SST unless shadow verify is on
-                device_faults.maybe_flip_survivors(surv, mk_surv)
+                with pipeline_span("survivor_select"):
+                    surv = perm_c[keep_c]
+                    mk_surv = mk_c[keep_c]
+                    # silent-corruption injection point (tests): a flipped
+                    # decision lands in the SST unless shadow verify is on
+                    device_faults.maybe_flip_survivors(surv, mk_surv)
                 if shadow is not None:
                     shadow.check_chunk(surv, mk_surv)
                 tombstones_written += int(np.count_nonzero(mk_surv))
-                job.append_survivors(surv, mk_surv)
-                writer.feed(job.n_survivors)
+                with pipeline_span("shell_feed"):
+                    job.append_survivors(surv, mk_surv)
+                    writer.feed(job.n_survivors)
             rows_out = job.n_survivors
             if shadow is not None:
                 shadow.finish(rows_out)  # before the tail files write
             outputs, ranges = writer.finish(rows_out)
         else:
             perm, keep, mk = handle.result()
-            surv = perm[keep]
-            mk_surv = mk[keep]
-            device_faults.maybe_flip_survivors(surv, mk_surv)
+            with pipeline_span("survivor_select"):
+                surv = perm[keep]
+                mk_surv = mk[keep]
+                device_faults.maybe_flip_survivors(surv, mk_surv)
             if shadow is not None:
                 shadow.check_chunk(surv, mk_surv)
             tombstones_written = int(np.count_nonzero(mk_surv))
-            job.set_survivors(surv, mk_surv)
+            with pipeline_span("shell_feed"):
+                job.set_survivors(surv, mk_surv)
             rows_out = job.n_survivors
             if shadow is not None:
                 shadow.finish(rows_out)
@@ -1142,10 +1196,12 @@ def _device_native_body(
             # run-cache write-through: exported survivors are
             # byte-equivalent to re-decoding the files just written, so
             # the NEXT compaction over these outputs starts all-cached
-            for (fid, _base, _props), (start, end) in zip(outputs, ranges):
-                rid = job.export_run(start, end, tombstone_value)
-                run_cache.put(fid, rid,
-                              native_engine.runcache_entry_bytes(rid))
+            with pipeline_span("run_export"):
+                for (fid, _base, _props), (start, end) in zip(outputs,
+                                                              ranges):
+                    rid = job.export_run(start, end, tombstone_value)
+                    run_cache.put(fid, rid,
+                                  native_engine.runcache_entry_bytes(rid))
     if installer is not None:
         # spans a chunked stream deferred (parent-domain device arrays
         # only exist once every chunk's decisions landed) install here;
@@ -1153,7 +1209,8 @@ def _device_native_body(
         # disk. Either way the entries were gathered ON DEVICE — zero
         # host->device transfer — and `ranges` are the spans the shell
         # actually wrote.
-        installer.finish()
+        with pipeline_span("installer_finish"):
+            installer.finish()
     return CompactionResult(outputs, rows_in + dropped_rows, rows_out,
                             tombstones_written=tombstones_written)
 
@@ -1193,12 +1250,14 @@ class _DeviceCodecWriter:
 
     def _gather_span(self, start: int, end: int):
         from yugabyte_tpu.ops import run_merge
+        from yugabyte_tpu.utils.metrics import pipeline_span
         h = self._handle
         if getattr(h, "_perm_dev", None) is None \
                 and hasattr(h, "to_parent_products"):
             # chunked stream: decisions fully drained before stage C, so
             # the parent-domain device arrays can rebuild here
-            h.to_parent_products()
+            with pipeline_span("parent_products"):
+                h.to_parent_products()
         inst = self._installer
         if inst is not None:
             st = inst._gather_span(start, end)
@@ -1207,52 +1266,55 @@ class _DeviceCodecWriter:
             inst._span_cache[(start, end)] = st
             return st, inst.lindex_for_span(start, end)
         if self._pos_all is None:
-            self._pos_all = run_merge.survivor_positions(h)
-        return run_merge.gather_staged_output_span(
-            h, self._pos_all, start, end), None
+            with pipeline_span("survivor_positions"):
+                self._pos_all = run_merge.survivor_positions(h)
+        with pipeline_span("span_gather"):
+            return run_merge.gather_staged_output_span(
+                h, self._pos_all, start, end), None
 
     def _write_span(self, surv: np.ndarray, mk: np.ndarray,
                     start: int, end: int, more_coming: bool) -> None:
-        import time as _time
         from yugabyte_tpu.ops import block_codec
         from yugabyte_tpu.storage.sst import (data_file_name, write_base_file,
                                               sst_compression_enabled)
         from yugabyte_tpu.utils.env import get_env
-        from yugabyte_tpu.utils.metrics import record_pipeline_stage
+        from yugabyte_tpu.utils.metrics import pipeline_span
         if self._cancel is not None:
             self._cancel.check()   # file-split boundary: clean abort point
         st, lindex = self._gather_span(start, end)
-        vals = self._values.gather(surv[start:end],
-                                   replace_mask=mk[start:end],
-                                   replacement=self._tombstone_value)
+        with pipeline_span("value_gather"):
+            vals = self._values.gather(surv[start:end],
+                                       replace_mask=mk[start:end],
+                                       replacement=self._tombstone_value)
         blocks, index, hashes, fk, lk = block_codec.encode_span(
             st, end - start, self._w_out, vals, self._block_entries,
             compress=sst_compression_enabled())
-        t0 = _time.monotonic()
-        fid = self._new_file_id()
-        base_path = os.path.join(self._out_dir, f"{fid:06d}.sst")
-        data_path = data_file_name(base_path)
-        if os.path.exists(data_path):
-            os.remove(data_path)   # never append to a stale data file
-        df = get_env().open_append(data_path)
-        try:
-            size = 0
-            for blk in blocks:
-                df.append(blk)
-                size += len(blk)
-            df.flush(fsync=True)
-        finally:
-            df.close()
-        props = write_base_file(base_path, index, end - start, hashes,
-                                fk, lk, self._fr, size,
-                                has_deep=self._has_deep, lindex=lindex)
-        self.outputs.append((fid, base_path, props))
-        self.ranges.append((start, end))
-        record_pipeline_stage("write", (_time.monotonic() - t0) * 1e3)
+        with pipeline_span("write"):
+            fid = self._new_file_id()
+            base_path = os.path.join(self._out_dir, f"{fid:06d}.sst")
+            data_path = data_file_name(base_path)
+            if os.path.exists(data_path):
+                os.remove(data_path)   # never append to a stale data file
+            df = get_env().open_append(data_path)
+            try:
+                size = 0
+                for blk in blocks:
+                    df.append(blk)
+                    size += len(blk)
+                df.flush(fsync=True)
+            finally:
+                df.close()
+            props = write_base_file(base_path, index, end - start, hashes,
+                                    fk, lk, self._fr, size,
+                                    has_deep=self._has_deep, lindex=lindex)
+            self.outputs.append((fid, base_path, props))
+            self.ranges.append((start, end))
         if self._installer is not None:
-            self._installer.on_span(fid, base_path, start, end)
+            with pipeline_span("cache_install"):
+                self._installer.on_span(fid, base_path, start, end)
         if self._limiter is not None and more_coming:
-            self._limiter.acquire(props.data_size + props.base_size)
+            with pipeline_span("pace"):
+                self._limiter.acquire(props.data_size + props.base_size)
 
     def write_all(self, surv: np.ndarray, mk: np.ndarray, rows_out: int
                   ) -> Tuple[List[Tuple[int, str, SSTProps]],
@@ -1308,14 +1370,14 @@ def _device_codec_body(
         new_file_id, history_cutoff_ht: int, is_major: bool,
         retain_deletes: bool, device, block_entries, device_cache,
         cancel, state: dict) -> CompactionResult:
-    import time as _time
     from yugabyte_tpu.ops import block_codec, device_faults, run_merge
     from yugabyte_tpu.ops.slabs import ValueArray
     from yugabyte_tpu.storage import integrity
-    from yugabyte_tpu.utils.metrics import record_pipeline_stage
+    from yugabyte_tpu.utils.metrics import pipeline_span
 
-    shadow = integrity.maybe_shadow_verifier(
-        inputs, history_cutoff_ht, is_major, retain_deletes)
+    with pipeline_span("shadow_setup"):
+        shadow = integrity.maybe_shadow_verifier(
+            inputs, history_cutoff_ht, is_major, retain_deletes)
 
     # -- stage A: raw-byte ingest. One file read + per-block CRC check +
     # zero-copy value slicing per input (block_format.split_raw_block);
@@ -1323,56 +1385,66 @@ def _device_codec_body(
     # cache already holds them — either way no host decode_block runs, so
     # sst_block_decode_total and compaction_ingest_decode_total stay flat
     # even on a COLD chain.
-    t0 = _time.monotonic()
-    staged_list = []
-    values_parts = []
-    rows_in = 0
-    w_out = 1
-    for r, fid in zip(inputs, input_ids or [None] * len(inputs)):
-        if cancel is not None:
-            cancel.check()   # input boundary, like the shell ingest
-        rfb = block_codec.parse_raw_file(r.read_raw(), r.block_handles)
-        values_parts.extend(rfb.value_parts)
-        rows_in += rfb.n
-        w_out = max(w_out, rfb.w)
-        st = device_cache.get(fid) if (device_cache is not None
-                                       and fid is not None) else None
-        if st is None:
-            st = (device_cache.stage_from_raw(fid, rfb)
-                  if device_cache is not None and fid is not None
-                  else block_codec.decode_file_to_staged(rfb, device))
-        if device_cache is not None and fid is not None \
-                and device_cache.pin(fid):
-            state["pins"].append(fid)
-        staged_list.append(st)
-    values = ValueArray.concat(values_parts)
-    record_pipeline_stage("host", (_time.monotonic() - t0) * 1e3)
+    with pipeline_span("ingest", inclusive="host"):
+        staged_list = []
+        values_parts = []
+        rows_in = 0
+        w_out = 1
+        for r, fid in zip(inputs, input_ids or [None] * len(inputs)):
+            if cancel is not None:
+                cancel.check()   # input boundary, like the shell ingest
+            with pipeline_span("raw_read"):
+                raw = r.read_raw()
+            with pipeline_span("raw_parse"):
+                rfb = block_codec.parse_raw_file(raw, r.block_handles)
+            values_parts.extend(rfb.value_parts)
+            rows_in += rfb.n
+            w_out = max(w_out, rfb.w)
+            # a slab-cache hit, or the decode dispatch (`decode` inside)
+            with pipeline_span("stage_input"):
+                st = device_cache.get(fid) if (
+                    device_cache is not None and fid is not None) else None
+                if st is None:
+                    st = (device_cache.stage_from_raw(fid, rfb)
+                          if device_cache is not None and fid is not None
+                          else block_codec.decode_file_to_staged(rfb,
+                                                                 device))
+                if device_cache is not None and fid is not None \
+                        and device_cache.pin(fid):
+                    state["pins"].append(fid)
+            staged_list.append(st)
+        with pipeline_span("value_concat"):
+            values = ValueArray.concat(values_parts)
 
     # -- stage B: the same fused merge+GC launch as the shell path
-    t0 = _time.monotonic()
-    staged_runs = run_merge.stage_runs_from_staged(staged_list)
+    with pipeline_span("merge_stage", inclusive="host"):
+        staged_runs = run_merge.stage_runs_from_staged(staged_list)
     params = GCParams(history_cutoff_ht, is_major, retain_deletes)
-    handle = run_merge.launch_merge_gc(staged_runs, params)
-    record_pipeline_stage("host", (_time.monotonic() - t0) * 1e3)
+    with pipeline_span("merge_launch", inclusive="host"):
+        handle = run_merge.launch_merge_gc(staged_runs, params)
 
     # decisions drain fully before stage C: the survivor indices drive
     # the host value gather, and span gathers need the parent-domain
-    # device arrays (chunked streams only expose them post-drain)
+    # device arrays (chunked streams only expose them post-drain). The
+    # handle's own spans name the wait for the device (`device`) and the
+    # unpack of what came back (`decision_unpack`, `decision_remap`).
     surv_parts, mk_parts = [], []
     for perm_c, keep_c, mk_c in handle.result_iter():
         if cancel is not None:
             cancel.check()
-        surv_c = perm_c[keep_c]
-        mk_surv = mk_c[keep_c]
-        device_faults.maybe_flip_survivors(surv_c, mk_surv)
+        with pipeline_span("survivor_select"):
+            surv_c = perm_c[keep_c]
+            mk_surv = mk_c[keep_c]
+            device_faults.maybe_flip_survivors(surv_c, mk_surv)
         if shadow is not None:
             shadow.check_chunk(surv_c, mk_surv)
         surv_parts.append(surv_c)
         mk_parts.append(mk_surv)
-    surv = (np.concatenate(surv_parts) if surv_parts
-            else np.zeros(0, dtype=np.int64))
-    mk = (np.concatenate(mk_parts) if mk_parts
-          else np.zeros(0, dtype=bool))
+    with pipeline_span("survivor_concat"):
+        surv = (np.concatenate(surv_parts) if surv_parts
+                else np.zeros(0, dtype=np.int64))
+        mk = (np.concatenate(mk_parts) if mk_parts
+              else np.zeros(0, dtype=bool))
     rows_out = int(surv.shape[0])
     if shadow is not None:
         shadow.finish(rows_out)
@@ -1396,7 +1468,8 @@ def _device_codec_body(
     state["writer"] = writer
     outputs, _ranges = writer.write_all(surv, mk, rows_out)
     if installer is not None:
-        installer.finish()
+        with pipeline_span("installer_finish"):
+            installer.finish()
     return CompactionResult(outputs, rows_in + dropped_rows, rows_out,
                             tombstones_written=int(np.count_nonzero(mk)))
 
@@ -1461,7 +1534,7 @@ def run_compaction_job_dist_native(
     from yugabyte_tpu.parallel.dist_compact import (
         _quantized_capacity, distributed_compact_with_outputs)
     from yugabyte_tpu.storage import integrity, native_engine
-    from yugabyte_tpu.utils.metrics import record_pipeline_stage
+    from yugabyte_tpu.utils.metrics import pipeline_span
 
     all_inputs = list(inputs)
     id_of = ({id(r): fid for r, fid in zip(all_inputs, input_ids)}
@@ -1508,20 +1581,20 @@ def run_compaction_job_dist_native(
             ingest = {"rows_in": None, "err": None}
 
             def _ingest_inputs():
-                t0 = _time.monotonic()
-                try:
-                    for r in inputs:
-                        if cancel is not None:
-                            cancel.check()
-                        with open(r.data_path, "rb") as f:
-                            job.add_input(f.read(), r.block_handles)
-                        _ingest_decode_counter().increment()
-                    ingest["rows_in"] = job.prepare()
-                except BaseException as e:  # noqa: BLE001  # yblint: contained(parked in ingest['err'], re-raised on the join path)
-                    ingest["err"] = e
-                finally:
-                    record_pipeline_stage(
-                        "host", (_time.monotonic() - t0) * 1e3)
+                # its own thread's root, legacy `host` only (see
+                # _device_native_body)
+                with pipeline_span("shell_ingest", inclusive="host",
+                                   stage=None, parent=None):
+                    try:
+                        for r in inputs:
+                            if cancel is not None:
+                                cancel.check()
+                            with open(r.data_path, "rb") as f:
+                                job.add_input(f.read(), r.block_handles)
+                            _ingest_decode_counter().increment()
+                        ingest["rows_in"] = job.prepare()
+                    except BaseException as e:  # noqa: BLE001  # yblint: contained(parked in ingest['err'], re-raised on the join path)
+                        ingest["err"] = e
 
             ingest_thread = threading.Thread(
                 target=_ingest_inputs, name="dist-compaction-ingest",
@@ -1538,7 +1611,8 @@ def run_compaction_job_dist_native(
             finally:
                 # the thread calls into the C++ job; it MUST finish
                 # before any unwind can free the job
-                ingest_thread.join()
+                with pipeline_span("ingest_join"):
+                    ingest_thread.join()
             if ingest["err"] is not None:
                 raise ingest["err"]
             rows_in = ingest["rows_in"]

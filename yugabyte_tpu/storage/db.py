@@ -706,7 +706,6 @@ class DB:
                 (_time.monotonic() - t0) * 1e3)
 
     def _multi_get_inner(self, keys, read_ht, doc_key_lens=None):
-        import time as _time
         from yugabyte_tpu.utils import latency as _latency
         read_ht = read_ht or HybridTime.kMax
         if not keys:
@@ -714,17 +713,14 @@ class DB:
         if flags.get_flag("point_read_batched") \
                 and self._device_cache is not None \
                 and self.opts.device not in (None, "native"):
-            t0 = _time.monotonic()
-            res = self._multi_get_device(keys, read_ht, doc_key_lens)
-            _latency.record_stage(_latency.STAGE_DEVICE_DISPATCH,
-                                  (_time.monotonic() - t0) * 1e3)
+            # host wall around the whole device point-read path; its
+            # sub-stages (utils/latency.py) say where inside
+            with _latency.stage_span(_latency.STAGE_DEVICE_DISPATCH):
+                res = self._multi_get_device(keys, read_ht, doc_key_lens)
             if res is not None:
                 return res
-        t0 = _time.monotonic()
-        res = self._multi_get_native(keys, read_ht)
-        _latency.record_stage(_latency.STAGE_HOST_FALLBACK,
-                              (_time.monotonic() - t0) * 1e3)
-        return res
+        with _latency.stage_span(_latency.STAGE_HOST_FALLBACK):
+            return self._multi_get_native(keys, read_ht)
 
     def _multi_get_native(self, keys, read_ht):
         """The CPU fallback: one native multi_get per key over a single
@@ -780,37 +776,27 @@ class DB:
         bucket, or a mid-batch device fault — all byte-identical)."""
         from yugabyte_tpu.ops import device_faults, point_read
         from yugabyte_tpu.storage import offload_policy
+        from yugabyte_tpu.utils.latency import sub_span
         # memtable snapshot BEFORE the reader set (see get())
-        with self._lock:
-            mems = [self.mem] + ([self._imm] if self._imm is not None
-                                 else [])
-            readers = list(self._readers.items())
-            for fid, _ in readers:
-                self._pins[fid] = self._pins.get(fid, 0) + 1
+        with sub_span("stage_lookup"):
+            with self._lock:
+                mems = [self.mem] + ([self._imm] if self._imm is not None
+                                     else [])
+                readers = list(self._readers.items())
+                for fid, _ in readers:
+                    self._pins[fid] = self._pins.get(fid, 0) + 1
         try:
-            staged_by = []
-            for fid, r in readers:
-                if r.props.n_entries == 0:
-                    continue
-                st = self._device_cache.get(fid)
-                if st is None:
-                    # write-through on miss, like scan_visible: the next
-                    # batch over this file finds it resident
-                    try:
-                        st = self._device_cache.stage(fid, r.read_all(),
-                                                      for_read=True)
-                    except StatusError:
-                        raise  # corrupt block: multi_get routes + re-raises
-                if st.n != r.props.n_entries:
+            with sub_span("stage_lookup"):
+                staged_by = self._staged_readers(readers)
+                if staged_by is None:
                     return None  # stale residency: let native serve
-                staged_by.append((fid, r, st))
-            from yugabyte_tpu.storage.bucket_health import health_board
-            board = health_board()
-            if any(not board.allow_device(
-                    "point_read_locate",
-                    offload_policy.point_read_bucket_key(st.n_pad))
-                   for _fid, _r, st in staged_by):
-                return None
+                from yugabyte_tpu.storage.bucket_health import health_board
+                board = health_board()
+                if any(not board.allow_device(
+                        "point_read_locate",
+                        offload_policy.point_read_bucket_key(st.n_pad))
+                       for _fid, _r, st in staged_by):
+                    return None
             results: List = [None] * len(keys)
             cur = {"n_pad": staged_by[0][2].n_pad if staged_by else 0}
             import time as _time
@@ -843,12 +829,33 @@ class DB:
                 return None
             return results
         finally:
-            with self._lock:
+            with sub_span("stage_lookup"), self._lock:
                 for fid, _ in readers:
                     self._pins[fid] -= 1
                     if not self._pins[fid]:
                         del self._pins[fid]
                 self._purge_obsolete_unlocked()
+
+    def _staged_readers(self, readers):
+        """[(fid, reader, staged cols)] of the non-empty SSTs, staging a
+        slab-cache miss on the way; None when a resident entry is stale."""
+        from yugabyte_tpu.utils.latency import sub_span
+        staged_by = []
+        for fid, r in readers:
+            if r.props.n_entries == 0:
+                continue
+            st = self._device_cache.get(fid)
+            if st is None:
+                # write-through on miss, like scan_visible: the next
+                # batch over this file finds it resident (a corrupt
+                # block raises: multi_get routes + re-raises)
+                with sub_span("stage_miss"):
+                    st = self._device_cache.stage(fid, r.read_all(),
+                                                  for_read=True)
+            if st.n != r.props.n_entries:
+                return None
+            staged_by.append((fid, r, st))
+        return staged_by
 
     def _multi_get_device_batches(self, keys, read_ht, mems, staged_by,
                                   results, doc_key_lens, cur):
@@ -856,9 +863,13 @@ class DB:
         from yugabyte_tpu.ops import point_read
         from yugabyte_tpu.ops.slabs import _doc_key_len
         from yugabyte_tpu.storage import learned_index
+        from yugabyte_tpu.utils.latency import sub_span
         metrics = point_read.point_read_metrics()
         mems = [m for m in mems if not m.empty]
         use_model = flags.get_flag("point_read_learned_index")
+        # the device calls below (hash_batch, probe_bloom, locate_batch)
+        # carry their own sub-stage spans: `device_enqueue` around each
+        # dispatch, `device_wait` around each blocking download
         for start in range(0, len(keys), 1024):
             chunk = keys[start: start + 1024]
             b = len(chunk)
@@ -868,16 +879,17 @@ class DB:
             metrics["batch_rows"].increment(b)
             # bloom hashes over the DocKey prefixes — one device FNV
             # dispatch per chunk (storage/bloom.py is the CPU twin)
-            if doc_key_lens is not None:
-                dkls = doc_key_lens[start: start + 1024]
-            else:
-                dkls = [_doc_key_len(k) for k in chunk]
-            max_dkl = max(dkls) if dkls else 1
-            from yugabyte_tpu.ops.run_merge import quantize_width
-            w_hash = quantize_width(max(1, -(-max_dkl // 4)))
-            hw, _hl = point_read.pack_query_batch(chunk, w_hash)
-            dk_pad = np.zeros(b_pad, dtype=np.int32)
-            dk_pad[:b] = dkls
+            with sub_span("query_pack"):
+                if doc_key_lens is not None:
+                    dkls = doc_key_lens[start: start + 1024]
+                else:
+                    dkls = [_doc_key_len(k) for k in chunk]
+                max_dkl = max(dkls) if dkls else 1
+                from yugabyte_tpu.ops.run_merge import quantize_width
+                w_hash = quantize_width(max(1, -(-max_dkl // 4)))
+                hw, _hl = point_read.pack_query_batch(chunk, w_hash)
+                dk_pad = np.zeros(b_pad, dtype=np.int32)
+                dk_pad[:b] = dkls
             h1, h2 = point_read.hash_batch(hw, dk_pad)
             packs = {}
             exact_fallback = set()
@@ -889,37 +901,39 @@ class DB:
                 if maybe is not None and not maybe[:b].any():
                     metrics["bloom_skips"].increment()
                     continue
-                if st.w not in packs:
-                    packs[st.w] = point_read.pack_query_batch(chunk,
-                                                              st.w)
-                qw, ql = packs[st.w]
-                model = (learned_index.model_operands(r.props.lindex,
-                                                      st.n)
-                         if use_model else None)
+                with sub_span("query_pack"):
+                    if st.w not in packs:
+                        packs[st.w] = point_read.pack_query_batch(chunk,
+                                                                  st.w)
+                    qw, ql = packs[st.w]
+                    model = (learned_index.model_operands(r.props.lindex,
+                                                          st.n)
+                             if use_model else None)
                 _idx, hit, hhi, hlo, wid, miss = point_read.locate_batch(
                     st, qw, ql, read_ht.value, model)
-                if model is not None:
-                    metrics["learned_hits"].increment()
-                    n_miss = int(miss[:b].sum())
-                    if n_miss:
-                        metrics["learned_fallbacks"].increment(n_miss)
-                        for i in np.nonzero(miss[:b])[0]:
-                            exact_fallback.add(int(i))
-                ht = (hhi.astype(np.uint64) << np.uint64(32)) \
-                    | hlo.astype(np.uint64)
-                if best is None:
-                    best = [np.zeros(b_pad, np.uint64),
-                            np.zeros(b_pad, np.uint32),
-                            np.zeros(b_pad, np.int64),
-                            np.zeros(b_pad, np.int64),
-                            np.zeros(b_pad, bool)]
-                upd = hit & (~best[4] | (ht > best[0])
-                             | ((ht == best[0]) & (wid > best[1])))
-                best[0] = np.where(upd, ht, best[0])
-                best[1] = np.where(upd, wid, best[1])
-                best[2] = np.where(upd, _idx.astype(np.int64), best[2])
-                best[3] = np.where(upd, fi, best[3])
-                best[4] = best[4] | hit
+                with sub_span("chunk_combine"):
+                    if model is not None:
+                        metrics["learned_hits"].increment()
+                        n_miss = int(miss[:b].sum())
+                        if n_miss:
+                            metrics["learned_fallbacks"].increment(n_miss)
+                            for i in np.nonzero(miss[:b])[0]:
+                                exact_fallback.add(int(i))
+                    ht = (hhi.astype(np.uint64) << np.uint64(32)) \
+                        | hlo.astype(np.uint64)
+                    if best is None:
+                        best = [np.zeros(b_pad, np.uint64),
+                                np.zeros(b_pad, np.uint32),
+                                np.zeros(b_pad, np.int64),
+                                np.zeros(b_pad, np.int64),
+                                np.zeros(b_pad, bool)]
+                    upd = hit & (~best[4] | (ht > best[0])
+                                 | ((ht == best[0]) & (wid > best[1])))
+                    best[0] = np.where(upd, ht, best[0])
+                    best[1] = np.where(upd, wid, best[1])
+                    best[2] = np.where(upd, _idx.astype(np.int64), best[2])
+                    best[3] = np.where(upd, fi, best[3])
+                    best[4] = best[4] | hit
             self._combine_device_chunk(chunk, start, read_ht, mems,
                                        staged_by, best, exact_fallback,
                                        results)
@@ -927,30 +941,37 @@ class DB:
     def _combine_device_chunk(self, chunk, start, read_ht, mems,
                               staged_by, best, exact_fallback, results):
         """Merge device SST winners with host memtable probes per key —
-        newest (ht, wid) wins, exactly get()'s compare."""
-        live_mems = [m for m in mems if not m.empty]
-        mem_hits = self._mem_probe_many(live_mems, chunk, read_ht)
-        for i, k in enumerate(chunk):
-            if i in exact_fallback:
-                # learned-index misprediction beyond its bound: the
-                # binary-search invariant caught it — resolve this key
-                # exactly (correctness never rides the model)
-                results[start + i] = self._get_inner(k, read_ht)
-                continue
-            mem_best = mem_hits[i]
-            if best is not None and best[4][i]:
-                ht_v = int(best[0][i])
-                wid_v = int(best[1][i])
-                if mem_best is None or (ht_v, wid_v) > mem_best[:2]:
-                    value = self._fetch_staged_value(
-                        staged_by[int(best[3][i])], int(best[2][i]))
-                    results[start + i] = (
-                        DocHybridTime(HybridTime(ht_v), wid_v), value)
+        newest (ht, wid) wins, exactly get()'s compare. The winners'
+        values are fetched in one pass at the end (`value_fetch`)."""
+        from yugabyte_tpu.utils.latency import sub_span
+        fetch = []   # (result slot, staged entry, row, ht, write id)
+        with sub_span("chunk_combine"):
+            live_mems = [m for m in mems if not m.empty]
+            mem_hits = self._mem_probe_many(live_mems, chunk, read_ht)
+            for i, k in enumerate(chunk):
+                if i in exact_fallback:
+                    # learned-index misprediction beyond its bound: the
+                    # binary-search invariant caught it — resolve this key
+                    # exactly (correctness never rides the model)
+                    results[start + i] = self._get_inner(k, read_ht)
                     continue
-            results[start + i] = (
-                None if mem_best is None else
-                (DocHybridTime(HybridTime(mem_best[0]), mem_best[1]),
-                 mem_best[2]))
+                mem_best = mem_hits[i]
+                if best is not None and best[4][i]:
+                    ht_v = int(best[0][i])
+                    wid_v = int(best[1][i])
+                    if mem_best is None or (ht_v, wid_v) > mem_best[:2]:
+                        fetch.append((start + i,
+                                      staged_by[int(best[3][i])],
+                                      int(best[2][i]), ht_v, wid_v))
+                        continue
+                results[start + i] = (
+                    None if mem_best is None else
+                    (DocHybridTime(HybridTime(mem_best[0]), mem_best[1]),
+                     mem_best[2]))
+        with sub_span("value_fetch"):
+            for slot, entry, row, ht_v, wid_v in fetch:
+                results[slot] = (DocHybridTime(HybridTime(ht_v), wid_v),
+                                 self._fetch_staged_value(entry, row))
 
     @staticmethod
     def _fetch_staged_value(entry, row: int) -> bytes:
@@ -1371,46 +1392,12 @@ class DB:
                 and e.status.code == Code.CORRUPTION)
 
     def _run_compaction_inner(self, pick) -> None:
+        from yugabyte_tpu.utils.metrics import pipeline_span
         try:
-            inputs = [self._readers[fm.file_id] for fm in pick.inputs]
-            cutoff = self.opts.retention_policy()
-            result = self._dispatch_compaction(pick, inputs, cutoff)
-            from yugabyte_tpu.utils import sync_point
-            sync_point.hit("db.compaction:before_install")
-            with self._lock:
-                removed = [fm.file_id for fm in pick.inputs]
-                self.versions.install_compaction(
-                    removed, [(fid, p, props) for fid, p, props in result.outputs])
-                self._rset = None  # native snapshot is stale; removed
-                self._rset_gen += 1
-                # native readers are dropped from the dict below and freed
-                # by refcount once in-flight scans release their snapshot
-                for fid, path, props in result.outputs:
-                    self._readers[fid] = SSTReader(path, self.opts.block_cache)
-                for fid in removed:
-                    self._native_readers.pop(fid, None)
-                    r = self._readers.pop(fid, None)
-                    if r:
-                        if self._pins.get(fid):
-                            # an active scan still reads this SST: defer the
-                            # close+delete until its pin drops
-                            self._obsolete[fid] = r
-                        else:
-                            r.close()
-                            _delete_sst_files(r.base_path)
-                    if self._device_cache is not None:
-                        self._device_cache.drop(fid)
-                    if self._run_cache is not None:
-                        self._run_cache.drop(fid)
-            self.compaction_stats.record_compaction(
-                bytes_read=sum(fm.total_size for fm in pick.inputs),
-                bytes_written=sum(p.data_size + p.base_size
-                                  for _fid, _path, p in result.outputs),
-                files_in=len(pick.inputs), files_out=len(result.outputs),
-                rows_in=result.rows_in, rows_out=result.rows_out,
-                tombstones_written=result.tombstones_written)
-            TRACE("compaction: %d files -> %d rows (%d in)",
-                  len(pick.inputs), result.rows_out, result.rows_in)
+            # the job's root span: `job` is its wall, `job_other` what no
+            # stage span under it names (utils/metrics._PIPELINE_STAGES)
+            with pipeline_span("job", inclusive="job", stage="job_other"):
+                self._compact_and_install(pick)
         finally:
             with self._lock:
                 self._compacting = False
@@ -1424,6 +1411,56 @@ class DB:
         # cascade if still over trigger
         if self.opts.auto_compact:
             self.maybe_schedule_compaction()
+
+    def _compact_and_install(self, pick) -> None:
+        """The job body under the root span: dispatch, then install the
+        outputs into the version set, open their readers and delete the
+        inputs."""
+        from yugabyte_tpu.utils.metrics import pipeline_span
+        inputs = [self._readers[fm.file_id] for fm in pick.inputs]
+        cutoff = self.opts.retention_policy()
+        result = self._dispatch_compaction(pick, inputs, cutoff)
+        from yugabyte_tpu.utils import sync_point
+        sync_point.hit("db.compaction:before_install")
+        with self._lock:
+            removed = [fm.file_id for fm in pick.inputs]
+            with pipeline_span("version_install"):
+                self.versions.install_compaction(
+                    removed,
+                    [(fid, p, props) for fid, p, props in result.outputs])
+            self._rset = None  # native snapshot is stale; removed
+            self._rset_gen += 1
+            # native readers are dropped from the dict below and freed
+            # by refcount once in-flight scans release their snapshot
+            with pipeline_span("reader_open"):
+                for fid, path, props in result.outputs:
+                    self._readers[fid] = SSTReader(path,
+                                                   self.opts.block_cache)
+            with pipeline_span("input_delete"):
+                for fid in removed:
+                    self._native_readers.pop(fid, None)
+                    r = self._readers.pop(fid, None)
+                    if r:
+                        if self._pins.get(fid):
+                            # an active scan still reads this SST: defer
+                            # the close+delete until its pin drops
+                            self._obsolete[fid] = r
+                        else:
+                            r.close()
+                            _delete_sst_files(r.base_path)
+                    if self._device_cache is not None:
+                        self._device_cache.drop(fid)
+                    if self._run_cache is not None:
+                        self._run_cache.drop(fid)
+        self.compaction_stats.record_compaction(
+            bytes_read=sum(fm.total_size for fm in pick.inputs),
+            bytes_written=sum(p.data_size + p.base_size
+                              for _fid, _path, p in result.outputs),
+            files_in=len(pick.inputs), files_out=len(result.outputs),
+            rows_in=result.rows_in, rows_out=result.rows_out,
+            tombstones_written=result.tombstones_written)
+        TRACE("compaction: %d files -> %d rows (%d in)",
+              len(pick.inputs), result.rows_out, result.rows_in)
 
     def _dispatch_compaction(self, pick, inputs, cutoff):
         """Route one picked compaction: through the mesh-sharded
@@ -1460,7 +1497,10 @@ class DB:
                     input_ids=[fm.file_id for fm in pick.inputs],
                     device_cache=self._device_cache, est_rows=est,
                     cancel=self._cancel)
-                return handle.result()
+                from yugabyte_tpu.utils.metrics import pipeline_span
+                with pipeline_span("pool_wait"):
+                    # the pool's worker runs the job on its own thread
+                    return handle.result()
         return compaction_mod.run_compaction_job(
             inputs, self.db_dir, self.versions.new_file_id, cutoff,
             pick.is_major, device=self.opts.device,

@@ -109,8 +109,7 @@ def maybe_shadow_verifier(inputs, history_cutoff_ht: int, is_major: bool,
 
 @ybsan.shadow(_surv=ybsan.PUBLISHER_CONSUMER,
               _mk=ybsan.PUBLISHER_CONSUMER,
-              _oracle_err=ybsan.PUBLISHER_CONSUMER,
-              _ms=ybsan.PUBLISHER_CONSUMER)
+              _oracle_err=ybsan.PUBLISHER_CONSUMER)
 class ShadowVerifier:
     """Re-derives one compaction job's survivor decisions through the
     native heap-merge oracle and compares the device decisions against
@@ -133,15 +132,20 @@ class ShadowVerifier:
         self._mk: Optional[np.ndarray] = None
         self._oracle_err: Optional[BaseException] = None
         self._off = 0
-        self._ms = 0.0
         self._thread = threading.Thread(target=self._run_oracle,
                                         name="compaction-shadow",
                                         daemon=True)
         self._thread.start()
 
     def _run_oracle(self) -> None:
-        import time as _time
-        t0 = _time.monotonic()
+        from yugabyte_tpu.utils.metrics import pipeline_span
+        # a root of the oracle's own thread, for the profile only: what the
+        # JOB pays for verification is the `shadow` spans of check_chunk
+        # and finish (the wait for this thread, and the compares)
+        with pipeline_span("shadow_oracle", stage=None, parent=None):
+            self._run_oracle_inner()
+
+    def _run_oracle_inner(self) -> None:
         try:
             from yugabyte_tpu.ops.slabs import concat_slabs
             from yugabyte_tpu.storage.cpu_baseline import \
@@ -156,8 +160,6 @@ class ShadowVerifier:
             self._mk = mk[keep]
         except BaseException as e:  # noqa: BLE001  # yblint: contained(oracle failure disables shadow verify for this job — it is not corruption evidence; counted + TRACEd on the join path)
             self._oracle_err = e
-        finally:
-            self._ms = (_time.monotonic() - t0) * 1e3
 
     def _join(self) -> bool:
         """True when the oracle produced expected decisions; False when
@@ -179,6 +181,11 @@ class ShadowVerifier:
         """Compare one streamed decision chunk (global survivor indexes +
         tombstone flags, in merged order) against the oracle's span at
         the running offset. Raises ShadowMismatch on ANY divergence."""
+        from yugabyte_tpu.utils.metrics import pipeline_span
+        with pipeline_span("shadow"):
+            self._check_chunk(surv, make_tomb)
+
+    def _check_chunk(self, surv: np.ndarray, make_tomb: np.ndarray) -> None:
         if not self._join():
             return
         lo, hi = self._off, self._off + len(surv)
@@ -207,7 +214,11 @@ class ShadowVerifier:
     def finish(self, rows_out: int) -> None:
         """Final totals check + accounting; called after the last chunk,
         BEFORE the tail output files are written/installed."""
-        from yugabyte_tpu.utils.metrics import record_pipeline_stage
+        from yugabyte_tpu.utils.metrics import pipeline_span
+        with pipeline_span("shadow"):
+            self._finish(rows_out)
+
+    def _finish(self, rows_out: int) -> None:
         if self._join():
             if rows_out != len(self._surv) or self._off != rows_out:
                 raise ShadowMismatch(
@@ -220,7 +231,6 @@ class ShadowVerifier:
             _counter("shadow_verify_rows_total",
                      "survivor decisions compared by shadow "
                      "verification").increment(rows_out)
-        record_pipeline_stage("shadow", self._ms)
 
 
 def shadow_snapshot() -> dict:

@@ -261,8 +261,10 @@ class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit g
                 from yugabyte_tpu.utils.status import Status, StatusError
                 raise StatusError(Status.ServiceUnavailable(
                     "duplicate request still in flight"))
+        from yugabyte_tpu.utils.latency import sub_span
         try:
-            self._check_write_backpressure()
+            with sub_span("admission"):
+                self._check_write_backpressure()
         except BaseException:
             if request is not None:
                 self.retryable.failed(*request)
@@ -574,57 +576,59 @@ class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit g
         only surviving data lives at non-schema column ids inside SSTs
         (dropped columns) are the one documented divergence — they need
         the full iterator to prove existence."""
-        ht = self.read_time(read_ht)
+        from yugabyte_tpu.utils import latency as _latency
+        with _latency.sub_span("read_point"):
+            ht = self.read_time(read_ht)
         if txn_id is not None \
                 or self.intents_db.approx_entry_count() != 0 \
                 or self.regular_db.has_deep_files():
             return [self.read_row(dk, ht, projection, txn_id=txn_id)
                     for dk in doc_keys]
-        from yugabyte_tpu.docdb.doc_operations import (column_key_suffix,
-                                                       kLivenessColumnId)
-        schema = self.schema
-        cids = [kLivenessColumnId] + [schema.column_id(c.name)
-                                      for c in schema.value_columns]
-        suffixes = [column_key_suffix(cid) for cid in cids]
-        cid_by_suffix = dict(zip(suffixes, cids))
-        # projection names -> ids ONCE per batch (mirrors
-        # VisibleEntryRowAssembler: unknown names never match)
-        proj_ids = None
-        if projection is not None:
-            proj_ids = set()
-            for cname in projection:
-                try:
-                    proj_ids.add(cname if isinstance(cname, int)
-                                 else schema.column_id(cname))
-                except KeyError:
-                    pass
-        keys: list = []
-        dkls: list = []
-        spans = []          # per doc key: (start, count) into keys
-        row_keys_by = []
-        fallback = set()    # row indexes that need the exact path
-        encs = []
-        for ri, dk in enumerate(doc_keys):
-            self.metric_reads.increment()
-            enc = dk.encode()
-            encs.append(enc)
-            upper = enc + bytes([ValueType.kMaxByte])
-            enumerated = sorted([enc] + [enc + s for s in suffixes])
-            enum_set = set(enumerated)
-            # memtable probe: recent writes at non-enumerated subkeys
-            # (deep documents, unknown cids) make this row non-flat
-            from yugabyte_tpu.docdb.doc_key import split_key_and_ht
-            for ikey, _v in self.regular_db.mem_entries_range(enc, upper):
-                prefix, dht = split_key_and_ht(ikey)
-                if dht is None or prefix not in enum_set:
-                    fallback.add(ri)
-                    break
-            row_keys_by.append(enumerated)
-            spans.append((len(keys), len(enumerated)))
-            keys.extend(enumerated)
-            dkls.extend([len(enc)] * len(enumerated))
+        with _latency.sub_span("key_build"):
+            from yugabyte_tpu.docdb.doc_operations import (column_key_suffix,
+                                                           kLivenessColumnId)
+            schema = self.schema
+            cids = [kLivenessColumnId] + [schema.column_id(c.name)
+                                          for c in schema.value_columns]
+            suffixes = [column_key_suffix(cid) for cid in cids]
+            cid_by_suffix = dict(zip(suffixes, cids))
+            # projection names -> ids ONCE per batch (mirrors
+            # VisibleEntryRowAssembler: unknown names never match)
+            proj_ids = None
+            if projection is not None:
+                proj_ids = set()
+                for cname in projection:
+                    try:
+                        proj_ids.add(cname if isinstance(cname, int)
+                                     else schema.column_id(cname))
+                    except KeyError:
+                        pass
+            keys: list = []
+            dkls: list = []
+            spans = []          # per doc key: (start, count) into keys
+            row_keys_by = []
+            fallback = set()    # row indexes that need the exact path
+            encs = []
+            for ri, dk in enumerate(doc_keys):
+                self.metric_reads.increment()
+                enc = dk.encode()
+                encs.append(enc)
+                upper = enc + bytes([ValueType.kMaxByte])
+                enumerated = sorted([enc] + [enc + s for s in suffixes])
+                enum_set = set(enumerated)
+                # memtable probe: recent writes at non-enumerated subkeys
+                # (deep documents, unknown cids) make this row non-flat
+                from yugabyte_tpu.docdb.doc_key import split_key_and_ht
+                for ikey, _v in self.regular_db.mem_entries_range(enc, upper):
+                    prefix, dht = split_key_and_ht(ikey)
+                    if dht is None or prefix not in enum_set:
+                        fallback.add(ri)
+                        break
+                row_keys_by.append(enumerated)
+                spans.append((len(keys), len(enumerated)))
+                keys.extend(enumerated)
+                dkls.extend([len(enc)] * len(enumerated))
         results = self.regular_db.multi_get(keys, ht, doc_key_lens=dkls)
-        from yugabyte_tpu.utils import latency as _latency
         rows = []
         asm_s = fb_s = 0.0
         for ri, dk in enumerate(doc_keys):

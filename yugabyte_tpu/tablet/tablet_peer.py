@@ -133,8 +133,10 @@ class RaftWriteContext:
 
     def submit(self, kv_pairs, ht: HybridTime, timeout_s: float = 30.0,
                target_intents: bool = False, request=None) -> Tuple[int, int]:
-        payload = encode_write_batch(kv_pairs, target_intents,
-                                     request=request)
+        from yugabyte_tpu.utils.latency import sub_span
+        with sub_span("batch_encode"):
+            payload = encode_write_batch(kv_pairs, target_intents,
+                                         request=request)
         try:
             return self._peer.raft.replicate(OP_WRITE, ht.value, payload,
                                              timeout_s=timeout_s)
@@ -566,7 +568,9 @@ class TabletPeer:
         ONCE for the whole batch, rows resolved through the tablet's
         batched path (Tablet.multi_read -> DB.multi_get)."""
         if self.raft.is_leader():
-            self.check_leader_lease()
+            from yugabyte_tpu.utils.latency import sub_span
+            with sub_span("read_point"):
+                self.check_leader_lease()
             return self.tablet.multi_read(doc_keys, read_ht, projection,
                                           txn_id=txn_id)
         if not allow_follower:

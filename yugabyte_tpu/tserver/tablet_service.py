@@ -18,6 +18,7 @@ from yugabyte_tpu.consensus.raft import (NotLeader, OperationOutcomeUnknown,
                                          ReplicationAborted)
 from yugabyte_tpu.tserver.ts_tablet_manager import TSTabletManager
 from yugabyte_tpu.utils import flags as _flags
+from yugabyte_tpu.utils import latency as _latency
 from yugabyte_tpu.utils.status import Code, Status, StatusError
 
 _flags.define_flag(
@@ -113,21 +114,23 @@ class TabletServiceImpl:
         from yugabyte_tpu.tablet.tablet import TabletHasBeenSplit
         self._check_schema_version(tablet_id, schema_version)
         peer = self._tablets.get_tablet(tablet_id)
-        decoded = [write_op_from_wire(w) for w in ops]
-        # Key-bounds guard: after a split, a stale client batch may span
-        # both children; accepting out-of-range keys would strand data in a
-        # tablet that never serves them (ref CheckOperationAllowed key
-        # bounds validation in the reference write path).
-        lo = peer.tablet.opts.lower_bound_key
-        hi = peer.tablet.opts.upper_bound_key
-        if lo or hi is not None:
-            for op in decoded:
-                enc = op.doc_key.encode()
-                if (lo and enc < lo) or (hi is not None and enc >= hi):
-                    err = StatusError(Status.IllegalState(
-                        f"key outside tablet range of {tablet_id}"))
-                    err.extra = {"wrong_tablet": True}
-                    raise err
+        with _latency.sub_span("request_decode"):
+            decoded = [write_op_from_wire(w) for w in ops]
+            # Key-bounds guard: after a split, a stale client batch may
+            # span both children; accepting out-of-range keys would strand
+            # data in a tablet that never serves them (ref
+            # CheckOperationAllowed key bounds validation in the reference
+            # write path).
+            lo = peer.tablet.opts.lower_bound_key
+            hi = peer.tablet.opts.upper_bound_key
+            if lo or hi is not None:
+                for op in decoded:
+                    enc = op.doc_key.encode()
+                    if (lo and enc < lo) or (hi is not None and enc >= hi):
+                        err = StatusError(Status.IllegalState(
+                            f"key outside tablet range of {tablet_id}"))
+                        err.extra = {"wrong_tablet": True}
+                        raise err
         request = ((client_id, request_id)
                    if client_id is not None and request_id is not None
                    else None)
@@ -194,16 +197,19 @@ class TabletServiceImpl:
         Response rows align with the request keys (None = absent)."""
         self._check_schema_version(tablet_id, schema_version)
         peer = self._tablets.get_tablet(tablet_id)
+        with _latency.sub_span("request_decode"):
+            decoded = [doc_key_from_wire(d) for d in doc_keys]
         try:
             rows = peer.multi_read(
-                [doc_key_from_wire(d) for d in doc_keys],
+                decoded,
                 HybridTime(read_ht) if read_ht else None,
                 projection=tuple(projection) if projection else None,
                 allow_follower=allow_follower)
         except NotLeader as e:
             raise NotLeaderError(_leader_server_hint(e)) from e
-        return {"rows": [None if r is None else row_to_wire(r)
-                         for r in rows]}
+        with _latency.sub_span("response_encode"):
+            return {"rows": [None if r is None else row_to_wire(r)
+                             for r in rows]}
 
     def scan(self, tablet_id: str, lower_doc_key: bytes = b"",
              upper_doc_key: Optional[bytes] = None,

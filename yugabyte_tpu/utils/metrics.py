@@ -20,6 +20,8 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from yugabyte_tpu.utils.trace import AMBIENT, span
+
 
 class Counter:
     __slots__ = ("name", "help", "_value", "_lock")
@@ -423,51 +425,115 @@ def publish_compile_surface(counts: Dict[str, int]) -> None:
             "families (committed manifest)").set(total)
 
 
-_PIPELINE_STAGES = ("host", "device", "write", "shadow", "decode",
-                    "encode")
+# The compaction job's stage vocabulary (README "Telemetry timebase").
+# `job` is the root span's inclusive time and `job_other` its self time
+# (what still has no name); `device`, `write`, `shadow`, `decode`,
+# `encode` and every name after them are disjoint self times of spans
+# under the root, so that
+#   job = device + write + shadow + decode + encode + <the rest> + job_other
+# on the job's thread. `host` is the legacy inclusive slice (raw-byte
+# ingest + merge staging + decision decode); it overlaps the ingest
+# stages and is in no sum.
+_PIPELINE_STAGES = (
+    "host", "device", "write", "shadow", "decode", "encode",
+    "job", "job_other",
+    "routing", "pool_wait", "shadow_setup",
+    "ingest", "raw_read", "raw_parse", "stage_input", "value_concat",
+    "ingest_join",
+    "merge_stage", "merge_launch", "decision_unpack", "decision_remap",
+    "survivor_select", "survivor_concat", "shell_feed",
+    "parent_products", "survivor_positions", "span_gather", "lindex_fit",
+    "value_gather", "cache_install", "pace", "installer_finish",
+    "run_export",
+    "native_ingest", "native_merge",
+    "version_install", "reader_open", "input_delete")
+
+_stage_metrics: Dict[str, Tuple[Histogram, Gauge]] = {}
 
 
 def record_pipeline_stage(stage: str, ms: float) -> None:
-    """One slice of compaction-pipeline wall time: `stage` is where the
-    time went — 'host' (raw-byte ingest + column packing + decision
-    decode), 'device' (kernel compute + H2D/D2H transfer waits),
-    'write' (SST output I/O), 'shadow' (sampled oracle verification),
-    'decode' (device block-codec ingest: raw-word upload + decode
-    dispatch) or 'encode' (device block-codec output: span encode
-    dispatch + download + block assembly). Per-stage histograms plus
-    a cumulative-ms gauge feed /compactionz and bench.py's stage report,
-    so a stalled pipeline shows WHICH stage is the bottleneck."""
-    e = kernel_metrics()
-    e.histogram(f"compaction_pipeline_stage_{stage}_ms",
-                f"compaction pipeline {stage}-stage wall time per "
-                "slice").increment(max(ms, 0.0))
-    e.gauge(f"compaction_pipeline_stage_{stage}_total_ms",
-            f"cumulative compaction pipeline {stage}-stage wall "
-            "time").increment(max(ms, 0.0))
+    """One slice of compaction-pipeline wall time under `stage` (the
+    vocabulary above). Per-stage histograms plus a cumulative-ms gauge
+    feed /compactionz and the benchmark's counter snapshot, so a slow
+    job shows WHICH stage holds it. Called by the spans of
+    `pipeline_span`; the six legacy names mean what they always did:
+    'host' (raw-byte ingest + column packing + decision decode),
+    'device' (host blocked on the decision download), 'write' (SST
+    output I/O), 'shadow' (the job thread held by the sampled oracle
+    verification), 'decode' (device block-codec ingest: raw-word upload
+    + decode dispatch), 'encode' (device block-codec output: span encode
+    dispatch + download + block assembly)."""
+    pair = _stage_metrics.get(stage)
+    if pair is None:
+        e = kernel_metrics()
+        pair = _stage_metrics[stage] = (
+            e.histogram(f"compaction_pipeline_stage_{stage}_ms",
+                        f"compaction pipeline {stage}-stage wall time "
+                        "per slice"),
+            e.gauge(f"compaction_pipeline_stage_{stage}_total_ms",
+                    f"cumulative compaction pipeline {stage}-stage wall "
+                    "time"))
+    ms = max(ms, 0.0)
+    pair[0].increment(ms)
+    pair[1].increment(ms)
+
+
+class _PipelineSink:
+    """A span's sink on the compaction rail: self time under one stage,
+    inclusive time under another (either may be absent)."""
+
+    __slots__ = ("stage", "inclusive")
+
+    def __init__(self, stage: Optional[str], inclusive: Optional[str]):
+        self.stage = stage
+        self.inclusive = inclusive
+
+    def __call__(self, inclusive_ms: float, self_ms: float) -> None:
+        if self.stage is not None:
+            record_pipeline_stage(self.stage, self_ms)
+        if self.inclusive is not None:
+            record_pipeline_stage(self.inclusive, inclusive_ms)
+
+
+_pipeline_sinks: Dict[Tuple[Optional[str], Optional[str]],
+                      _PipelineSink] = {}
+_NAMED = object()
+
+
+def pipeline_span(name: str, inclusive: Optional[str] = None,
+                  stage=_NAMED, parent=AMBIENT) -> span:
+    """The span "yb/compact/<name>" of a compaction job. Its self time is
+    recorded under `stage` (default: `name`; None records none: a helper
+    thread whose wall overlaps the job thread's stages), its inclusive
+    time under `inclusive` where given (the root's `job`, the legacy
+    `host` of the ingest, launch and unpack spans)."""
+    key = (name if stage is _NAMED else stage, inclusive)
+    sink = _pipeline_sinks.get(key)
+    if sink is None:
+        sink = _pipeline_sinks[key] = _PipelineSink(*key)
+    return span("compact/" + name, sink, parent)
 
 
 def pipeline_stage_totals() -> Dict[str, float]:
-    """Cumulative per-stage pipeline milliseconds (host/device/write) —
-    the snapshot bench.py diffs around a run to report where the wall
-    time of the offloaded compactions went."""
+    """Cumulative per-stage pipeline milliseconds — the snapshot
+    /compactionz shows and the benchmark diffs around its traced jobs to
+    report where the wall time of the offloaded compactions went."""
     e = kernel_metrics()
     return {s: float(e.gauge(
         f"compaction_pipeline_stage_{s}_total_ms").value())
         for s in _PIPELINE_STAGES}
 
 
-def record_kernel_dispatch(kind: str, n_rows: int, n_pad: int,
-                           duration_ms: Optional[float] = None) -> None:
-    """One JAX-kernel dispatch: invocation counter, wall-time histogram,
-    batch-size histogram, and the padding-waste gauges the shape-bucketing
-    design makes interesting (padded slots are pure device work). `kind`
-    is the kernel family, e.g. 'kernel_merge_gc' / 'kernel_scan'."""
+def record_kernel_dispatch(kind: str, n_rows: int, n_pad: int) -> None:
+    """One JAX-kernel dispatch: invocation counter, batch-size histogram,
+    and the padding-waste gauges the shape-bucketing design makes
+    interesting (padded slots are pure device work). `kind` is the
+    kernel family, e.g. 'kernel_merge_gc' / 'kernel_scan'. No duration:
+    a host clock around an enqueue or a blocking download is not a
+    kernel time — that comes from a profiler trace."""
     e = kernel_metrics()
     e.counter(kind + "_dispatch_total",
               f"{kind} device dispatches").increment()
-    if duration_ms is not None:
-        e.histogram(kind + "_duration_ms",
-                    f"{kind} dispatch wall time").increment(duration_ms)
     e.histogram(kind + "_batch_rows",
                 f"{kind} real rows per dispatch").increment(max(n_rows, 1))
     e.gauge("kernel_batch_rows",

@@ -1,4 +1,6 @@
-"""Per-request tracing with cross-node propagation.
+"""Per-request tracing with cross-node propagation, and the stage-timing
+rail (`span`, further down: named host intervals on the profiler's clock
+that feed the compaction and serve-path stage counters).
 
 Capability parity with yb::Trace (ref: src/yb/util/trace.h:62-137): a Trace
 collects timestamped messages for one request; traces dump on slow operations
@@ -103,6 +105,108 @@ class Trace:
         # nested local-bypass call must still appear in /tracez
         if self.record and self.sampled and (self.entries or self.children):
             _record_tracez(self)
+
+
+# ---------------------------------------------------------------- spans
+# The one stage-timing rail. A `span` is a named host interval that (a)
+# shows on the profiler's clock as a `jax.profiler.TraceAnnotation`
+# "yb/<name>" — the same clock the device planes of a trace use, so a
+# device idle gap can be laid against what the host was doing — and (b)
+# hands its inclusive and self time to a sink, which adds them to the
+# registry counters of its rail (utils/metrics.pipeline_span for the
+# compaction job, utils/latency.stage_span / sub_span for the serve
+# path). Nothing is appended to any list: always on, sums and counts
+# only. Keep spans out of per-row, per-key and per-block loops.
+
+_current_span: "contextvars.ContextVar[Optional[span]]" = \
+    contextvars.ContextVar("ybtpu_span", default=None)
+
+AMBIENT = object()   # `parent=AMBIENT`: the enclosing span of this context
+SPAN_PREFIX = "yb/"  # never "bench/": the benchmark attributes idle time
+                     # to its own spans and must keep reading only those
+
+_annotation = None   # jax.profiler.TraceAnnotation, or False without JAX
+_now_ns = time.monotonic_ns
+
+
+def _resolve_annotation():
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    except ImportError:      # a process without JAX: counters alone
+        _annotation = False
+    return _annotation
+
+
+class span:
+    """`with span(name, sink):` — one timed, profiler-visible interval.
+
+    On exit the span's duration is added to its parent's child time; its
+    self time is the duration minus its own children's (so the self times
+    of a tree of spans are disjoint and sum to the root's duration). The
+    sink, when given, is called as ``sink(inclusive_ms, self_ms)``.
+    `ns` / `self_ns` (and `ms`) hold both after exit; `elapsed_ms()` reads
+    the clock while the span is open.
+
+    parent: AMBIENT (default) takes the span open in this context; a new
+    thread starts with none, so work handed to another thread passes the
+    waiting span explicitly (`parent=that_span`), as LatencyBudget is
+    passed across the same hand-offs. None makes a root. An exception
+    leaves the span like any other exit: timed, recorded, re-raised.
+    """
+
+    __slots__ = ("name", "sink", "parent", "child_ns", "ns", "self_ns",
+                 "_t0", "_ann", "_token")
+
+    def __init__(self, name: str, sink=None, parent=AMBIENT):
+        self.name = name
+        self.sink = sink
+        self.parent = parent
+        self.child_ns = 0
+        self.ns = self.self_ns = 0
+
+    def elapsed_ms(self) -> float:
+        return (_now_ns() - self._t0) / 1e6
+
+    @property
+    def ms(self) -> float:
+        """Inclusive milliseconds, once the span has exited."""
+        return self.ns / 1e6
+
+    def __enter__(self) -> "span":
+        if self.parent is AMBIENT:
+            self.parent = _current_span.get()
+        self._token = _current_span.set(self)
+        ann = _annotation if _annotation is not None \
+            else _resolve_annotation()
+        # the annotation object only while a profiler session is on: with
+        # none it would be a third of the span's cost, for nothing
+        if ann and ann.is_enabled():
+            self._ann = ann(SPAN_PREFIX + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t0 = _now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.ns = ns = _now_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _current_span.reset(self._token)
+        child_ns = self.child_ns
+        self.self_ns = self_ns = ns - child_ns if ns > child_ns else 0
+        parent = self.parent
+        if parent is not None:
+            parent.child_ns += ns
+        if self.sink is not None:
+            self.sink(ns / 1e6, self_ns / 1e6)
+        return False
+
+
+def current_span() -> Optional[span]:
+    return _current_span.get()
 
 
 def TRACE(msg: str, *args) -> None:
