@@ -656,6 +656,22 @@ void ce_bloom_build(const uint64_t* h, int64_t n, uint8_t* bits,
   }
 }
 
+// Variable-length row gather (ops/slabs.py ValueArray.gather): row i of the
+// output is lens[i] bytes at src + starts[i] — or at rep + starts[i] where
+// from_rep[i] is set (the TTL-expiry -> tombstone rewrite; from_rep may be
+// null) — copied to out + out_off[i]. The numpy form builds an int64 index
+// per output BYTE; this is one memcpy per row. The caller has range-checked
+// every (start, len) against its buffer.
+void ce_gather_rows(const uint8_t* src, const uint8_t* rep,
+                    const uint8_t* from_rep, const int64_t* starts,
+                    const int64_t* lens, const int64_t* out_off, int64_t n,
+                    uint8_t* out) {
+  for (int64_t i = 0; i < n; ++i) {
+    const uint8_t* base = (from_rep && from_rep[i]) ? rep : src;
+    memcpy(out + out_off[i], base + starts[i], (size_t)lens[i]);
+  }
+}
+
 // --- native run cache ----------------------------------------------------
 // Export survivors [start, end) of a finished job as a cached packed run —
 // byte-equivalent to decoding the output file just written for that range

@@ -28,7 +28,7 @@ from test_run_merge import _make_run  # noqa: E402
 
 from yugabyte_tpu.ops import block_codec, device_faults  # noqa: E402
 from yugabyte_tpu.ops.merge_gc import stage_slab  # noqa: E402
-from yugabyte_tpu.ops.slabs import ValueArray  # noqa: E402
+from yugabyte_tpu.ops.slabs import ValueArray, gather_metrics  # noqa: E402
 from yugabyte_tpu.storage import block_format  # noqa: E402
 from yugabyte_tpu.storage import compaction as compaction_mod  # noqa: E402
 from yugabyte_tpu.storage import integrity  # noqa: E402,F401 (flag defs)
@@ -413,5 +413,50 @@ def test_cancel_mid_codec_stage_c_sweeps_partials(tmp_path, monkeypatch):
     leftovers = os.listdir(out_dir) if os.path.isdir(out_dir) else []
     assert not leftovers, f"partial outputs leaked: {leftovers}"
     assert host_staging_pool().outstanding() == 0
+    for r in readers:
+        r.close()
+
+
+@pytest.mark.skipif(not native_engine.available(),
+                    reason="native engine unavailable")
+def test_codec_job_native_gather_matches_numpy_fallback(tmp_path,
+                                                        monkeypatch):
+    """The same job through _device_codec_body with the survivors' values
+    copied by the native library and by the numpy fallback writes
+    byte-identical files; the native run counts exactly its survivors."""
+    rng = np.random.default_rng(38)
+    runs = []
+    for _ in range(3):
+        slab = _make_run(rng, 900, 3000, ttl_frac=0.2)
+        lens = rng.choice([1, 9, 41, 44, 47], size=slab.n).astype(np.int64)
+        offs = np.zeros(slab.n + 1, dtype=np.int64)
+        np.cumsum(lens, out=offs[1:])
+        slab.values = ValueArray(
+            rng.integers(0, 256, size=int(offs[-1]), dtype=np.uint8), offs)
+        runs.append(slab)
+    old_split = flags.get_flag("compaction_max_output_entries_per_sst")
+    old_shadow = flags.get_flag("shadow_verify_sample")
+    flags.set_flag("compaction_max_output_entries_per_sst", 700)
+    flags.set_flag("shadow_verify_sample", 0.0)
+    gm = gather_metrics()
+    cm = block_codec.codec_metrics()
+    try:
+        readers = _write_runs(str(tmp_path), runs)
+        n0, f0 = gm["native_rows"].value(), gm["fallback_rows"].value()
+        e0 = cm["encode_fallbacks"].value()
+        res = _run_job(readers, str(tmp_path / "native"), is_major=False)
+        assert gm["native_rows"].value() - n0 == res.rows_out
+        assert gm["fallback_rows"].value() == f0
+        n1 = gm["native_rows"].value()
+        monkeypatch.setattr(ValueArray, "gather", ValueArray._gather_numpy)
+        ref = _run_job(readers, str(tmp_path / "numpy"), is_major=False)
+        assert gm["native_rows"].value() == n1
+        assert cm["encode_fallbacks"].value() == e0   # both took the codec
+    finally:
+        flags.set_flag("compaction_max_output_entries_per_sst", old_split)
+        flags.set_flag("shadow_verify_sample", old_shadow)
+    assert len(res.outputs) >= 2, "expected a multi-file split"
+    assert res.tombstones_written == ref.tombstones_written > 0
+    assert _file_bytes(res.outputs) == _file_bytes(ref.outputs)
     for r in readers:
         r.close()

@@ -52,15 +52,40 @@ FLAG_HAS_TTL = 4
 FLAG_DEEP = 8
 
 
+def gather_metrics():
+    """Which implementation of ValueArray.gather's copy this process runs,
+    in rows (the storage entity, beside block_codec.codec_metrics())."""
+    from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
+    e = ROOT_REGISTRY.entity("server", "storage")
+    return {
+        "native_rows": e.counter(
+            "value_gather_native_rows_total",
+            "value rows copied by ValueArray.gather through the native "
+            "library (one memcpy per row)"),
+        "fallback_rows": e.counter(
+            "value_gather_fallback_rows_total",
+            "value rows copied by ValueArray.gather's numpy fallback (an "
+            "index per output byte): the native library did not build "
+            "on this host"),
+    }
+
+
 class ValueArray:
     """Columnar value payloads: ONE contiguous byte buffer + row offsets.
 
     The slab counterpart of hot loop ③'s output path (ref:
-    rocksdb/db/compaction_job.cc:958-1024 block building): gather/concat
-    are pure numpy offset arithmetic, so permuting a million values for an
-    SST write costs two vectorized indexing passes instead of a
-    per-row Python loop. Duck-types as a sequence of bytes rows
-    (va[i] -> bytes), which keeps point-read paths unchanged.
+    rocksdb/db/compaction_job.cc:958-1024 block building): permuting a
+    million values for an SST write is offset arithmetic over one element
+    per ROW in numpy (starts, lengths, output offsets), then `gather`
+    copies each selected row with one memcpy in the native library
+    (native/compaction_engine.cc ce_gather_rows), the interpreter lock
+    released. Where that library did not build, `_gather_numpy` does the
+    same copy by fancy-indexing the blob with one int64 index per output
+    BYTE: 24+ bytes of freshly mapped index temporaries for every byte
+    copied (~0.9 s to move 16 MB on the chip host, PERF.md) — the
+    fallback and the tests' oracle, not the fast path. Duck-types as a
+    sequence of bytes rows (va[i] -> bytes), which keeps point-read paths
+    unchanged.
     """
 
     __slots__ = ("data", "offsets")
@@ -131,7 +156,50 @@ class ValueArray:
     def gather(self, idx: np.ndarray, replace_mask: Optional[np.ndarray] = None,
                replacement: bytes = b"") -> "ValueArray":
         """Rows at `idx`, with rows under `replace_mask` substituted by
-        `replacement` (the compaction TTL-expiry -> tombstone rewrite)."""
+        `replacement` (the compaction TTL-expiry -> tombstone rewrite).
+        An index outside [0, len) raises IndexError."""
+        from yugabyte_tpu.storage import native_engine
+        idx = np.ascontiguousarray(idx, dtype=np.int64)
+        n = len(idx)
+        if n and (int(idx.min()) < 0 or int(idx.max()) >= len(self)):
+            raise IndexError(
+                f"ValueArray.gather: index outside [0, {len(self)})")
+        if not native_engine.available():
+            gather_metrics()["fallback_rows"].increment(n)
+            return self._gather_numpy(idx, replace_mask, replacement)
+        starts = self.offsets[idx]
+        ends = self.offsets[1:][idx]
+        lens = ends - starts
+        data = np.ascontiguousarray(self.data)
+        # the native copy trusts its spans: hold them to the blob here
+        # (numpy's fancy index raised IndexError on offsets past the data)
+        if n and (int(starts.min()) < 0 or int(lens.min()) < 0
+                  or int(ends.max()) > len(data)):
+            raise IndexError("ValueArray.gather: row offsets outside the blob")
+        rep = from_rep = None
+        if replace_mask is not None and replacement is not None \
+                and replace_mask.any():
+            # replaced rows read the replacement's own buffer (never a
+            # concatenated copy of the whole blob)
+            from_rep = np.ascontiguousarray(replace_mask, dtype=bool)
+            rep = np.frombuffer(replacement, dtype=np.uint8)
+            starts[from_rep] = 0
+            lens[from_rep] = len(rep)
+        out_off = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum(lens, out=out_off[1:])
+        out = np.empty(int(out_off[-1]), dtype=np.uint8)
+        if len(out):
+            native_engine.gather_rows(data, starts, lens, out_off, out,
+                                      rep=rep, from_rep=from_rep)
+        gather_metrics()["native_rows"].increment(n)
+        return ValueArray(out, out_off)
+
+    def _gather_numpy(self, idx: np.ndarray,
+                      replace_mask: Optional[np.ndarray] = None,
+                      replacement: bytes = b"") -> "ValueArray":
+        """`gather` without the native library, and the tests' oracle for
+        it: an int64 index per output byte (a 2-D one for rows of one
+        length), then one fancy index of the blob."""
         idx = np.asarray(idx, dtype=np.int64)
         starts = self.offsets[idx]
         lens = self.offsets[idx + 1] - starts

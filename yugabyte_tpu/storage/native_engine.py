@@ -97,6 +97,9 @@ def _load():
                                         ctypes.c_int32]
         lib.ce_bloom_build.argtypes = [
             _u64p, ctypes.c_int64, _u8p, ctypes.c_uint64, ctypes.c_int32]
+        lib.ce_gather_rows.restype = None
+        lib.ce_gather_rows.argtypes = [
+            _u8p, _u8p, _u8p, _i64p, _i64p, _i64p, ctypes.c_int64, _u8p]
         lib.ce_runcache_export.restype = ctypes.c_int64
         lib.ce_runcache_export.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, _u8p,
@@ -138,6 +141,27 @@ def bloom_build(hashes: np.ndarray, bits: np.ndarray,
                        ctypes.c_int64(len(h)),
                        bits.ctypes.data_as(_u8p),
                        ctypes.c_uint64(m_bits), ctypes.c_int32(k))
+
+
+def gather_rows(src: np.ndarray, starts: np.ndarray, lens: np.ndarray,
+                out_off: np.ndarray, out: np.ndarray,
+                rep: Optional[np.ndarray] = None,
+                from_rep: Optional[np.ndarray] = None) -> None:
+    """out[out_off[i]: +lens[i]] = src[starts[i]: +lens[i]] for every row,
+    one memcpy each (ops/slabs.py ValueArray.gather); a row whose
+    `from_rep` byte is set reads `rep` instead of `src`. All arrays
+    C-contiguous (uint8 bytes, int64 spans), every span inside its buffer:
+    the caller checks, nothing here does. ctypes drops the interpreter lock
+    for the copy."""
+    lib = _load()
+    with_rep = rep is not None and from_rep is not None
+    lib.ce_gather_rows(
+        src.ctypes.data_as(_u8p),
+        rep.ctypes.data_as(_u8p) if with_rep else None,
+        from_rep.ctypes.data_as(_u8p) if with_rep else None,
+        starts.ctypes.data_as(_i64p), lens.ctypes.data_as(_i64p),
+        out_off.ctypes.data_as(_i64p), ctypes.c_int64(len(starts)),
+        out.ctypes.data_as(_u8p))
 
 
 def runcache_drop(run_id: int) -> None:
