@@ -727,7 +727,11 @@ def dist_phase(phase: Phase, devices, seed: int, rows_per_run: int,
     require(_sst_files(res_dist.outputs) == _sst_files(res_one.outputs),
             "mesh job outputs differ from the single-device job's")
 
-    # one pool wave of four tablet jobs vs the same four, sequentially
+    # one pool wave of four tablet jobs vs the same four, sequentially.
+    # A correctness run: the four jobs go straight to `pool.submit` from
+    # this thread, in however many waves the scheduler makes of them. A
+    # server's jobs reach the pool through DB.compact_all() on its
+    # compaction threads: benchmarks/drivers/pool.py times that (PR 27).
     pool = CompactionPool(mesh, device=devices[0])
     shared = DeviceSlabCache(devices[0])
     try:

@@ -139,12 +139,15 @@ def test_every_pipeline_span_in_the_source_is_a_listed_stage():
     import yugabyte_tpu
     root = os.path.dirname(yugabyte_tpu.__file__)
     call = re.compile(r'pipeline_span\(\s*"(\w+)"([^)]*)\)')
+    pool_call = re.compile(r'pool_span\(\s*"(\w+)"\)')  # stage pool_<name>
     found = set()
     for dirpath, _dirs, files in os.walk(root):
         for name in files:
             if name.endswith(".py"):
                 with open(os.path.join(dirpath, name)) as f:
-                    for stage, rest in call.findall(f.read()):
+                    text = f.read()
+                    found.update("pool_" + n for n in pool_call.findall(text))
+                    for stage, rest in call.findall(text):
                         m = re.search(r'inclusive="(\w+)"', rest)
                         if m:
                             found.add(m.group(1))

@@ -380,6 +380,11 @@ class SSTReader:
         if self._data is not None:
             self._data.close()
             self._data = None
+            if self.block_cache is not None:
+                # the cache is keyed by path: a tablet directory made
+                # anew (deleted and bootstrapped again, file ids from 1)
+                # must not be served the old file's blocks
+                self.block_cache.drop_file(self.base_path)
 
     @property
     def n_blocks(self) -> int:
@@ -448,6 +453,8 @@ class BlockCache:
         self.capacity = capacity_bytes
         self.used = 0
         self._map: "OrderedDict" = OrderedDict()
+        # a reader's keys are (SST path, block index): path -> its keys
+        self._by_file: dict = {}
         self._lock = threading.Lock()
 
     def get(self, key):
@@ -463,14 +470,26 @@ class BlockCache:
             if key in self._map:
                 return
             self._map[key] = (slab, size)
+            self._by_file.setdefault(key[0], set()).add(key)
             self.used += size
             while self.used > self.capacity and self._map:
                 self._pop_lru_locked()
 
     def _pop_lru_locked(self) -> int:
-        _, (_, sz) = self._map.popitem(last=False)
+        key, (_, sz) = self._map.popitem(last=False)
+        keys = self._by_file[key[0]]
+        keys.discard(key)
+        if not keys:
+            del self._by_file[key[0]]
         self.used -= sz
         return sz
+
+    def drop_file(self, path) -> None:
+        """Forget every block of one SST (its reader closed: the file is
+        being deleted, or its DB shut down)."""
+        with self._lock:
+            for key in self._by_file.pop(path, ()):
+                self.used -= self._map.pop(key)[1]
 
     def evict(self, required: int) -> int:
         """LRU-evict at least ``required`` bytes; the MemTracker GC hook

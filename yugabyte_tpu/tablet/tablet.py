@@ -147,6 +147,22 @@ class TabletOptions:
     lower_bound_key: bytes = b""
     upper_bound_key: Optional[bytes] = None
 
+    def regular_db_options(self, retention_policy) -> DBOptions:
+        """What a tablet opens its regular DB with (the intents DB takes
+        the subset `Tablet` names)."""
+        return DBOptions(
+            block_entries=self.block_entries,
+            device=self.device,
+            mesh=self.mesh,
+            offload_policy=self.offload_policy,
+            device_cache=self.device_cache,
+            compaction_pool=self.compaction_pool,
+            mesh_pool=self.mesh_pool,
+            block_cache=self.block_cache,
+            retention_policy=retention_policy,
+            memstore_size_bytes=self.memstore_size_bytes,
+            auto_compact=self.auto_compact)
+
 
 class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit goes to the consensus seam, and all cross-thread mutable state lives in DB/RaftConsensus/ admission, each covered by its own guarded-by annotations)
     def __init__(self, tablet_id: str, data_dir: str, schema: Schema,
@@ -160,18 +176,8 @@ class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit g
         self.retention_policy = TabletRetentionPolicy(self.clock)
         from yugabyte_tpu.tablet.retryable_requests import RetryableRequests
         self.retryable = RetryableRequests()
-        db_opts = DBOptions(
-            block_entries=self.opts.block_entries,
-            device=self.opts.device,
-            mesh=self.opts.mesh,
-            offload_policy=self.opts.offload_policy,
-            device_cache=self.opts.device_cache,
-            compaction_pool=self.opts.compaction_pool,
-            mesh_pool=self.opts.mesh_pool,
-            block_cache=self.opts.block_cache,
-            retention_policy=self.retention_policy.history_cutoff,
-            memstore_size_bytes=self.opts.memstore_size_bytes,
-            auto_compact=self.opts.auto_compact)
+        db_opts = self.opts.regular_db_options(
+            self.retention_policy.history_cutoff)
         # Two DB instances, exactly like the reference (tablet.h:856-857):
         # committed data in regular_db, provisional records in intents_db.
         self.regular_db = DB(os.path.join(data_dir, "regular"), db_opts)

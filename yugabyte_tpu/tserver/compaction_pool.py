@@ -48,7 +48,15 @@ import numpy as np
 from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils.cancellation import (CancellationToken,
                                              OperationCancelled)
+from yugabyte_tpu.utils.metrics import pool_span
 from yugabyte_tpu.utils.trace import TRACE
+
+# Longest the scheduler holds a round back while fewer wave jobs than
+# slots are queued. Compaction threads handed work at the same instant
+# arrive within a few milliseconds of each other; a wave job takes some
+# hundreds.
+_WAVE_LINGER_S = 0.020
+
 
 @dataclass
 class PoolRequest:
@@ -289,26 +297,54 @@ class CompactionPool:
     def _queue_depth_unlocked(self) -> int:
         return sum(len(q) for q in self._queues.values())
 
+    def _is_mesh_job(self, job: _Job) -> bool:
+        """A job at or above `distributed_compaction_min_rows` takes the
+        whole mesh (`_run_exclusive`) and no wave slot."""
+        return (self.n_slots > 1 and job.request.slabs is None
+                and job.request.est_rows >= flags.get_flag(
+                    "distributed_compaction_min_rows"))
+
+    def _wave_jobs_queued_unlocked(self) -> int:
+        return sum(1 for q in self._queues.values() for job in q
+                   if not self._is_mesh_job(job))
+
+    def _linger_for_full_wave_unlocked(self) -> None:
+        """Hold the round back, for `_WAVE_LINGER_S` at most, while fewer
+        wave jobs than slots are queued: a round taken at the first
+        arrival would dispatch a one-job wave."""
+        deadline = time.monotonic() + _WAVE_LINGER_S
+        while not self._shutdown \
+                and self._wave_jobs_queued_unlocked() < self.n_slots:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                return
+            self._cond.wait(timeout=left)
+
     def _take_round(self) -> List[_Job]:
-        """Pop up to n_slots queue heads in deficit-fair order: tablets
-        sorted by rows served ascending, then round-robin across their
-        queues until the slots fill or the queues drain."""
+        """Pop queue heads in deficit-fair order: tablets sorted by rows
+        served ascending, then round-robin across their queues until
+        n_slots wave jobs are picked or the queues drain. A mesh-sized
+        head rides the round without taking a slot (it runs exclusively
+        after the waves), so it never leaves a wave short."""
         with self._cond:
             while not self._shutdown \
                     and self._queue_depth_unlocked() == 0:
                 self._cond.wait(timeout=0.5)
+            self._linger_for_full_wave_unlocked()
             if self._shutdown:
                 return []
             order = sorted(
                 (tid for tid, q in self._queues.items() if q),
                 key=lambda tid: self._credits.get(tid, 0.0))
             picked: List[_Job] = []
-            while len(picked) < self.n_slots:
+            slots = 0
+            while slots < self.n_slots:
                 progressed = False
                 for tid in order:
                     q = self._queues.get(tid)
-                    if q and len(picked) < self.n_slots:
+                    if q and slots < self.n_slots:
                         picked.append(q.popleft())
+                        slots += not self._is_mesh_job(picked[-1])
                         progressed = True
                 if not progressed:
                     break
@@ -367,15 +403,14 @@ class CompactionPool:
         # failures and cancellations here affect only their own job
         staged_jobs: List[_Job] = []
         big_jobs: List[_Job] = []
-        dist_min = flags.get_flag("distributed_compaction_min_rows")
         for job in jobs:
             try:
                 job.handle.cancel_token.check()
-                if self.n_slots > 1 and job.request.slabs is None \
-                        and job.request.est_rows >= dist_min:
+                if self._is_mesh_job(job):
                     big_jobs.append(job)
                     continue
-                self._stage_job(job)
+                with pool_span("stage"):
+                    self._stage_job(job)
                 if job.staged is None:      # nothing to merge
                     continue
                 staged_jobs.append(job)
@@ -401,13 +436,15 @@ class CompactionPool:
                 # open fault-quarantine window, or sticky mismatch): run
                 # these natively until a probe / the decay re-opens it
                 for job in group:
-                    self._complete_natively(job, record_rate=True)
+                    with pool_span("native"):
+                        self._complete_natively(job, record_rate=True)
                 continue
             self._run_wave(bucket, key[3], key[4], group)
 
         # whole-mesh jobs run after the waves (exclusive use of the mesh)
         for job in big_jobs:
-            self._run_exclusive(job)
+            with pool_span("exclusive"):
+                self._run_exclusive(job)
 
     def _stage_job(self, job: _Job) -> None:
         """Filter + read + pack one job's device-stage input. Resident
@@ -487,12 +524,14 @@ class CompactionPool:
             self._g_fill.set(len(wave) / self.n_slots)
             t0 = time.monotonic()
             try:
-                handle = pooled_merge_gc(
-                    self.mesh,
-                    [(job.staged,
-                      GCParams(job.request.history_cutoff_ht, is_major,
-                               retain_deletes))
-                     for job in wave])
+                # the one dispatch and the wait for its decisions
+                with pool_span("wave"):
+                    handle = pooled_merge_gc(
+                        self.mesh,
+                        [(job.staged,
+                          GCParams(job.request.history_cutoff_ht, is_major,
+                                   retain_deletes))
+                         for job in wave])
             except Exception as e:  # noqa: BLE001 — wave fault containment
                 if not device_faults.is_device_fault(e):
                     for job in wave:
@@ -510,7 +549,8 @@ class CompactionPool:
                       "k_pad=%d m=%d quarantined; completing %d job(s) "
                       "natively", e, bucket[0], bucket[1], len(wave))
                 for job in wave:
-                    self._complete_natively(job, record_rate=False)
+                    with pool_span("native"):
+                        self._complete_natively(job, record_rate=False)
                 continue
             self._c_waves.increment()
             wall = max(time.monotonic() - t0, 1e-9)
@@ -520,7 +560,8 @@ class CompactionPool:
             for slot, job in enumerate(wave):
                 self._c_wave_jobs.increment()
                 try:
-                    self._finish_wave_job(job, handle, slot)
+                    with pool_span("finish"):
+                        self._finish_wave_job(job, handle, slot)
                 except BaseException as e:  # noqa: BLE001 — per-job
                     self._finish(job, exc=e)
                 finally:

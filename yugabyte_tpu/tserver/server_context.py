@@ -74,12 +74,11 @@ class ServerExecutionContext:  # yblint: disable=ybsan-coverage (set-once-in-__i
     cache are shared server-wide."""
 
     def __init__(self, metrics: Optional[MetricRegistry] = None,
-                 device=None):
-        self.pool = PriorityThreadPool(
-            max_threads=flags.get_flag("tserver_compaction_pool_size"),
-            name="compact")
+                 device=None, mesh=None):
+        """device (with, optionally, a mesh that holds it): the caller's
+        choice in place of the `tserver_device` flag's."""
         if device is not None:
-            self.device, self.mesh = device, None
+            self.device, self.mesh = device, mesh
         else:
             self.device, self.mesh = resolve_device(
                 flags.get_flag("tserver_device"))
@@ -92,8 +91,21 @@ class ServerExecutionContext:  # yblint: disable=ybsan-coverage (set-once-in-__i
         # device-routed compactions from every hosted tablet share the
         # mesh through batch-slot waves / whole-mesh dist jobs
         self.compaction_pool = None
-        if self.mesh is not None \
-                and flags.get_flag("tserver_mesh_compaction_pool"):
+        n_threads = flags.get_flag("tserver_compaction_pool_size")
+        pooled = self.mesh is not None \
+            and flags.get_flag("tserver_mesh_compaction_pool")
+        if pooled:
+            # A compaction thread blocks in `pool_wait` for the whole of
+            # the job it submits, so the threads bound what the mesh pool
+            # can ever see queued: with the flag's 2 a four-slot wave is
+            # never more than half full. Two waves' worth, so that the
+            # next wave queues up while the slots of this one finish
+            # (upstream sizes priority_thread_pool_size by the machine;
+            # here the mesh is the machine). No mesh: the flag, as ever.
+            n_threads = max(n_threads, 2 * int(self.mesh.devices.size))
+        self.pool = PriorityThreadPool(max_threads=n_threads,
+                                       name="compact")
+        if pooled:
             from yugabyte_tpu.tserver.compaction_pool import CompactionPool
             self.compaction_pool = CompactionPool(self.mesh,
                                                   device=self.device)

@@ -433,7 +433,10 @@ def publish_compile_surface(counts: Dict[str, int]) -> None:
 #   job = device + write + shadow + decode + encode + <the rest> + job_other
 # on the job's thread. `host` is the legacy inclusive slice (raw-byte
 # ingest + merge staging + decision decode); it overlaps the ingest
-# stages and is in no sum.
+# stages and is in no sum. The `pool_*` names are the mesh pool's
+# scheduler thread (tserver/compaction_pool.py, `pool_span`): self times
+# too, so that what runs on that thread sums to its busy wall, while the
+# submitting job's thread sits in `pool_wait`.
 _PIPELINE_STAGES = (
     "host", "device", "write", "shadow", "decode", "encode",
     "job", "job_other",
@@ -446,7 +449,9 @@ _PIPELINE_STAGES = (
     "value_gather", "cache_install", "pace", "installer_finish",
     "run_export",
     "native_ingest", "native_merge",
-    "version_install", "reader_open", "input_delete")
+    "version_install", "reader_open", "input_delete",
+    "pool_stage", "pool_wave", "pool_finish", "pool_exclusive",
+    "pool_native")
 
 _stage_metrics: Dict[str, Tuple[Histogram, Gauge]] = {}
 
@@ -500,6 +505,15 @@ _pipeline_sinks: Dict[Tuple[Optional[str], Optional[str]],
 _NAMED = object()
 
 
+def _pipeline_sink(stage: Optional[str],
+                   inclusive: Optional[str]) -> _PipelineSink:
+    key = (stage, inclusive)
+    sink = _pipeline_sinks.get(key)
+    if sink is None:
+        sink = _pipeline_sinks[key] = _PipelineSink(*key)
+    return sink
+
+
 def pipeline_span(name: str, inclusive: Optional[str] = None,
                   stage=_NAMED, parent=AMBIENT) -> span:
     """The span "yb/compact/<name>" of a compaction job. Its self time is
@@ -507,11 +521,17 @@ def pipeline_span(name: str, inclusive: Optional[str] = None,
     thread whose wall overlaps the job thread's stages), its inclusive
     time under `inclusive` where given (the root's `job`, the legacy
     `host` of the ingest, launch and unpack spans)."""
-    key = (name if stage is _NAMED else stage, inclusive)
-    sink = _pipeline_sinks.get(key)
-    if sink is None:
-        sink = _pipeline_sinks[key] = _PipelineSink(*key)
-    return span("compact/" + name, sink, parent)
+    return span("compact/" + name,
+                _pipeline_sink(name if stage is _NAMED else stage,
+                               inclusive), parent)
+
+
+def pool_span(name: str) -> span:
+    """The span "yb/pool/<name>" of the mesh compaction pool's scheduler
+    thread; its self time is the pipeline stage `pool_<name>`. The job
+    spans opened under it (`write`, `encode`, ...) keep their own
+    stages, as on a job's own thread."""
+    return span("pool/" + name, _pipeline_sink("pool_" + name, None))
 
 
 def pipeline_stage_totals() -> Dict[str, float]:
