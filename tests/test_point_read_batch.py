@@ -96,6 +96,21 @@ def _fill_db(tmp_path, n_keys=1200, n_ssts=3, device=True,
     return db
 
 
+def _poison_models(db):
+    """Every learned index reversed with a bound of 0, and the readers
+    reloaded so the poisoned models serve: the locate flags its
+    mispredictions and those keys take the exact path."""
+    for fid, r in list(db._readers.items()):
+        m = r.props.lindex
+        if m is None:
+            continue
+        bad = dict(m, a_hi=list(reversed(m["a_hi"])),
+                   a_lo=list(reversed(m["a_lo"])), max_err=0)
+        learned_index.attach_learned_index(r.base_path, bad)
+        db._readers[fid] = SSTReader(r.base_path, db.opts.block_cache)
+        r.close()
+
+
 def _query_keys(n_keys, rng, m=400):
     # hits, misses past the range, and misses interleaved in the range
     ids = list(rng.integers(0, n_keys + 200, size=m))
@@ -247,19 +262,7 @@ class TestLearnedIndex:
             expect = [db.get(k) for k in keys]
             from yugabyte_tpu.ops.point_read import point_read_metrics
             fb0 = point_read_metrics()["learned_fallbacks"].value()
-            for fid, r in list(db._readers.items()):
-                m = r.props.lindex
-                if m is None:
-                    continue
-                bad = dict(m)
-                bad["a_hi"] = list(reversed(m["a_hi"]))
-                bad["a_lo"] = list(reversed(m["a_lo"]))
-                bad["max_err"] = 0
-                learned_index.attach_learned_index(r.base_path, bad)
-                # reload the reader so the poisoned model serves
-                db._readers[fid] = SSTReader(r.base_path,
-                                             db.opts.block_cache)
-                r.close()
+            _poison_models(db)
             assert db.multi_get(keys) == expect
             assert point_read_metrics()["learned_fallbacks"].value() > fb0
         finally:
@@ -394,6 +397,286 @@ class TestDeviceFaults:
             assert db.background_error is not None
             assert db.background_error.code == Code.CORRUPTION
             assert db._pins == {}
+        finally:
+            db.close()
+
+
+# ------------------------------------------- the host side, chunk by chunk
+def _ht(us: int) -> HybridTime:
+    return HybridTime.from_micros(us)
+
+
+def _wide_key(i: int) -> bytes:
+    """28 bytes: staged 8 words wide where _key's 16 bytes stage 4."""
+    return _key(i) + b"K%09dxx" % i
+
+
+def _zoo_db(tmp_path, n=900, block_entries=32):
+    """One DB with everything the batched path merges: three SSTs of the
+    narrow width holding overlapping versions (and tombstones) of the
+    same keys in small blocks, one SST of the wide width, and a memtable
+    that is newer than the files for some keys, OLDER for some, and
+    holds tombstones."""
+    dev = _device()
+    db = DB(str(tmp_path / "zoo"), DBOptions(
+        device=dev, device_cache=DeviceSlabCache(device=dev),
+        auto_compact=False, block_entries=block_entries))
+    for f in range(3):
+        items = []
+        for i in range(0, n, f + 1):   # file 0: every key; 1: evens; ...
+            v = _tomb() if (i + f) % 23 == 0 else b"f%d-%06d-" % (f, i) \
+                + b"v" * (i % 40)
+            items.append((_key(i), DocHybridTime(
+                _ht(1000 + 100 * f + i % 7), f), v))
+        db.write_batch(items, op_id=(1, f + 1))
+        db.flush()
+    db.write_batch([(_wide_key(i), DocHybridTime(_ht(1500), 0),
+                     b"wide-%d" % i) for i in range(0, n, 3)],
+                   op_id=(1, 4))
+    db.flush()
+    mem = []
+    for i in range(0, n, 5):
+        if i % 15 == 0:      # older than every file's version: loses
+            mem.append((_key(i), DocHybridTime(_ht(900), 9), b"old-mem"))
+        elif i % 15 == 5:    # newest, a tombstone
+            mem.append((_key(i), DocHybridTime(_ht(5000), 0), _tomb()))
+        else:                # newest, a value
+            mem.append((_key(i), DocHybridTime(_ht(5000), 1),
+                        b"mem-%d" % i))
+    db.write_batch(mem, op_id=(1, 5))
+    return db
+
+
+def _entry_blocks(db):
+    """(key, doc hybrid time) -> (file id, block) of every SST entry."""
+    where = {}
+    for fid, r in db._readers.items():
+        for blk in range(r.n_blocks):
+            slab = r.read_block(blk)
+            for i in range(slab.n):
+                where[(slab.key_bytes(i), slab.doc_ht(i))] = (fid, blk)
+    return where
+
+
+_ZOO_CASES = {
+    # (a) keys with versions in two and three files
+    "overlapping_versions": lambda n: [_key(i) for i in range(0, n, 2)],
+    # (b) a run of neighbours (one block) and a stride over every block
+    "one_block_and_many": lambda n: (
+        [_key(i) for i in range(100, 130)]
+        + [_key(i) for i in range(0, n, 37)]),
+    # (c) keys the memtable wins, loses, and buries
+    "memtable_wins_loses_tombstones": lambda n: [
+        _key(i) for i in range(0, n, 5)] + [_key(i) for i in range(1, 60)],
+    # (d) nothing anywhere, inside and past the key range
+    "absent": lambda n: [_key(n + i) for i in range(40)] + [
+        _key(i) + b"\x01" for i in range(0, n, 50)],
+    # (e) longer than the narrow files' 16 bytes, and than every file's
+    "longer_than_width": lambda n: [
+        _key(3) + b"\x00", _key(4) + b"Z" * 30, _wide_key(3) + b"q" * 9,
+        _key(3), _wide_key(3)],
+    # (f) both widths in one batch
+    "two_widths": lambda n: [
+        k for i in range(0, n, 9) for k in (_key(i), _wide_key(i))],
+    # (g) a batch of one, a hit and a miss
+    "batch_of_one": lambda n: [_key(7)],
+    "batch_of_one_absent": lambda n: [_key(n + 7)],
+    # (h) the learned index mispredicts: exact_fallback keys
+    "exact_fallback": lambda n: [_key(i) for i in range(0, n, 3)],
+}
+
+
+class TestHostSideByChunk:
+    @pytest.mark.parametrize("case", sorted(_ZOO_CASES))
+    def test_multi_get_equals_gets(self, tmp_path, case):
+        from yugabyte_tpu.ops.point_read import point_read_metrics
+        n = 900
+        db = _zoo_db(tmp_path, n)
+        try:
+            if case == "exact_fallback":
+                _poison_models(db)
+            keys = _ZOO_CASES[case](n)
+            m = point_read_metrics()
+            before = {k: m[k].value() for k in
+                      ("batches", "device_fallbacks", "learned_fallbacks")}
+            for read_ht in (None, _ht(950), _ht(1003), _ht(1104),
+                            _ht(1500), _ht(4999)):
+                want = [db.get(k, read_ht) for k in keys]
+                got = db.multi_get(keys, read_ht)
+                assert len(got) == len(want)
+                for k, g, w in zip(keys, got, want):
+                    assert g == w, (case, read_ht, k)
+            # the device path answered, every time
+            assert m["batches"].value() == before["batches"] + 6
+            assert m["device_fallbacks"].value() \
+                == before["device_fallbacks"]
+            assert (m["learned_fallbacks"].value()
+                    > before["learned_fallbacks"]) \
+                == (case == "exact_fallback")
+            if case == "memtable_wins_loses_tombstones":
+                vals = [r[1] for r in db.multi_get(keys) if r is not None]
+                assert any(v.startswith(b"mem-") for v in vals)
+                assert _tomb() in vals
+                assert b"old-mem" not in vals
+                assert b"old-mem" in [
+                    r[1] for r in db.multi_get(keys, _ht(950))
+                    if r is not None]
+        finally:
+            db.close()
+
+    def test_value_fetch_counters(self, tmp_path):
+        """value_fetch_rows counts the SST winners, value_fetch_blocks
+        the distinct blocks read for them: a run of neighbours costs one
+        read_block, not one a winner."""
+        from yugabyte_tpu.ops.point_read import (point_read_metrics,
+                                                 point_read_snapshot)
+        db = _fill_db(tmp_path, n_keys=1200, n_ssts=3, mem_overlay=False)
+        try:
+            where = _entry_blocks(db)
+            assert len({b for _f, b in where.values()}) == 1  # 4096 a block
+            m = point_read_metrics()
+            for keys in ([_key(i) for i in range(300, 360)],
+                         [_key(i) for i in range(0, 1400, 11)],
+                         [_key(5000)]):
+                rows0 = m["value_fetch_rows"].value()
+                blocks0 = m["value_fetch_blocks"].value()
+                got = db.multi_get(keys)
+                winners = [(k, r[0]) for k, r in zip(keys, got)
+                           if r is not None]
+                assert m["value_fetch_rows"].value() - rows0 \
+                    == len(winners)
+                assert m["value_fetch_blocks"].value() - blocks0 \
+                    == len({where[w] for w in winners})
+            snap = point_read_snapshot()
+            assert snap["value_fetch_rows"] == m["value_fetch_rows"].value()
+            assert snap["value_fetch_blocks"] \
+                == m["value_fetch_blocks"].value()
+        finally:
+            db.close()
+
+    def test_value_fetch_counters_small_blocks(self, tmp_path):
+        """The same two counts where the winners spread over many
+        blocks of several files, some of them beaten by a memtable."""
+        from yugabyte_tpu.ops.point_read import point_read_metrics
+        n = 900
+        db = _zoo_db(tmp_path, n)
+        try:
+            assert all(r.n_blocks > 5 for r in db._readers.values())
+            where = _entry_blocks(db)
+            m = point_read_metrics()
+            keys = [_key(i) for i in range(0, n, 2)] \
+                + [_wide_key(i) for i in range(0, n, 6)]
+            rows0 = m["value_fetch_rows"].value()
+            blocks0 = m["value_fetch_blocks"].value()
+            got = db.multi_get(keys)
+            assert got == [db.get(k) for k in keys]
+            # a memtable's winner is in no file at its hybrid time
+            winners = [(k, r[0]) for k, r in zip(keys, got)
+                       if r is not None and (k, r[0]) in where]
+            assert 0 < len(winners) < sum(r is not None for r in got)
+            n_blocks = len({where[w] for w in winners})
+            assert 5 < n_blocks < len(winners)
+            assert m["value_fetch_rows"].value() - rows0 == len(winners)
+            assert m["value_fetch_blocks"].value() - blocks0 == n_blocks
+        finally:
+            db.close()
+
+    def test_resident_operands_go_with_the_file(self, tmp_path):
+        """What is kept per file (bloom words, entry count, learned
+        index on the device; the blocks' first rows) is built on the
+        file's first read and dropped when a compaction replaces the
+        file: the new file is served with its own."""
+        db = _fill_db(tmp_path, n_keys=1200, n_ssts=3, mem_overlay=False)
+        keys = [_key(i) for i in range(0, 1300, 7)]
+        try:
+            want = [db.get(k) for k in keys]
+            assert db.multi_get(keys) == want
+            old = list(db._readers.values())
+            assert len(old) == 3
+            for r in old:
+                words, m_bits, k = r._bloom_dev
+                assert int(m_bits) == r.bloom.m_bits and int(k) == r.bloom.k
+                n_dev, model = r._locate_dev
+                assert int(n_dev) == r.props.n_entries == 400
+                assert [int(x) for x in model[0]] == r.props.lindex["a_hi"]
+                assert int(model[4]) == r.props.lindex["max_err"]
+                assert r._row_offs_pr.tolist() == [0, 400]
+                # uncommitted, as jnp.int32(...) made them: a committed
+                # operand would be another program to the compiler
+                assert not any(x.committed for x in
+                               (m_bits, k, n_dev) + tuple(model))
+            held = old[0]._locate_dev
+            assert db.multi_get(keys) == want
+            assert old[0]._locate_dev is held      # built once
+            db.compact_all()
+            (new,) = db._readers.values()
+            assert new not in old
+            for r in old:
+                assert r._bloom_dev is None and r._locate_dev is None \
+                    and r._row_offs_pr is None
+            assert new._locate_dev is None         # nothing read it yet
+            assert db.multi_get(keys) == want == [db.get(k) for k in keys]
+            n_dev, model = new._locate_dev
+            assert int(n_dev) == new.props.n_entries
+            assert new.props.n_entries != 400
+            assert new._row_offs_pr[-1] == new.props.n_entries
+            assert int(new._bloom_dev[1]) == new.bloom.m_bits
+        finally:
+            db.close()
+
+    def test_second_batch_compiles_nothing(self, tmp_path):
+        """A second multi_get of the same shape finds every program
+        compiled: the operands reach the three programs with the avals
+        prewarm_point_read lowers them for, call after call."""
+        import jax
+        from yugabyte_tpu.ops import point_read as pr
+        db = _fill_db(tmp_path, n_keys=1200, n_ssts=3)
+        programs = (pr._fnv64_fused, pr._bloom_probe_fused,
+                    pr._locate_gather_fused)
+        seen = []
+        real = pr._locate_gather_fused
+
+        def spy(*args, **statics):
+            seen.append((args, statics))
+            return real(*args, **statics)
+
+        compiles = []
+
+        def on_event(event, _secs, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(event)
+
+        try:
+            assert db.multi_get([_key(i) for i in range(40)]) \
+                == [db.get(_key(i)) for i in range(40)]
+            sizes = [f._cache_size() for f in programs]
+            jax.monitoring.register_event_duration_secs_listener(on_event)
+            pr._locate_gather_fused = spy
+            try:
+                keys = [_key(i) for i in range(500, 560)]   # same bucket
+                got = db.multi_get(keys, HybridTime.from_micros(60_000))
+            finally:
+                pr._locate_gather_fused = real
+                jax.monitoring.unregister_event_duration_listener(on_event)
+            assert got == [db.get(k, HybridTime.from_micros(60_000))
+                           for k in keys]
+            assert compiles == []
+            assert [f._cache_size() for f in programs] == sizes
+            assert len(seen) == 3
+            s17 = (learned_index.LINDEX_SEGMENTS + 1,)
+            for args, statics in seen:
+                assert statics == {"w": 4, "use_model": True}
+                got_avals = [(tuple(np.shape(a)), np.dtype(a.dtype).name)
+                             for a in args]
+                assert got_avals == [
+                    ((12, args[0].shape[1]), "uint32"), ((), "int32"),
+                    ((64, 4), "uint32"), ((64,), "int32"),
+                    ((), "uint32"), ((), "uint32"),
+                    (s17, "uint32"), (s17, "uint32"), (s17, "int32"),
+                    ((), "int32"), ((), "int32")]
+                # no Python number among them: a weak-typed scalar is
+                # another aval, and another executable
+                assert not any(isinstance(a, (int, float)) for a in args)
         finally:
             db.close()
 

@@ -108,6 +108,13 @@ def point_read_metrics():
             "point_read_device_fallback_total",
             "multi_get batches completed via the native per-key path "
             "after a device fault"),
+        "value_fetch_rows": e.counter(
+            "point_read_value_fetch_rows_total",
+            "SST winners whose value the batched path fetched"),
+        "value_fetch_blocks": e.counter(
+            "point_read_value_fetch_blocks_total",
+            "distinct blocks read for those winners, chunk by chunk: "
+            "rows over blocks is how many winners one read_block serves"),
         "max_error": e.gauge(
             "learned_index_max_error_rows",
             "recorded max-error bound (entry positions) of the most "
@@ -388,50 +395,101 @@ def pack_query_batch(keys: Sequence[bytes], w: int
     """Pad a key batch to (batch_bucket(B), w) uint32 words + int32 lens.
     Keys longer than w*4 bytes are truncated in the word matrix but keep
     their true length, so the exact-match compare can never accept them
-    (no entry of a w-wide SST has key_len > w*4)."""
-    from yugabyte_tpu.ops.slabs import _pad_keys_to_words
-    b_pad = batch_bucket(len(keys))
-    clipped = [k[: w * 4] for k in keys]
-    words, _lens = _pad_keys_to_words(clipped, width_words=w)
+    (no entry of a w-wide SST has key_len > w*4). The first w' columns of
+    the result ARE the batch packed at a narrower width w' (big-endian
+    words, zero padded): a chunk is packed once, at its widest width."""
+    n = len(keys)
+    stride = w * 4
+    lens = np.fromiter(map(len, keys), dtype=np.int64, count=n)
+    clens = lens
+    if n and int(lens.max()) > stride:
+        keys = [k[:stride] for k in keys]
+        clens = np.minimum(lens, stride)
+    rows = np.zeros((n, stride), dtype=np.uint8)
+    flat = np.frombuffer(b"".join(keys), dtype=np.uint8)
+    # byte i of the joined keys lands at its row's start + its offset
+    # within the key: i + (row * stride - start of the row's key)
+    shift = np.arange(n, dtype=np.int64) * stride
+    shift[1:] -= np.cumsum(clens[:-1])
+    rows.reshape(-1)[np.arange(len(flat), dtype=np.int64)
+                     + np.repeat(shift, clens)] = flat
+    b_pad = batch_bucket(n)
     out_w = np.zeros((b_pad, w), dtype=np.uint32)
-    out_w[: len(keys)] = words
+    out_w[:n] = rows.view(">u4")
     out_l = np.zeros(b_pad, dtype=np.int32)
-    out_l[: len(keys)] = [len(k) for k in keys]
+    out_l[:n] = lens
     return out_w, out_l
 
 
+# Operands that depend on the file alone are put on the device once and
+# kept on the reader (dropped by its close()). The scalars and the
+# model's arrays go UNCOMMITTED (no device named), as the arrays that
+# jnp.asarray / jnp.int32 made of them on every call were: a committed
+# operand lowers with a sharding on its argument, which is another
+# program to the compiler than the one the compile cache holds.
+
 def bloom_device_words(reader, device=None):
-    """The SST's bloom bit array as a padded device uint32 vector, cached
-    on the reader for its lifetime (blooms are ~1.25 bytes/key — tiny
-    next to the staged key columns). Returns (words_dev, m_bits, k), or
+    """The SST's bloom bit array as a padded device uint32 vector with
+    its size and probe count as device scalars (uint32 m_bits, int32 k:
+    the probe program's avals), kept on the reader until it closes
+    (blooms are ~1.25 bytes/key — tiny next to the staged key columns).
     None when the filter is too large for the u32 probe arithmetic."""
-    cached = getattr(reader, "_bloom_dev", None)
+    cached = reader._bloom_dev
     if cached is not None:
         return cached
     bloom = reader.bloom
     if bloom.m_bits >= BLOOM_PROBE_MAX_BITS or bloom.m_bits == 0:
         return None
     words = np.frombuffer(bloom.bits.tobytes(), dtype="<u4")
-    n_pad = bucket_size(len(words))
-    padded = np.zeros(n_pad, dtype=np.uint32)
+    padded = np.zeros(bucket_size(len(words)), dtype=np.uint32)
     padded[: len(words)] = words
-    dev = (jax.device_put(padded, device) if device is not None
-           else jnp.asarray(padded))
-    reader._bloom_dev = (dev, int(bloom.m_bits), int(bloom.k))
+    reader._bloom_dev = (
+        jax.device_put(padded, device),
+        *jax.device_put((np.uint32(bloom.m_bits), np.int32(bloom.k))))
     return reader._bloom_dev
+
+
+@functools.cache
+def _no_model_operands():
+    """The locate program's model operands where no model serves (the
+    exact full seek ignores them): zeros of the model's shapes, put on
+    the device once for the life of the process."""
+    z = np.zeros(LINDEX_SEGMENTS + 1, dtype=np.uint32)
+    return jax.device_put(
+        (z, z, z.astype(np.int32), np.int32(0), np.int32(0)))
+
+
+def locate_device_operands(reader):
+    """What the locate program needs of one SST besides its staged
+    columns: (n, model) on the device, kept on the reader until it
+    closes. n is the int32 entry count; model is the validated learned
+    index (a_hi, a_lo, anchor_pos, p, max_err: storage/learned_index.
+    model_operands) or None where the file carries none that serves."""
+    cached = reader._locate_dev
+    if cached is not None:
+        return cached
+    from yugabyte_tpu.storage import learned_index
+    n = int(reader.props.n_entries)
+    model = learned_index.model_operands(reader.props.lindex, n)
+    if model is not None:
+        a_hi, a_lo, anchor_pos, p, max_err = model
+        model = (a_hi, a_lo, anchor_pos, np.int32(p), np.int32(max_err))
+    reader._locate_dev = jax.device_put((np.int32(n), model))
+    return reader._locate_dev
 
 
 def hash_batch(qwords: np.ndarray, dkls: np.ndarray
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Device FNV over the doc-key prefix of each padded query."""
+    """Device FNV over the doc-key prefix of each padded query (uint32
+    [B, w] words, int32 [B] doc-key lengths, as numpy: the program's own
+    argument path uploads them)."""
     from yugabyte_tpu.ops.run_merge import quantize_width
     from yugabyte_tpu.utils.latency import sub_span
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     # the batch is packed at a quantize_width point already; re-routing
     # the static through the quantizer keeps the lattice explicit
     with sub_span("device_enqueue"):
-        h1, h2 = _fnv64_fused(jnp.asarray(qwords),
-                              jnp.asarray(dkls, dtype=np.int32),
+        h1, h2 = _fnv64_fused(qwords, dkls,
                               w=quantize_width(int(qwords.shape[1])))
     record_kernel_dispatch("kernel_point_hash", int(qwords.shape[0]),
                            int(qwords.shape[0]))
@@ -446,46 +504,33 @@ def probe_bloom(reader, h1, h2, device=None) -> Optional[np.ndarray]:
         bd = bloom_device_words(reader, device)
         if bd is None:
             return None
-        words, m_bits, k = bd
-        ok = _bloom_probe_fused(h1, h2, words, jnp.uint32(m_bits),
-                                jnp.int32(k))
+        ok = _bloom_probe_fused(h1, h2, *bd)
     with sub_span(SUB_DEVICE_WAIT):
         return np.asarray(ok)
 
 
 def locate_batch(staged: StagedCols, qwords: np.ndarray,
-                 qlens: np.ndarray, read_ht_value: int,
-                 model_ops=None):
+                 qlens: np.ndarray, rhi: np.uint32, rlo: np.uint32,
+                 n_dev, model_dev=None):
     """Run the locate+gather kernel over one staged SST.
 
-    model_ops: (a_hi u32 [S+1], a_lo u32 [S+1], anchor_pos i32 [S+1],
-    p int, max_err int) from storage/learned_index.model_operands, or
-    None for the exact full binary seek. Returns numpy
-    (idx, hit, ht_hi, ht_lo, wid, miss).
+    qwords uint32 [B, staged.w] / qlens int32 [B] / rhi, rlo (the read
+    time's halves, numpy uint32 scalars): the chunk's operands, as
+    numpy. n_dev, model_dev: the file's own, resident
+    (locate_device_operands); model_dev None runs the exact full binary
+    seek. Returns numpy (idx, hit, ht_hi, ht_lo, wid, miss).
     """
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.utils.latency import SUB_DEVICE_WAIT, sub_span
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
     b = int(qwords.shape[0])
-    use_model = model_ops is not None
-    if use_model:
-        a_hi, a_lo, anchor_pos, p, max_err = model_ops
-    else:
-        a_hi = np.zeros(LINDEX_SEGMENTS + 1, dtype=np.uint32)
-        a_lo = np.zeros(LINDEX_SEGMENTS + 1, dtype=np.uint32)
-        anchor_pos = np.zeros(LINDEX_SEGMENTS + 1, dtype=np.int32)
-        p = 0
-        max_err = 0
+    use_model = model_dev is not None
     device_faults.maybe_fault("dispatch")
     with sub_span("device_enqueue"):
         out = _locate_gather_fused(
-            staged.cols_dev, jnp.int32(staged.n), jnp.asarray(qwords),
-            jnp.asarray(qlens, dtype=np.int32),
-            jnp.uint32(read_ht_value >> 32),
-            jnp.uint32(read_ht_value & 0xFFFFFFFF),
-            jnp.asarray(a_hi), jnp.asarray(a_lo), jnp.asarray(anchor_pos),
-            jnp.int32(p), jnp.int32(max_err), w=staged.w,
-            use_model=use_model)
+            staged.cols_dev, n_dev, qwords, qlens, rhi, rlo,
+            *(model_dev if use_model else _no_model_operands()),
+            w=staged.w, use_model=use_model)
     device_faults.maybe_fault("result")
     with sub_span(SUB_DEVICE_WAIT):
         idx, hit, ht_hi, ht_lo, wid, miss = (np.asarray(x) for x in out)
@@ -575,5 +620,7 @@ def point_read_snapshot() -> dict:
         "learned_index_hits": m["learned_hits"].value(),
         "learned_index_fallbacks": m["learned_fallbacks"].value(),
         "device_fallbacks": m["device_fallbacks"].value(),
+        "value_fetch_rows": m["value_fetch_rows"].value(),
+        "value_fetch_blocks": m["value_fetch_blocks"].value(),
         "learned_index_max_error": m["max_error"].value(),
     }

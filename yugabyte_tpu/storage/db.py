@@ -859,21 +859,29 @@ class DB:
 
     def _multi_get_device_batches(self, keys, read_ht, mems, staged_by,
                                   results, doc_key_lens, cur):
+        """Resolve `keys` chunk by chunk into `results` (which arrives
+        holding None for every key). On the host nothing here runs once
+        per key or once per operand that can run once per chunk, once
+        per block or once per file: one query pack, numpy operands, the
+        files' own operands resident, array compares, values by block."""
         import numpy as np
         from yugabyte_tpu.ops import point_read
+        from yugabyte_tpu.ops.run_merge import quantize_width
         from yugabyte_tpu.ops.slabs import _doc_key_len
-        from yugabyte_tpu.storage import learned_index
         from yugabyte_tpu.utils.latency import sub_span
         metrics = point_read.point_read_metrics()
         mems = [m for m in mems if not m.empty]
         use_model = flags.get_flag("point_read_learned_index")
+        device = self._device_cache.device
+        rhi = np.uint32(read_ht.value >> 32)
+        rlo = np.uint32(read_ht.value & 0xFFFFFFFF)
+        file_widths = {st.w for _fid, _r, st in staged_by}
         # the device calls below (hash_batch, probe_bloom, locate_batch)
         # carry their own sub-stage spans: `device_enqueue` around each
         # dispatch, `device_wait` around each blocking download
         for start in range(0, len(keys), 1024):
             chunk = keys[start: start + 1024]
             b = len(chunk)
-            b_pad = point_read.batch_bucket(b)
             metrics["batches"].increment()
             metrics["keys"].increment(b)
             metrics["batch_rows"].increment(b)
@@ -884,111 +892,153 @@ class DB:
                     dkls = doc_key_lens[start: start + 1024]
                 else:
                     dkls = [_doc_key_len(k) for k in chunk]
-                max_dkl = max(dkls) if dkls else 1
-                from yugabyte_tpu.ops.run_merge import quantize_width
-                w_hash = quantize_width(max(1, -(-max_dkl // 4)))
-                hw, _hl = point_read.pack_query_batch(chunk, w_hash)
-                dk_pad = np.zeros(b_pad, dtype=np.int32)
+                w_hash = quantize_width(max(1, -(-max(dkls) // 4)))
+                # ONE pack, at the widest width in play: the hash's and
+                # a narrower file's operand are its first columns
+                widths = file_widths | {w_hash}
+                w_pack = max(widths)
+                qw, ql = point_read.pack_query_batch(chunk, w_pack)
+                qw_by_w = {w: (qw if w == w_pack
+                               else np.ascontiguousarray(qw[:, :w]))
+                           for w in widths}
+                dk_pad = np.zeros(len(ql), dtype=np.int32)
                 dk_pad[:b] = dkls
-            h1, h2 = point_read.hash_batch(hw, dk_pad)
-            packs = {}
+            h1, h2 = point_read.hash_batch(qw_by_w[w_hash], dk_pad)
             exact_fallback = set()
-            best = None  # (ht u64, wid, row, file-index, valid) arrays
+            best = None  # [ht u64, wid, row, file index, valid] arrays
             for fi, (fid, r, st) in enumerate(staged_by):
                 cur["n_pad"] = st.n_pad
-                maybe = point_read.probe_bloom(
-                    r, h1, h2, device=self._device_cache.device)
+                maybe = point_read.probe_bloom(r, h1, h2, device=device)
                 if maybe is not None and not maybe[:b].any():
                     metrics["bloom_skips"].increment()
                     continue
                 with sub_span("query_pack"):
-                    if st.w not in packs:
-                        packs[st.w] = point_read.pack_query_batch(chunk,
-                                                                  st.w)
-                    qw, ql = packs[st.w]
-                    model = (learned_index.model_operands(r.props.lindex,
-                                                          st.n)
-                             if use_model else None)
-                _idx, hit, hhi, hlo, wid, miss = point_read.locate_batch(
-                    st, qw, ql, read_ht.value, model)
+                    n_dev, model = point_read.locate_device_operands(r)
+                    if not use_model:
+                        model = None
+                # the chunk's own rows of the padded results: what follows
+                # costs by the keys asked, not by their bucket
+                idx, hit, hhi, hlo, wid, miss = (
+                    x[:b] for x in point_read.locate_batch(
+                        st, qw_by_w[st.w], ql, rhi, rlo, n_dev, model))
                 with sub_span("chunk_combine"):
                     if model is not None:
                         metrics["learned_hits"].increment()
-                        n_miss = int(miss[:b].sum())
-                        if n_miss:
-                            metrics["learned_fallbacks"].increment(n_miss)
-                            for i in np.nonzero(miss[:b])[0]:
-                                exact_fallback.add(int(i))
-                    ht = (hhi.astype(np.uint64) << np.uint64(32)) \
-                        | hlo.astype(np.uint64)
+                        missed = np.flatnonzero(miss)
+                        if len(missed):
+                            metrics["learned_fallbacks"].increment(
+                                len(missed))
+                            exact_fallback.update(missed.tolist())
+                    ht = (hhi.astype(np.uint64) << np.uint64(32)) | hlo
+                    row = idx.astype(np.int64)
                     if best is None:
-                        best = [np.zeros(b_pad, np.uint64),
-                                np.zeros(b_pad, np.uint32),
-                                np.zeros(b_pad, np.int64),
-                                np.zeros(b_pad, np.int64),
-                                np.zeros(b_pad, bool)]
+                        # what a column holds where `valid` is False is
+                        # never read
+                        best = [ht, wid, row, np.full(b, fi, np.int64), hit]
+                        continue
                     upd = hit & (~best[4] | (ht > best[0])
                                  | ((ht == best[0]) & (wid > best[1])))
                     best[0] = np.where(upd, ht, best[0])
                     best[1] = np.where(upd, wid, best[1])
-                    best[2] = np.where(upd, _idx.astype(np.int64), best[2])
+                    best[2] = np.where(upd, row, best[2])
                     best[3] = np.where(upd, fi, best[3])
                     best[4] = best[4] | hit
             self._combine_device_chunk(chunk, start, read_ht, mems,
                                        staged_by, best, exact_fallback,
-                                       results)
+                                       results, metrics)
 
     def _combine_device_chunk(self, chunk, start, read_ht, mems,
-                              staged_by, best, exact_fallback, results):
-        """Merge device SST winners with host memtable probes per key —
-        newest (ht, wid) wins, exactly get()'s compare. The winners'
-        values are fetched in one pass at the end (`value_fetch`)."""
+                              staged_by, best, exact_fallback, results,
+                              metrics):
+        """Merge device SST winners with host memtable probes — newest
+        (ht, wid) wins, a tie goes to the memtable: exactly get()'s
+        compare, as array compares. Python runs per key only for the
+        keys a memtable wins and the learned index's mispredictions;
+        the SST winners' values are fetched block by block at the end
+        (`value_fetch`)."""
+        import numpy as np
         from yugabyte_tpu.utils.latency import sub_span
-        fetch = []   # (result slot, staged entry, row, ht, write id)
+        b = len(chunk)
         with sub_span("chunk_combine"):
-            live_mems = [m for m in mems if not m.empty]
-            mem_hits = self._mem_probe_many(live_mems, chunk, read_ht)
-            for i, k in enumerate(chunk):
-                if i in exact_fallback:
-                    # learned-index misprediction beyond its bound: the
-                    # binary-search invariant caught it — resolve this key
-                    # exactly (correctness never rides the model)
-                    results[start + i] = self._get_inner(k, read_ht)
-                    continue
-                mem_best = mem_hits[i]
-                if best is not None and best[4][i]:
-                    ht_v = int(best[0][i])
-                    wid_v = int(best[1][i])
-                    if mem_best is None or (ht_v, wid_v) > mem_best[:2]:
-                        fetch.append((start + i,
-                                      staged_by[int(best[3][i])],
-                                      int(best[2][i]), ht_v, wid_v))
-                        continue
+            sst_wins = (best[4].copy() if best is not None
+                        else np.zeros(b, bool))
+            mem_hits = (self._mem_probe_many(mems, chunk, read_ht)
+                        if mems else [])
+            held = [i for i, h in enumerate(mem_hits) if h is not None]
+            mem_wins = np.zeros(b, bool)
+            if held:
+                mem_wins[held] = True
+                if best is not None:
+                    m_ht = np.zeros(b, np.uint64)
+                    m_wid = np.zeros(b, np.uint32)
+                    m_ht[held] = np.array([mem_hits[i][0] for i in held],
+                                          dtype=np.uint64)
+                    m_wid[held] = np.array([mem_hits[i][1] for i in held],
+                                           dtype=np.uint32)
+                    sst_wins &= ~mem_wins | (best[0] > m_ht) | (
+                        (best[0] == m_ht) & (best[1] > m_wid))
+                    mem_wins &= ~sst_wins
+            if exact_fallback:
+                # learned-index misprediction beyond its bound: the
+                # binary-search invariant caught it — resolve these keys
+                # exactly (correctness never rides the model)
+                missed = list(exact_fallback)
+                sst_wins[missed] = False
+                mem_wins[missed] = False
+                for i in missed:
+                    results[start + i] = self._get_inner(chunk[i], read_ht)
+            for i in np.flatnonzero(mem_wins).tolist():
+                ht_v, wid_v, value = mem_hits[i]
                 results[start + i] = (
-                    None if mem_best is None else
-                    (DocHybridTime(HybridTime(mem_best[0]), mem_best[1]),
-                     mem_best[2]))
+                    DocHybridTime(HybridTime(ht_v), wid_v), value)
         with sub_span("value_fetch"):
-            for slot, entry, row, ht_v, wid_v in fetch:
-                results[slot] = (DocHybridTime(HybridTime(ht_v), wid_v),
-                                 self._fetch_staged_value(entry, row))
+            won = np.flatnonzero(sst_wins)
+            if len(won):
+                self._fetch_staged_values(
+                    staged_by, best[3][won], best[2][won], best[0][won],
+                    best[1][won], won + start, results, metrics)
 
     @staticmethod
-    def _fetch_staged_value(entry, row: int) -> bytes:
-        """Value bytes of staged entry `row` (sorted order): decode only
-        the winner's block — the survivor-gather half of the batched
-        read (values never live in HBM; ops/slabs.py)."""
+    def _fetch_staged_values(staged_by, files, rows, hts, wids, slots,
+                             results, metrics) -> None:
+        """results[slot] = (doc hybrid time, value bytes) for each SST
+        winner, given as parallel arrays: staged file index, row (sorted
+        order), hybrid time, write id, result slot. The survivor-gather
+        half of the batched read (values never live in HBM;
+        ops/slabs.py), block by block: per file one searchsorted of its
+        rows against the blocks' first rows, per distinct block one
+        read_block and one index over its values."""
         import numpy as np
-        _fid, r, _st = entry
-        offs = getattr(r, "_row_offs_pr", None)
-        if offs is None:
-            offs = np.concatenate(
-                ([0], np.cumsum([h[2] for h in r.block_handles])))
-            r._row_offs_pr = offs
-        blk = int(np.searchsorted(offs, row, side="right") - 1)
-        slab = r.read_block(blk)
-        j = row - int(offs[blk])
-        return slab.values[int(slab.value_idx[j])]
+        from yugabyte_tpu.ops.slabs import ValueArray
+        n_blocks = 0
+        for fi in np.unique(files).tolist():
+            r = staged_by[fi][1]
+            offs = r.block_row_offsets()
+            of_file = np.flatnonzero(files == fi)
+            blks = np.searchsorted(offs, rows[of_file], side="right") - 1
+            order = np.argsort(blks, kind="stable")
+            of_file = of_file[order]
+            blks = blks[order]
+            in_blk = rows[of_file] - offs[blks]
+            slot_l = slots[of_file].tolist()
+            ht_l = hts[of_file].tolist()
+            wid_l = wids[of_file].tolist()
+            los = [0] + (np.flatnonzero(blks[1:] != blks[:-1]) + 1).tolist()
+            n_blocks += len(los)
+            for lo, hi, blk in zip(los, los[1:] + [len(blks)],
+                                   blks[los].tolist()):
+                slab = r.read_block(blk)
+                va = ValueArray.from_list(slab.values)
+                vi = slab.value_idx[in_blk[lo:hi]]
+                data = memoryview(va.data)
+                for slot, ht_v, wid_v, s, e in zip(
+                        slot_l[lo:hi], ht_l[lo:hi], wid_l[lo:hi],
+                        va.offsets[vi].tolist(),
+                        va.offsets[vi + 1].tolist()):
+                    results[slot] = (DocHybridTime(HybridTime(ht_v), wid_v),
+                                     data[s:e].tobytes())
+        metrics["value_fetch_rows"].increment(len(slots))
+        metrics["value_fetch_blocks"].increment(n_blocks)
 
     def iter_from(self, seek_internal_key: bytes = b"",
                   check_bloom_doc: Optional[bytes] = None

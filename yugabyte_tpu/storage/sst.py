@@ -375,8 +375,16 @@ class SSTReader:
         # Env random-access handle (position-less preads are safe under
         # concurrent readers; decrypts transparently at rest).
         self._data = get_env().open_random(self.data_path)
+        # what the batched point read keeps of this file, built on its
+        # first read and dropped with the reader: the bloom and the
+        # locate operands on the device (ops/point_read.py) and the
+        # first row of each block (block_row_offsets)
+        self._bloom_dev = None
+        self._locate_dev = None
+        self._row_offs_pr = None
 
     def close(self) -> None:
+        self._bloom_dev = self._locate_dev = self._row_offs_pr = None
         if self._data is not None:
             self._data.close()
             self._data = None
@@ -389,6 +397,15 @@ class SSTReader:
     @property
     def n_blocks(self) -> int:
         return len(self.block_handles)
+
+    def block_row_offsets(self) -> np.ndarray:
+        """int64 [n_blocks + 1]: the file-order row at which each block
+        starts, then the file's row count; built once."""
+        if self._row_offs_pr is None:
+            self._row_offs_pr = np.concatenate(
+                ([0], np.cumsum([h[2] for h in self.block_handles],
+                                dtype=np.int64)))
+        return self._row_offs_pr
 
     def read_block(self, block_idx: int) -> KVSlab:
         if self.block_cache is not None:
