@@ -15,13 +15,22 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
 
+import shutil
+
 import pytest
+
+from yugabyte_tpu.utils import native_build
 
 
 def pytest_configure(config):
-    """Arm the race sanitizer when the environment asks (`YBSAN=1
+    """Build the native libraries before xdist forks its workers (the
+    controller has no `workerinput`), so six workers never compile; and
+    arm the race sanitizer when the environment asks (`YBSAN=1
     pytest ...`): the vector-clock detector patches the sync vocabulary
     and every guarded-by / @ybsan.shadow class before any test runs."""
+    if not hasattr(config, "workerinput"):
+        for stem in native_build.LIBS:
+            native_build.available(stem)  # a failure is the marked tests' to report
     from yugabyte_tpu.utils import ybsan as _shim
     if _shim.enabled():
         import tools.sanitizer
@@ -42,6 +51,21 @@ def pytest_sessionfinish(session, exitstatus):
         for f in failures:
             print(f, file=sys.stderr)
         session.exitstatus = 1
+
+
+def pytest_runtest_setup(item):
+    """`requires_native`: never a silent skip. A library that is absent
+    beside a working compiler is a fault of the build, and the test says
+    so with the reason native_build kept."""
+    for mark in item.iter_markers("requires_native"):
+        for stem in mark.args:
+            if native_build.available(stem):
+                continue
+            if shutil.which("g++") is None:
+                pytest.skip(f"no g++ on this machine: {stem} cannot be built")
+            pytest.fail(f"native library unavailable with g++ present — "
+                        f"{stem}: {native_build.unavailable()[stem]}",
+                        pytrace=False)
 
 
 @pytest.fixture(autouse=True)

@@ -115,8 +115,15 @@ def test_rate_limiter_paces_bytes():
 
 
 def test_compaction_rate_limit_flag(tmp_path):
+    """The job's output passes through the flag's token bucket and takes
+    the time the bucket's law says. The bucket starts full (half a second
+    of the rate), so the rate is set low enough that the four output files
+    (~75 KB) overflow it: at 200 KB/s they never did, and the old
+    `dt >= 0.1` passed only where the job paid a kernel compile (it failed
+    in any worker whose earlier tests had compiled that bucket)."""
+    from yugabyte_tpu.storage import compaction as C
     old = flags.get_flag("compaction_rate_bytes_per_sec")
-    flags.set_flag("compaction_rate_bytes_per_sec", 200_000)
+    flags.set_flag("compaction_rate_bytes_per_sec", 50_000)
     try:
         db = DB(str(tmp_path / "db"), DBOptions(auto_compact=False))
         ht = 1
@@ -132,10 +139,17 @@ def test_compaction_rate_limit_flag(tmp_path):
         old_split = flags.get_flag("compaction_max_output_entries_per_sst")
         flags.set_flag("compaction_max_output_entries_per_sst", 300)
         try:
+            limiter = C.compaction_rate_limiter()
+            through0 = limiter.total_through
             t0 = time.monotonic()
             db.compact_all()
             dt = time.monotonic() - t0
-            assert dt >= 0.1, f"compaction unthrottled: {dt}"
+            through = limiter.total_through - through0
+            assert through > limiter.capacity, (
+                f"only {through} B went through a {limiter.capacity} B "
+                f"bucket: nothing was paced")
+            owed = (through - limiter.capacity) / limiter.rate
+            assert dt >= owed, f"compaction unthrottled: {dt} < {owed}"
         finally:
             flags.set_flag("compaction_max_output_entries_per_sst",
                            old_split)

@@ -32,7 +32,6 @@ from yugabyte_tpu.ops.slabs import ValueArray, gather_metrics  # noqa: E402
 from yugabyte_tpu.storage import block_format  # noqa: E402
 from yugabyte_tpu.storage import compaction as compaction_mod  # noqa: E402
 from yugabyte_tpu.storage import integrity  # noqa: E402,F401 (flag defs)
-from yugabyte_tpu.storage import native_engine  # noqa: E402
 from yugabyte_tpu.storage import offload_policy  # noqa: E402
 from yugabyte_tpu.storage.device_cache import (DeviceSlabCache,  # noqa: E402
                                                host_staging_pool)
@@ -207,8 +206,7 @@ def test_corrupt_crc_raises_typed_corruption(tmp_path):
     r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_corrupt_input_fails_job_without_fallback(tmp_path):
     """Corruption is NOT a device fault: the codec job surfaces it typed
     instead of silently completing via the native merge."""
@@ -231,8 +229,7 @@ def test_corrupt_input_fails_job_without_fallback(tmp_path):
 # ---------------------------------------------------------------- encode
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 @pytest.mark.parametrize("compress", [False, True])
 def test_codec_job_byte_identical_to_shell(tmp_path, compress):
     """The codec-driven compaction == the shell-driven device-native job
@@ -266,8 +263,7 @@ def test_codec_job_byte_identical_to_shell(tmp_path, compress):
         r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_codec_counters_and_flat_host_decode(tmp_path):
     """A codec job moves ONLY the device codec counters: host block
     decode and shell ingest stay flat; device decode/encode counters
@@ -307,8 +303,7 @@ def test_codec_counters_and_flat_host_decode(tmp_path):
 # ------------------------------------------------- device-fault containment
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 @pytest.mark.parametrize("site", ["dispatch", "result"])
 def test_persistent_fault_falls_back_byte_identical(tmp_path, site):
     """A persistent device fault in the codec path quarantines the shape
@@ -359,12 +354,11 @@ def test_persistent_fault_falls_back_byte_identical(tmp_path, site):
         r.close()
 
 
+@pytest.mark.requires_native("compaction_engine")
 def test_transient_decode_fault_retries_and_stays_on_device(tmp_path):
     """count=1 result fault fires at the decode download: the codec
     retries the launch once and the job completes WITHOUT the native
     fallback."""
-    if not native_engine.available():
-        pytest.skip("native engine unavailable")
     rng = np.random.default_rng(39)
     runs = [_mk_run(rng, 400, 200) for _ in range(2)]
     readers = _write_runs(str(tmp_path), runs)
@@ -383,8 +377,7 @@ def test_transient_decode_fault_retries_and_stays_on_device(tmp_path):
         r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_cancel_mid_codec_stage_c_sweeps_partials(tmp_path, monkeypatch):
     """Cancellation between codec span writes sweeps the already-written
     files and leaks nothing."""
@@ -417,8 +410,7 @@ def test_cancel_mid_codec_stage_c_sweeps_partials(tmp_path, monkeypatch):
         r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_codec_job_native_gather_matches_numpy_fallback(tmp_path,
                                                         monkeypatch):
     """The same job through _device_codec_body with the survivors' values

@@ -27,7 +27,7 @@ from test_device_fault_containment import (_mk_run, _native_reference,  # noqa: 
                                            _write_runs)
 
 from yugabyte_tpu.ops import device_faults, run_merge  # noqa: E402
-from yugabyte_tpu.storage import native_engine, offload_policy  # noqa: E402
+from yugabyte_tpu.storage import offload_policy  # noqa: E402
 from yugabyte_tpu.storage.bucket_health import (BucketHealthBoard,  # noqa: E402
                                                 health_board)
 from yugabyte_tpu.storage.device_cache import host_staging_pool  # noqa: E402
@@ -393,8 +393,7 @@ def test_slow_stacks_with_loud_fault():
 # -- the self-healing cycle, end to end -------------------------------
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_slow_bucket_demotes_completes_native_and_repromotes(tmp_path):
     """The nemesis proof: throttle ONE shape bucket's device dispatch
     (no exception — just latency), watch the board demote it on the

@@ -46,7 +46,7 @@ from yugabyte_tpu.integration.chaos import NemesisController
 from yugabyte_tpu.integration.mini_cluster import (MiniCluster,
                                                    MiniClusterOptions)
 from yugabyte_tpu.ops import device_faults
-from yugabyte_tpu.storage import native_engine, offload_policy
+from yugabyte_tpu.storage import offload_policy
 from yugabyte_tpu.storage.bucket_health import health_board
 from yugabyte_tpu.storage.device_cache import host_staging_pool
 from yugabyte_tpu.utils import env as env_mod
@@ -104,8 +104,7 @@ class _Workload:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_chaos_soak_three_nemesis_cycles(tmp_path):
     hold = float(os.environ.get("YBTPU_SOAK_SECONDS", 3))
     old_flags = {f: flags.get_flag(f) for f in

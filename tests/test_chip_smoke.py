@@ -16,12 +16,11 @@ import jax
 import pytest
 
 import chip_smoke
-from yugabyte_tpu.storage import bucket_health, native_engine
+from yugabyte_tpu.storage import bucket_health
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 @pytest.fixture(scope="module")

@@ -83,8 +83,7 @@ def _run_device_native(readers, out_dir, first_id=100, is_major=True):
 # ---------------------------------------------------------------- pipeline
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_pipeline_vs_sequential_vs_cpu_byte_identical(tmp_path, monkeypatch):
     """The headline equivalence: chunked pipelined device job ==
     unpipelined device job == native CPU fallback, byte for byte,
@@ -119,8 +118,7 @@ def test_pipeline_vs_sequential_vs_cpu_byte_identical(tmp_path, monkeypatch):
         r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_streaming_writer_overlaps_chunks(tmp_path, monkeypatch):
     """With chunking + a small file split, the streaming writer must
     emit at least one complete file BEFORE the last chunk's decisions
@@ -168,8 +166,7 @@ def test_streaming_writer_overlaps_chunks(tmp_path, monkeypatch):
         r.close()
 
 
-@pytest.mark.skipif(not native_engine.available(),
-                    reason="native engine unavailable")
+@pytest.mark.requires_native("compaction_engine")
 def test_append_survivors_equals_set_survivors(tmp_path):
     """The C++ streaming injection: appending chunk survivor spans must
     leave the job in exactly the state one set_survivors produces."""

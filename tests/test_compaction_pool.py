@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 import jax
 
-from bench import synth_ycsb_runs, _attach_values, _split_runs
+from yugabyte_tpu.integration.synth import (attach_values, split_runs,
+                                            synth_ycsb_runs)
 from yugabyte_tpu.ops import device_faults
 from yugabyte_tpu.ops.merge_gc import GCParams
 from yugabyte_tpu.parallel.mesh import make_mesh
@@ -45,8 +46,8 @@ def pool():
 
 def _write_tablet_inputs(tmp_path, tag, n=12000, k=4, seed=0):
     slab, offsets = synth_ycsb_runs(n, k, n // 2, seed=seed)
-    _attach_values(slab, 16)
-    runs = _split_runs(slab, offsets)
+    attach_values(slab, 16)
+    runs = split_runs(slab, offsets)
     d = tmp_path / tag
     d.mkdir()
     paths = []
@@ -71,7 +72,7 @@ def _merge_jobs(n_jobs, n=16000, seed0=0):
     jobs = []
     for j in range(n_jobs):
         slab, offsets = synth_ycsb_runs(n, 4, n // 2, seed=seed0 + j)
-        jobs.append(_split_runs(slab, offsets))
+        jobs.append(split_runs(slab, offsets))
     return jobs
 
 

@@ -17,7 +17,7 @@ from yugabyte_tpu.docdb.doc_key import DocKey
 from yugabyte_tpu.docdb.doc_operations import QLWriteOp, WriteOpKind
 from yugabyte_tpu.docdb.value import Value
 from yugabyte_tpu.integration.external_mini_cluster import (
-    ExternalMiniCluster)
+    ExternalMiniCluster, _free_port, _Node)
 from yugabyte_tpu.utils.status import StatusError
 
 
@@ -39,6 +39,19 @@ def cluster(tmp_path_factory):
         rf=3).start()
     yield c
     c.shutdown()
+
+
+def test_node_that_dies_before_ready_says_why(tmp_path):
+    """A failed start carries the end of the child's stderr (it used to
+    go to DEVNULL: "master m0 failed to start: ''")."""
+    node = _Node("tserver", "ets9", str(tmp_path / "ts9"), _free_port(),
+                 "127.0.0.1:1", 3)
+    with pytest.raises(RuntimeError) as e:
+        node.start(extra_flags={"no_such_flag_anywhere": 1})
+    assert "tserver ets9 failed to start: ''" in str(e.value)
+    assert "no_such_flag_anywhere" in str(e.value)
+    assert "no_such_flag_anywhere" in open(node.stderr_path).read()
+    assert not node.alive()
 
 
 def _wait_writes_ok(client, table, deadline_s=60.0):

@@ -42,7 +42,7 @@ from yugabyte_tpu.docdb.doc_key import DocKey, SubDocKey  # noqa: E402
 from yugabyte_tpu.docdb.value import Value  # noqa: E402
 from yugabyte_tpu.ops import device_faults, run_merge  # noqa: E402
 from yugabyte_tpu.storage import compaction as compaction_mod  # noqa: E402
-from yugabyte_tpu.storage import integrity, native_engine, offload_policy  # noqa: E402
+from yugabyte_tpu.storage import integrity, offload_policy  # noqa: E402
 from yugabyte_tpu.storage.db import DB, DBOptions  # noqa: E402
 from yugabyte_tpu.tserver.maintenance_manager import (  # noqa: E402
     MaintenanceOpStats, ScrubTabletsOp)
@@ -51,8 +51,7 @@ from yugabyte_tpu.utils import flags  # noqa: E402
 from yugabyte_tpu.utils.env import corrupt_file_range  # noqa: E402
 from yugabyte_tpu.utils.status import Code, StatusError  # noqa: E402
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 @pytest.fixture(autouse=True)

@@ -27,7 +27,6 @@ from test_run_merge import _make_run  # noqa: E402
 from yugabyte_tpu.ops import device_faults, run_merge  # noqa: E402
 from yugabyte_tpu.ops.slabs import ValueArray  # noqa: E402
 from yugabyte_tpu.storage import compaction as compaction_mod  # noqa: E402
-from yugabyte_tpu.storage import native_engine  # noqa: E402
 from yugabyte_tpu.storage import offload_policy  # noqa: E402
 from yugabyte_tpu.storage.device_cache import (DeviceSlabCache,  # noqa: E402
                                                host_staging_pool)
@@ -38,8 +37,7 @@ from yugabyte_tpu.utils.cancellation import (CancellationToken,  # noqa: E402
 
 CUTOFF = (10_000_000 << 12)
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 @pytest.fixture(autouse=True)

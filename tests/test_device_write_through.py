@@ -16,13 +16,11 @@ import pytest
 from yugabyte_tpu.ops.merge_gc import _ROW_WORDS, stage_slab
 from yugabyte_tpu.ops.slabs import ValueArray
 from yugabyte_tpu.storage import compaction as compaction_mod
-from yugabyte_tpu.storage import native_engine
 from yugabyte_tpu.storage.device_cache import DeviceSlabCache
 from yugabyte_tpu.storage.sst import Frontier, SSTReader, SSTWriter
 from yugabyte_tpu.utils import flags
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 def _mk_run(rng, n, key_space, value_bytes=16, ttl_frac=0.0):

@@ -14,12 +14,10 @@ import pytest
 from yugabyte_tpu.docdb.value import Value
 from yugabyte_tpu.ops.slabs import FLAG_HAS_TTL, KVSlab, ValueArray
 from yugabyte_tpu.storage import compaction as compaction_mod
-from yugabyte_tpu.storage import native_engine
 from yugabyte_tpu.storage.sst import Frontier, SSTReader, SSTWriter
 from yugabyte_tpu.utils import flags
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 def _write_runs(workdir, runs):
@@ -146,23 +144,3 @@ def test_outputs_reopen_and_read(tmp_path):
     assert total == rn.rows_out
     for r in readers:
         r.close()
-
-
-def test_foreign_host_stamp_forces_rebuild(tmp_path, monkeypatch):
-    """A binary built with -march=native for another CPU must not run
-    here (SIGILL): the `<lib>.host` stamp decides, not mtimes."""
-    import os
-    from yugabyte_tpu.utils import native_build as nb
-    monkeypatch.setattr(nb, "BUILD_DIR", str(tmp_path))
-
-    def build():
-        lib = nb.build_native_lib("compaction_baseline.cc", "libx.so")
-        return lib, os.stat(lib).st_mtime_ns
-
-    lib, m0 = build()
-    assert nb._built_for(lib) == nb._host_tag()
-    assert build()[1] == m0, "same host, fresh binary: no rebuild"
-    with open(lib + ".host", "w") as f:
-        f.write("some-other-cpu")
-    assert build()[1] != m0, "foreign stamp did not force a rebuild"
-    assert nb._built_for(lib) == nb._host_tag()

@@ -10,11 +10,9 @@ import pytest
 
 from yugabyte_tpu.common.hybrid_time import DocHybridTime, HybridTime
 from yugabyte_tpu.storage.memtable import (MemTable, NativeMemTable,
-                                           make_internal_key,
-                                           native_memtable_available)
+                                           make_internal_key)
 
-pytestmark = pytest.mark.skipif(not native_memtable_available(),
-                                reason="no native toolchain")
+pytestmark = pytest.mark.requires_native("memtable_arena")
 
 
 def _dht(us, w=0):

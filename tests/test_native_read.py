@@ -21,8 +21,7 @@ from yugabyte_tpu.storage.db import DB, DBOptions
 from yugabyte_tpu.utils import flags
 
 
-pytestmark = pytest.mark.skipif(not native_read.available(),
-                                reason="native read engine unavailable")
+pytestmark = pytest.mark.requires_native("read_engine")
 
 
 def _rand_value(rng) -> Value:
@@ -222,12 +221,9 @@ class TestNativeFlushEquivalence:
                 # device cache sentinel? simpler: call the python writer
                 # via the public knob — temporarily mark engine unavailable
                 from yugabyte_tpu.storage import native_engine
-                saved = native_engine._available
-                native_engine._available = False
-                try:
+                with pytest.MonkeyPatch.context() as mp:
+                    mp.setattr(native_engine, "available", lambda: False)
                     db.flush()
-                finally:
-                    native_engine._available = saved
             else:
                 db.flush()
             dbs.append(db)

@@ -350,6 +350,7 @@ def test_endpoint_smoke_and_compactionz(tmp_path):
         # tserver /healthz: liveness status + the bucket-health board
         hz = json.loads(_get(addr, "/healthz"))
         assert hz["status"] == "ok"
+        assert hz["native_unavailable"] == {}, "a native library is absent"
         bh = hz["bucket_health"]
         assert set(bh["states"]) == {"cold", "warming", "healthy",
                                      "degraded", "quarantined",

@@ -96,16 +96,17 @@ def test_compaction_job_cold_routes_native(tmp_path, monkeypatch):
     touch the device kernel at all."""
     import jax
 
-    from bench import _attach_values, _split_runs, synth_ycsb_runs
+    from yugabyte_tpu.integration.synth import (attach_values, split_runs,
+                                                synth_ycsb_runs)
     from yugabyte_tpu.ops import run_merge
     from yugabyte_tpu.storage.compaction import run_compaction_job
     from yugabyte_tpu.storage.sst import Frontier, SSTReader, SSTWriter
 
     n = 4096
     slab, offsets = synth_ycsb_runs(n, 4, n // 2, seed=3)
-    _attach_values(slab, 16)
+    attach_values(slab, 16)
     paths = []
-    runs = _split_runs(slab, offsets)
+    runs = split_runs(slab, offsets)
     for i, sub in enumerate(runs):
         p = str(tmp_path / f"{i:06d}.sst")
         SSTWriter(p).write(sub, Frontier())
@@ -144,16 +145,17 @@ def test_compaction_job_measured_demotion_routes_native(
     pre-dispatch — no kernel launch, no staging."""
     import jax
 
-    from bench import _attach_values, _split_runs, synth_ycsb_runs
+    from yugabyte_tpu.integration.synth import (attach_values, split_runs,
+                                                synth_ycsb_runs)
     from yugabyte_tpu.ops import run_merge
     from yugabyte_tpu.storage.compaction import run_compaction_job
     from yugabyte_tpu.storage.sst import Frontier, SSTReader, SSTWriter
 
     n = 4096
     slab, offsets = synth_ycsb_runs(n, 4, n // 2, seed=5)
-    _attach_values(slab, 16)
+    attach_values(slab, 16)
     paths = []
-    runs = _split_runs(slab, offsets)
+    runs = split_runs(slab, offsets)
     for i, sub in enumerate(runs):
         p = str(tmp_path / f"{i:06d}.sst")
         SSTWriter(p).write(sub, Frontier())

@@ -27,8 +27,7 @@ from benchmarks.drivers.pool import _Template
 from benchmarks.run import Context
 from yugabyte_tpu.common.hybrid_time import HybridTime
 from yugabyte_tpu.parallel.mesh import make_mesh
-from yugabyte_tpu.storage import (DB, DBOptions, SSTReader, bucket_health,
-                                  native_engine)
+from yugabyte_tpu.storage import DB, DBOptions, SSTReader, bucket_health
 from yugabyte_tpu.storage.sst import BlockCache
 from yugabyte_tpu.tserver.compaction_pool import CompactionPool
 from yugabyte_tpu.tserver.server_context import ServerExecutionContext
@@ -44,8 +43,7 @@ ROWS_PER_RUN = {"wave": 512, "mesh": 2048}
 POOL_STAGES = ("pool_stage", "pool_wave", "pool_finish", "pool_exclusive",
                "pool_native")
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 @pytest.fixture(scope="module")

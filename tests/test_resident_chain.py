@@ -28,7 +28,6 @@ from yugabyte_tpu.storage import compaction as compaction_mod  # noqa: E402
 from yugabyte_tpu.storage import integrity  # noqa: F401,E402 (registers
 #   shadow_verify_sample — without it the file only passes when another
 #   test module imported integrity first)
-from yugabyte_tpu.storage import native_engine  # noqa: E402
 from yugabyte_tpu.storage import offload_policy  # noqa: E402
 from yugabyte_tpu.storage.device_cache import DeviceSlabCache  # noqa: E402
 from yugabyte_tpu.storage.run_cache import (NamespacedRunCache,  # noqa: E402
@@ -37,8 +36,7 @@ from yugabyte_tpu.storage.sst import (Frontier, SSTReader,  # noqa: E402
                                       SSTWriter, _block_decode_counter)
 from yugabyte_tpu.utils import flags  # noqa: E402
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 CUTOFF = (10_000_000 << 12)
 

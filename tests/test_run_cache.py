@@ -26,8 +26,7 @@ from yugabyte_tpu.storage.run_cache import (NamespacedRunCache,
                                             NativeRunCache)
 from yugabyte_tpu.storage.sst import Frontier, SSTReader, SSTWriter
 
-pytestmark = pytest.mark.skipif(not native_engine.available(),
-                                reason="native engine unavailable")
+pytestmark = pytest.mark.requires_native("compaction_engine")
 
 
 def _mk_run(rng, n, key_space, value_bytes=16, ttl_frac=0.0):

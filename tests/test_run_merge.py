@@ -187,12 +187,12 @@ def test_write_id_tiebreak():
 def test_pallas_failure_degrades_to_network(monkeypatch):
     """A Mosaic lowering/runtime failure on the first real-TPU run must
     degrade to the jnp network, not kill the compaction/bench process."""
-    from bench import _split_runs, synth_ycsb_runs
+    from yugabyte_tpu.integration.synth import split_runs, synth_ycsb_runs
     from yugabyte_tpu.ops import pallas_merge, run_merge
     from yugabyte_tpu.ops.merge_gc import GCParams
 
     slab, offsets = synth_ycsb_runs(1 << 12, 4, 1 << 11, seed=3)
-    staged = run_merge.stage_runs_from_slabs(_split_runs(slab, offsets))
+    staged = run_merge.stage_runs_from_slabs(split_runs(slab, offsets))
     params = GCParams((10_000_000 << 12), True)
     expect = run_merge.launch_merge_gc(staged, params).result()
 

@@ -213,11 +213,9 @@ def _codec_job_deltas(tmp_path):
     return {k: after[k] - before[k] for k in after}
 
 
+@pytest.mark.requires_native("compaction_engine")
 def test_codec_job_is_the_sum_of_its_disjoint_stages(tmp_path, monkeypatch):
     from yugabyte_tpu.ops import block_codec
-    from yugabyte_tpu.storage import native_engine
-    if not native_engine.available():
-        pytest.skip("native engine unavailable")
     monkeypatch.setenv("YBTPU_DEVICE_CODEC", "1")
     f0 = block_codec.codec_metrics()["encode_fallbacks"].value()
     d = _codec_job_deltas(tmp_path)

@@ -75,15 +75,3 @@ def test_linked_list_under_churn(tmp_path):
         client.close()
     finally:
         c.shutdown()
-
-
-@pytest.mark.slow
-def test_ycsb_soak_stage_smoke(tmp_path, monkeypatch):
-    """BASELINE config 5 harness smoke: the bench's cluster-soak stage
-    produces a measured ops/s + p99 with churn underneath (short run)."""
-    monkeypatch.setenv("YBTPU_BENCH_SOAK_SECONDS", "12")
-    from bench import _cluster_soak_stage
-    out = _cluster_soak_stage()
-    assert out.get("cluster_ops_per_sec", 0) > 0
-    assert out.get("cluster_p99_ms", 0) > 0
-    assert out.get("cluster_soak_ops", 0) > 50

@@ -8,8 +8,7 @@ import pytest
 from yugabyte_tpu.ops.slabs import ValueArray, gather_metrics
 from yugabyte_tpu.storage import native_engine
 
-needs_native = pytest.mark.skipif(not native_engine.available(),
-                                  reason="native engine unavailable")
+needs_native = pytest.mark.requires_native("compaction_engine")
 
 TOMB = b"\x58\x01\x02"   # any multi-byte replacement
 

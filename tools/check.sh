@@ -55,7 +55,7 @@ echo "== no offload_calibration references (PR 16 deleted the file) =="
 # any source reference means a dispatch site regressed to the dead API
 if grep -rn --include='*.py' --include='*.sh' --include='*.md' \
         -l 'offload_calibration' \
-        yugabyte_tpu/ tools/ tests/ bench.py README.md 2>/dev/null \
+        yugabyte_tpu/ tools/ tests/ README.md 2>/dev/null \
         | grep -v '^tools/check.sh$'; then
     echo "check.sh: FAIL — offload_calibration is deleted; route through" \
          "the bucket-health board (storage/bucket_health.py)" >&2
@@ -64,24 +64,6 @@ fi
 
 echo "== kernel-manifest drift check (committed JSON) =="
 python -m tools.analysis.kernel_manifest --check
-
-echo "== bench regression gate (tools/bench_compare.py) =="
-# the comparator itself must work (a round against itself -> plain
-# diff exits 0)...
-python tools/bench_compare.py tools/bench_fixtures/base.json \
-    tools/bench_fixtures/base.json > /dev/null
-# ...and the gate must actually GATE: the committed synthetic-
-# regression fixture pair has to fail --check. If it passes, the
-# tolerance file or the direction inference silently broke.
-if python tools/bench_compare.py tools/bench_fixtures/base.json \
-        tools/bench_fixtures/regressed.json --check > /dev/null 2>&1; then
-    echo "check.sh: FAIL — bench_compare --check passed the synthetic" \
-         "regression fixture (the gate no longer gates)" >&2
-    exit 1
-fi
-# a round compared against itself must be clean
-python tools/bench_compare.py tools/bench_fixtures/base.json \
-    tools/bench_fixtures/base.json --check > /dev/null
 
 REGEN=0
 if [ "$RUN_FULL" = 1 ]; then

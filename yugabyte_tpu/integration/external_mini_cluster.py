@@ -47,6 +47,12 @@ class _Node:
     def address(self) -> str:
         return f"127.0.0.1:{self.port}"
 
+    @property
+    def stderr_path(self) -> str:
+        """Everything the node's processes wrote to stderr, restarts
+        appended."""
+        return os.path.join(self.fs_root, f"{self.server_id}.stderr")
+
     def start(self, crash_point: Optional[str] = None,
               wait_ready: bool = True,
               extra_flags: Optional[Dict[str, object]] = None) -> None:
@@ -65,14 +71,22 @@ class _Node:
             cmd += ["--flag", f"{k}={v}"]
         if self.master_addrs:
             cmd += ["--master-addrs", self.master_addrs]
-        self.proc = subprocess.Popen(
-            cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-            env=env, text=True)
+        os.makedirs(self.fs_root, exist_ok=True)
+        with open(self.stderr_path, "ab") as err:  # the child keeps its copy
+            self.proc = subprocess.Popen(
+                cmd, stdout=subprocess.PIPE, stderr=err, env=env, text=True)
         if wait_ready:
             line = self.proc.stdout.readline()
             if not line.startswith("READY"):
+                try:  # it is dying: let it finish saying why
+                    self.proc.wait(timeout=10)
+                except subprocess.TimeoutExpired:
+                    self.kill9()
+                with open(self.stderr_path, errors="replace") as f:
+                    said = "".join(f.readlines()[-20:])
                 raise RuntimeError(
-                    f"{self.role} {self.server_id} failed to start: {line!r}")
+                    f"{self.role} {self.server_id} failed to start: "
+                    f"{line!r}; its stderr ends:\n{said}")
 
     def kill9(self) -> None:
         """SIGKILL — no shutdown hooks, no flushes (the crash under test)."""

@@ -1,39 +1,22 @@
 """ctypes bridge to the native CPU compaction baseline.
 
-Builds native/compaction_baseline.cc on first use (g++ -O3). The baseline is
-the reference's architecture — heap merge + sequential filter — and serves
-as (a) the vs_baseline denominator in bench.py, (b) a third differential
-implementation in tests.
+native/compaction_baseline.cc is the reference's architecture — heap merge +
+sequential filter — and serves as a third differential implementation in
+tests.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
 
 from yugabyte_tpu.ops.slabs import KVSlab
+from yugabyte_tpu.utils import native_build
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "compaction_baseline.cc")
-_BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_BUILD_DIR, "libcompaction_baseline.so")
-
-_lib = None
-
-
-def _load():
-    global _lib
-    if _lib is not None:
-        return _lib
-    from yugabyte_tpu.utils.native_build import build_native_lib
-    _lib = ctypes.CDLL(build_native_lib("compaction_baseline.cc",
-                                        "libcompaction_baseline.so"))
-    _lib.compact_baseline.restype = ctypes.c_int64
-    return _lib
+def _bind(lib) -> None:
+    lib.compact_baseline.restype = ctypes.c_int64
 
 
 def compact_cpu_baseline(slab: KVSlab, run_offsets: Sequence[int],
@@ -45,7 +28,7 @@ def compact_cpu_baseline(slab: KVSlab, run_offsets: Sequence[int],
 
     Returns (order, keep, make_tombstone) like merge_and_gc_device (without
     padding)."""
-    lib = _load()
+    lib = native_build.load("compaction_baseline")
     n = slab.n
     stride = slab.width_words * 4
     keys = np.ascontiguousarray(slab.key_words).astype(">u4").tobytes()

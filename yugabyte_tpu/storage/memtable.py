@@ -202,55 +202,44 @@ import ctypes as _ct
 
 import numpy as _np
 
+from yugabyte_tpu.utils import native_build as _native_build
+
 _U64 = 0xFFFFFFFFFFFFFFFF
 _U32 = 0xFFFFFFFF
-_mt_lib = None
-_mt_lib_lock = threading.Lock()
 _i64p = _ct.POINTER(_ct.c_int64)
 _u64p = _ct.POINTER(_ct.c_uint64)
 _u32p = _ct.POINTER(_ct.c_uint32)
 _u8p = _ct.POINTER(_ct.c_uint8)
 
 
-def _load_mt_lib():
-    global _mt_lib
-    with _mt_lib_lock:
-        if _mt_lib is not None:
-            return _mt_lib
-        from yugabyte_tpu.utils.native_build import build_native_lib
-        path = build_native_lib("memtable_arena.cc", "libmemtable_arena.so",
-                                deps=())
-        lib = _ct.CDLL(path)
-        lib.mt_new.restype = _ct.c_void_p
-        lib.mt_free.argtypes = [_ct.c_void_p]
-        lib.mt_add_batch.argtypes = [_ct.c_void_p, _ct.c_char_p, _i64p,
-                                     _ct.c_char_p, _ct.c_char_p, _i64p,
-                                     _ct.c_int64]
-        lib.mt_n.restype = _ct.c_int64
-        lib.mt_n.argtypes = [_ct.c_void_p]
-        lib.mt_bytes.restype = _ct.c_int64
-        lib.mt_bytes.argtypes = [_ct.c_void_p]
-        lib.mt_raw_n.restype = _ct.c_int64
-        lib.mt_raw_n.argtypes = [_ct.c_void_p]
-        lib.mt_lower_bound.restype = _ct.c_int64
-        lib.mt_lower_bound.argtypes = [_ct.c_void_p, _ct.c_char_p,
-                                       _ct.c_int32]
-        lib.mt_range_sizes.argtypes = [_ct.c_void_p, _ct.c_int64,
-                                       _ct.c_int64, _ct.c_int32, _i64p,
-                                       _i64p]
-        lib.mt_export_range.argtypes = [_ct.c_void_p, _ct.c_int64,
-                                        _ct.c_int64, _ct.c_int32, _u8p,
-                                        _i64p, _u64p, _u32p, _u8p, _i64p]
-        _mt_lib = lib
-        return lib
+def _bind(lib) -> None:
+    """The functions' types; native_build.load calls it once per process."""
+    lib.mt_new.restype = _ct.c_void_p
+    lib.mt_free.argtypes = [_ct.c_void_p]
+    lib.mt_add_batch.argtypes = [_ct.c_void_p, _ct.c_char_p, _i64p,
+                                 _ct.c_char_p, _ct.c_char_p, _i64p,
+                                 _ct.c_int64]
+    lib.mt_n.restype = _ct.c_int64
+    lib.mt_n.argtypes = [_ct.c_void_p]
+    lib.mt_bytes.restype = _ct.c_int64
+    lib.mt_bytes.argtypes = [_ct.c_void_p]
+    lib.mt_raw_n.restype = _ct.c_int64
+    lib.mt_raw_n.argtypes = [_ct.c_void_p]
+    lib.mt_lower_bound.restype = _ct.c_int64
+    lib.mt_lower_bound.argtypes = [_ct.c_void_p, _ct.c_char_p,
+                                   _ct.c_int32]
+    lib.mt_range_sizes.argtypes = [_ct.c_void_p, _ct.c_int64,
+                                   _ct.c_int64, _ct.c_int32, _i64p,
+                                   _i64p]
+    lib.mt_export_range.argtypes = [_ct.c_void_p, _ct.c_int64,
+                                    _ct.c_int64, _ct.c_int32, _u8p,
+                                    _i64p, _u64p, _u32p, _u8p, _i64p]
 
 
 def native_memtable_available() -> bool:
-    try:
-        _load_mt_lib()
-        return True
-    except Exception:  # noqa: BLE001  # yblint: contained(feature probe — no toolchain means the Python memtable)
-        return False
+    """False (the reason in native_build.unavailable()) means the Python
+    memtable."""
+    return _native_build.available("memtable_arena")
 
 
 def _encode_suffixes(ht_vals: _np.ndarray, wids: _np.ndarray) -> bytes:
@@ -271,7 +260,7 @@ class NativeMemTable:
     """Drop-in MemTable twin backed by the C++ arena."""
 
     def __init__(self):
-        self._lib = _load_mt_lib()
+        self._lib = _native_build.load("memtable_arena")
         self._h = self._lib.mt_new()
         self._lock = threading.Lock()
         self.version = 0

@@ -16,13 +16,11 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
-import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from yugabyte_tpu.utils import flags
+from yugabyte_tpu.utils import flags, native_build
 
 flags.define_flag("compaction_native_threads",
                   min(4, os.cpu_count() or 1),
@@ -32,104 +30,85 @@ flags.define_flag("compaction_native_threads",
                   "oversubscribing memory-bound encode threads on a "
                   "1-core box only adds contention")
 
-_lib = None
-_lib_lock = threading.Lock()
-
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 _u64p = ctypes.POINTER(ctypes.c_uint64)
 
 
+def _bind(lib) -> None:
+    """The functions' types; native_build.load calls it once per process."""
+    lib.ce_job_new.restype = ctypes.c_void_p
+    lib.ce_job_new.argtypes = [ctypes.c_int32]
+    lib.ce_job_free.argtypes = [ctypes.c_void_p]
+    lib.ce_job_error.restype = ctypes.c_char_p
+    lib.ce_job_error.argtypes = [ctypes.c_void_p]
+    lib.ce_job_add_input.argtypes = [
+        ctypes.c_void_p, _u8p, ctypes.c_int64, _i64p, _i32p, _i32p,
+        ctypes.c_int32]
+    lib.ce_job_prepare.restype = ctypes.c_int64
+    lib.ce_job_prepare.argtypes = [ctypes.c_void_p]
+    lib.ce_job_add_raw.argtypes = [
+        ctypes.c_void_p, _u8p, _i64p, ctypes.c_int64, _u64p,
+        ctypes.POINTER(ctypes.c_uint32), _u8p, _i64p]
+    lib.ce_job_sort_all.restype = ctypes.c_int64
+    lib.ce_job_sort_all.argtypes = [ctypes.c_void_p]
+    lib.ce_job_props.argtypes = [ctypes.c_void_p, _u64p,
+                                 _i32p]
+    lib.ce_job_merge.restype = ctypes.c_int64
+    lib.ce_job_merge.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int32, ctypes.c_int32]
+    lib.ce_job_set_survivors.argtypes = [
+        ctypes.c_void_p, _i64p, _u8p, ctypes.c_int64]
+    lib.ce_job_append_survivors.argtypes = [
+        ctypes.c_void_p, _i64p, _u8p, ctypes.c_int64]
+    lib.ce_job_rows.restype = ctypes.c_int64
+    lib.ce_job_rows.argtypes = [ctypes.c_void_p]
+    lib.ce_job_n_survivors.restype = ctypes.c_int64
+    lib.ce_job_n_survivors.argtypes = [ctypes.c_void_p]
+    lib.ce_job_write_output.restype = ctypes.c_int64
+    lib.ce_job_write_output.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p,
+        ctypes.c_int32, ctypes.c_int32, _u8p, ctypes.c_int32]
+    lib.ce_out_n_blocks.restype = ctypes.c_int32
+    lib.ce_out_n_blocks.argtypes = [ctypes.c_void_p]
+    lib.ce_out_block_meta.argtypes = [ctypes.c_void_p, _i64p, _i32p,
+                                      _i32p, _i32p]
+    lib.ce_out_last_keys.argtypes = [ctypes.c_void_p, _u8p]
+    lib.ce_out_bloom_hashes.argtypes = [ctypes.c_void_p, _u64p]
+    lib.ce_out_first_key.restype = ctypes.c_int32
+    lib.ce_out_first_key.argtypes = [ctypes.c_void_p, _u8p,
+                                     ctypes.c_int32]
+    lib.ce_out_last_key.restype = ctypes.c_int32
+    lib.ce_out_last_key.argtypes = [ctypes.c_void_p, _u8p,
+                                    ctypes.c_int32]
+    lib.ce_bloom_build.argtypes = [
+        _u64p, ctypes.c_int64, _u8p, ctypes.c_uint64, ctypes.c_int32]
+    lib.ce_gather_rows.restype = None
+    lib.ce_gather_rows.argtypes = [
+        _u8p, _u8p, _u8p, _i64p, _i64p, _i64p, ctypes.c_int64, _u8p]
+    lib.ce_runcache_export.restype = ctypes.c_int64
+    lib.ce_runcache_export.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, _u8p,
+        ctypes.c_int32]
+    lib.ce_runcache_entry_bytes.restype = ctypes.c_int64
+    lib.ce_runcache_entry_bytes.argtypes = [ctypes.c_int64]
+    lib.ce_runcache_drop.argtypes = [ctypes.c_int64]
+    lib.ce_runcache_bytes.restype = ctypes.c_int64
+    lib.ce_job_add_cached.restype = ctypes.c_int32
+    lib.ce_job_add_cached.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    lib.ce_job_prepare_cached.restype = ctypes.c_int64
+    lib.ce_job_prepare_cached.argtypes = [ctypes.c_void_p]
+
+
 def _load():
-    global _lib
-    with _lib_lock:
-        if _lib is not None:
-            return _lib
-        from yugabyte_tpu.utils.native_build import build_native_lib
-        lib_path = build_native_lib("compaction_engine.cc",
-                                    "libcompaction_engine.so",
-                                    extra_args=("-lz", "-lpthread"))
-        lib = ctypes.CDLL(lib_path)
-        lib.ce_job_new.restype = ctypes.c_void_p
-        lib.ce_job_new.argtypes = [ctypes.c_int32]
-        lib.ce_job_free.argtypes = [ctypes.c_void_p]
-        lib.ce_job_error.restype = ctypes.c_char_p
-        lib.ce_job_error.argtypes = [ctypes.c_void_p]
-        lib.ce_job_add_input.argtypes = [
-            ctypes.c_void_p, _u8p, ctypes.c_int64, _i64p, _i32p, _i32p,
-            ctypes.c_int32]
-        lib.ce_job_prepare.restype = ctypes.c_int64
-        lib.ce_job_prepare.argtypes = [ctypes.c_void_p]
-        lib.ce_job_add_raw.argtypes = [
-            ctypes.c_void_p, _u8p, _i64p, ctypes.c_int64, _u64p,
-            ctypes.POINTER(ctypes.c_uint32), _u8p, _i64p]
-        lib.ce_job_sort_all.restype = ctypes.c_int64
-        lib.ce_job_sort_all.argtypes = [ctypes.c_void_p]
-        lib.ce_job_props.argtypes = [ctypes.c_void_p, _u64p,
-                                     _i32p]
-        lib.ce_job_merge.restype = ctypes.c_int64
-        lib.ce_job_merge.argtypes = [
-            ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int32, ctypes.c_int32]
-        lib.ce_job_set_survivors.argtypes = [
-            ctypes.c_void_p, _i64p, _u8p, ctypes.c_int64]
-        lib.ce_job_append_survivors.argtypes = [
-            ctypes.c_void_p, _i64p, _u8p, ctypes.c_int64]
-        lib.ce_job_rows.restype = ctypes.c_int64
-        lib.ce_job_rows.argtypes = [ctypes.c_void_p]
-        lib.ce_job_n_survivors.restype = ctypes.c_int64
-        lib.ce_job_n_survivors.argtypes = [ctypes.c_void_p]
-        lib.ce_job_write_output.restype = ctypes.c_int64
-        lib.ce_job_write_output.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p,
-            ctypes.c_int32, ctypes.c_int32, _u8p, ctypes.c_int32]
-        lib.ce_out_n_blocks.restype = ctypes.c_int32
-        lib.ce_out_n_blocks.argtypes = [ctypes.c_void_p]
-        lib.ce_out_block_meta.argtypes = [ctypes.c_void_p, _i64p, _i32p,
-                                          _i32p, _i32p]
-        lib.ce_out_last_keys.argtypes = [ctypes.c_void_p, _u8p]
-        lib.ce_out_bloom_hashes.argtypes = [ctypes.c_void_p, _u64p]
-        lib.ce_out_first_key.restype = ctypes.c_int32
-        lib.ce_out_first_key.argtypes = [ctypes.c_void_p, _u8p,
-                                         ctypes.c_int32]
-        lib.ce_out_last_key.restype = ctypes.c_int32
-        lib.ce_out_last_key.argtypes = [ctypes.c_void_p, _u8p,
-                                        ctypes.c_int32]
-        lib.ce_bloom_build.argtypes = [
-            _u64p, ctypes.c_int64, _u8p, ctypes.c_uint64, ctypes.c_int32]
-        lib.ce_gather_rows.restype = None
-        lib.ce_gather_rows.argtypes = [
-            _u8p, _u8p, _u8p, _i64p, _i64p, _i64p, ctypes.c_int64, _u8p]
-        lib.ce_runcache_export.restype = ctypes.c_int64
-        lib.ce_runcache_export.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, _u8p,
-            ctypes.c_int32]
-        lib.ce_runcache_entry_bytes.restype = ctypes.c_int64
-        lib.ce_runcache_entry_bytes.argtypes = [ctypes.c_int64]
-        lib.ce_runcache_drop.argtypes = [ctypes.c_int64]
-        lib.ce_runcache_bytes.restype = ctypes.c_int64
-        lib.ce_job_add_cached.restype = ctypes.c_int32
-        lib.ce_job_add_cached.argtypes = [ctypes.c_void_p, ctypes.c_int64]
-        lib.ce_job_prepare_cached.restype = ctypes.c_int64
-        lib.ce_job_prepare_cached.argtypes = [ctypes.c_void_p]
-        _lib = lib
-        return lib
-
-
-_available: Optional[bool] = None
+    return native_build.load("compaction_engine")
 
 
 def available() -> bool:
-    """Build-once probe; a failed compile is cached so the hot path does
-    not re-spawn a doomed g++ per compaction pick."""
-    global _available
-    if _available is None:
-        try:
-            _load()
-            _available = True
-        except Exception:  # yblint: contained(build probe — cached False routes every job to the Python shell)
-            _available = False
-    return _available
+    """Build-once probe; a failed build is cached with its reason
+    (native_build.unavailable()) and routes every job to the Python shell."""
+    return native_build.available("compaction_engine")
 
 
 def bloom_build(hashes: np.ndarray, bits: np.ndarray,

@@ -22,8 +22,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-_lib = None
-_lib_lock = threading.Lock()
+from yugabyte_tpu.utils import native_build
 
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _i32p = ctypes.POINTER(ctypes.c_int32)
@@ -33,62 +32,47 @@ _u64p = ctypes.POINTER(ctypes.c_uint64)
 _vpp = ctypes.POINTER(ctypes.c_void_p)
 
 
+def _bind(lib) -> None:
+    """The functions' types; native_build.load calls it once per process."""
+    lib.rs_open.restype = ctypes.c_void_p
+    lib.rs_open.argtypes = [_u8p, ctypes.c_int64, _i64p, _i32p, _i32p,
+                            ctypes.c_int32, _u8p, _i32p, _u8p,
+                            ctypes.c_int64]
+    lib.rs_close.argtypes = [ctypes.c_void_p]
+    lib.rs_error.restype = ctypes.c_char_p
+    lib.rs_error.argtypes = [ctypes.c_void_p]
+    lib.rs_doc_key_len.restype = ctypes.c_int32
+    lib.rs_doc_key_len.argtypes = [_u8p, ctypes.c_int32]
+    lib.rs_multi_get.restype = ctypes.c_int64
+    # key as c_char_p: ctypes passes the bytes object's buffer pointer
+    # directly (length travels separately), skipping a per-call cast on
+    # the hottest serving call
+    lib.rs_multi_get.argtypes = [_vpp, ctypes.c_int32, ctypes.c_char_p,
+                                 ctypes.c_int32, ctypes.c_int32,
+                                 ctypes.c_uint64, _u8p, ctypes.c_int64,
+                                 _u64p, _u32p, _u8p]
+    lib.rs_scan_new.restype = ctypes.c_void_p
+    lib.rs_scan_new.argtypes = [_vpp, ctypes.c_int32, _u8p, _i64p, _u64p,
+                                _u32p, _u8p, _i64p, _i32p, _u8p, _i64p,
+                                ctypes.c_int64, _u8p, ctypes.c_int32,
+                                _u8p, ctypes.c_int32, ctypes.c_uint64,
+                                ctypes.c_int32]
+    lib.rs_scan_free.argtypes = [ctypes.c_void_p]
+    lib.rs_scan_error.restype = ctypes.c_char_p
+    lib.rs_scan_error.argtypes = [ctypes.c_void_p]
+    lib.rs_scan_next.restype = ctypes.c_int64
+    lib.rs_scan_next.argtypes = [ctypes.c_void_p, ctypes.c_int64, _u8p,
+                                 ctypes.c_int64, _i32p, _u8p,
+                                 ctypes.c_int64, _i64p, _u64p, _u32p,
+                                 _u8p, _i32p]
+
+
 def _load():
-    global _lib
-    with _lib_lock:
-        if _lib is not None:
-            return _lib
-        from yugabyte_tpu.utils.native_build import build_native_lib
-        lib_path = build_native_lib("read_engine.cc", "libread_engine.so",
-                                    extra_args=("-lz",))
-        lib = ctypes.CDLL(lib_path)
-        lib.rs_open.restype = ctypes.c_void_p
-        lib.rs_open.argtypes = [_u8p, ctypes.c_int64, _i64p, _i32p, _i32p,
-                                ctypes.c_int32, _u8p, _i32p, _u8p,
-                                ctypes.c_int64]
-        lib.rs_close.argtypes = [ctypes.c_void_p]
-        lib.rs_error.restype = ctypes.c_char_p
-        lib.rs_error.argtypes = [ctypes.c_void_p]
-        lib.rs_doc_key_len.restype = ctypes.c_int32
-        lib.rs_doc_key_len.argtypes = [_u8p, ctypes.c_int32]
-        lib.rs_multi_get.restype = ctypes.c_int64
-        # key as c_char_p: ctypes passes the bytes object's buffer pointer
-        # directly (length travels separately), skipping a per-call cast on
-        # the hottest serving call
-        lib.rs_multi_get.argtypes = [_vpp, ctypes.c_int32, ctypes.c_char_p,
-                                     ctypes.c_int32, ctypes.c_int32,
-                                     ctypes.c_uint64, _u8p, ctypes.c_int64,
-                                     _u64p, _u32p, _u8p]
-        lib.rs_scan_new.restype = ctypes.c_void_p
-        lib.rs_scan_new.argtypes = [_vpp, ctypes.c_int32, _u8p, _i64p, _u64p,
-                                    _u32p, _u8p, _i64p, _i32p, _u8p, _i64p,
-                                    ctypes.c_int64, _u8p, ctypes.c_int32,
-                                    _u8p, ctypes.c_int32, ctypes.c_uint64,
-                                    ctypes.c_int32]
-        lib.rs_scan_free.argtypes = [ctypes.c_void_p]
-        lib.rs_scan_error.restype = ctypes.c_char_p
-        lib.rs_scan_error.argtypes = [ctypes.c_void_p]
-        lib.rs_scan_next.restype = ctypes.c_int64
-        lib.rs_scan_next.argtypes = [ctypes.c_void_p, ctypes.c_int64, _u8p,
-                                     ctypes.c_int64, _i32p, _u8p,
-                                     ctypes.c_int64, _i64p, _u64p, _u32p,
-                                     _u8p, _i32p]
-        _lib = lib
-        return lib
-
-
-_available: Optional[bool] = None
+    return native_build.load("read_engine")
 
 
 def available() -> bool:
-    global _available
-    if _available is None:
-        try:
-            _load()
-            _available = True
-        except Exception:
-            _available = False
-    return _available
+    return native_build.available("read_engine")
 
 
 def _u8ptr(b) -> _u8p:

@@ -167,10 +167,14 @@ class TabletServer:
         """Liveness (`status: ok`, what probes key on) plus the
         bucket-health board's single pane of glass: per-key state +
         rates + probe history, the state histogram, open quarantine
-        windows and the recent transition log."""
+        windows and the recent transition log; and every native library
+        this process could not get, with the reason (its callers run
+        their Python paths)."""
         from yugabyte_tpu.storage.bucket_health import health_board
+        from yugabyte_tpu.utils import native_build
         return {"status": "ok", "server_id": self.server_id,
-                "bucket_health": health_board().snapshot()}
+                "bucket_health": health_board().snapshot(),
+                "native_unavailable": native_build.unavailable()}
 
     def timeseriesz(self) -> dict:
         """The in-process time-series store: per-metric raw window,

@@ -25,13 +25,13 @@ _flags.define_flag(
     "scan_pushdown_pages", False,
     "route predicate-free scan RPC pages (the YCSB-E shape) through the "
     "fused device scan over resident slabs; default off — the per-page "
-    "dispatch only wins once the working set is resident (bench.py "
-    "enables it for the analytics/YCSB-E rungs)")
+    "dispatch only wins once the working set is resident (a scan-heavy "
+    "deployment turns it on)")
 
 
 def _scan_page_counters(pushed: bool) -> None:
-    """scan-RPC page accounting: total vs device-served — the numerator/
-    denominator of the bench's ycsb_e_pushdown_ratio."""
+    """scan-RPC page accounting: total vs device-served — the share of
+    scan pages the fused device scan answered."""
     from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
     e = ROOT_REGISTRY.entity("server", "scan_pushdown")
     e.counter("scan_rpc_pages_total",
@@ -665,7 +665,7 @@ class TabletServiceImpl:
         """The /compactionz "scans" block over RPC (webserver-less
         external nodes): pushdown hit/fallback counters by reason,
         per-bucket dispatches, blocks-decoded histogram, and the scan-
-        page routing counters the bench's ycsb_e_pushdown_ratio reads."""
+        page routing counters (_scan_page_counters)."""
         from yugabyte_tpu.ops.scan import pushdown_snapshot
         from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
         e = ROOT_REGISTRY.entity("server", "scan_pushdown")
@@ -681,7 +681,7 @@ class TabletServiceImpl:
     def overload_status(self) -> dict:
         """The /servez overload block over RPC: bounded-queue + shed
         counters + per-tablet write-pressure state. External-cluster
-        benches and the overload soak scrape this per node (their
+        drivers and the overload soak scrape this per node (their
         tservers run webserver-less, so the RPC is the only window)."""
         return {"server_id": self._tablets.server_id,
                 "overload": self._overload_provider()}

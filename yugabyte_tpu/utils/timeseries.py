@@ -25,8 +25,7 @@ waits on it (acceptance: zero new locks on the hot path).
 Queries: `window` (raw points), `delta`/`rate` (counter movement over a
 trailing window), and `page()` — the `/timeseriesz` JSON: per metric
 the raw window, the rate over the window, and a sparkline-ready
-downsample. `bench_snapshot()` is the compact form every bench round
-embeds.
+downsample.
 """
 
 from __future__ import annotations
@@ -310,8 +309,8 @@ class TimeSeriesStore:
         return self.capacity * self.metric_count()
 
     def overhead_ratio(self) -> float:
-        """Fraction of wall time spent sampling since start — the <1%
-        acceptance number bench.py snapshots on the YCSB rung."""
+        """Fraction of wall time spent sampling since start (held under
+        1% by tests/test_telemetry.py)."""
         with self._lock:
             total_ms = self._sample_ms_total
             t0 = self._started_t
@@ -358,16 +357,6 @@ class TimeSeriesStore:
             }
         meta["metrics"] = metrics
         return meta
-
-    def bench_snapshot(self, spark_points: int = 16) -> Dict[str, object]:
-        """Compact store snapshot every bench round embeds: the meta
-        block plus per-series last value + rate (no raw windows)."""
-        page = self.page(spark_points=spark_points)
-        out = {k: v for k, v in page.items() if k != "metrics"}
-        out["series"] = {
-            name: {"last": m["last"], "rate_per_s": round(m["rate_per_s"], 4)}
-            for name, m in page["metrics"].items()}
-        return out
 
 
 def _bucket_health_source() -> Dict[str, float]:
