@@ -40,8 +40,10 @@ CUTOFF = HybridTime.from_micros(10_000_000_000).value
 N_TABLETS, ROUNDS = 24, 3
 ROUND = ["mesh"] + ["wave"] * 8
 ROWS_PER_RUN = {"wave": 512, "mesh": 2048}
-POOL_STAGES = ("pool_stage", "pool_wave", "pool_finish", "pool_exclusive",
-               "pool_native")
+# self times of the pool's one scheduler thread; `device` and `merge_stage`
+# open only under its `pool_exclusive` here (the mesh job's step)
+SCHEDULER_STAGES = ("pool_sched_wait", "pool_wave", "pool_exclusive",
+                    "pool_native", "device", "merge_stage")
 
 pytestmark = pytest.mark.requires_native("compaction_engine")
 
@@ -114,8 +116,8 @@ def deployment(tmp_path_factory):
                "exclusive": exclusive, "wall_ms": wall_ms,
                "dist_steps": dist_steps.value() - dist0,
                "pool": {k: snap[k] - snap0[k] for k in (
-                   "waves", "wave_jobs", "native_completions",
-                   "wave_faults")},
+                   "waves", "wave_jobs", "owner_staged", "owner_finished",
+                   "native_completions", "wave_faults")},
                "stage_ms": {s: stages[s] - stages0[s] for s in stages}}
     finally:
         server.shutdown()
@@ -161,14 +163,19 @@ def test_the_mesh_sized_job_takes_the_whole_mesh(deployment):
 
 def test_pool_stage_counters_move_and_fit_the_pool_threads_wall(deployment):
     ms = deployment["stage_ms"]
-    for stage in ("pool_stage", "pool_wave", "pool_finish",
-                  "pool_exclusive"):
+    for stage in ("pool_stage", "pool_finish", "pool_wave", "pool_exclusive",
+                  "pool_sched_wait", "pool_wait"):
         assert ms[stage] > 0, stage
     assert ms["pool_native"] == 0
-    # self times on one thread: they cannot add up to more than its wall
-    assert sum(ms[s] for s in POOL_STAGES) <= deployment["wall_ms"]
-    # the submitters sat in pool_wait meanwhile, nine at a time
-    assert ms["pool_wait"] > sum(ms[s] for s in POOL_STAGES)
+    # self times on one thread, the scheduler's: they cannot add up to
+    # more than its wall. `pool_stage` and `pool_finish` run on the
+    # submitters' threads, several at once, and are in no such sum.
+    assert sum(ms[s] for s in SCHEDULER_STAGES) <= deployment["wall_ms"]
+    # every wave job was staged and finished by the thread that sat in
+    # `DB._dispatch_compaction` for it
+    pool = deployment["pool"]
+    assert pool["owner_staged"] == pool["owner_finished"] \
+        == pool["wave_jobs"] == ROUNDS * ROUND.count("wave")
 
 
 def test_the_servers_threads_follow_the_mesh_and_only_the_mesh(deployment):
@@ -196,6 +203,30 @@ def test_a_short_wave_is_held_back_briefly_and_a_full_one_not_at_all(
                 t0 = time.monotonic()
                 pool._linger_for_full_wave_unlocked()
                 assert low <= time.monotonic() - t0 < high
+    finally:
+        pool.shutdown()
+
+
+def test_a_short_wave_waits_for_jobs_still_with_their_owners(monkeypatch):
+    """An owner that is finishing a job frees a thread that may bring the
+    next one: while fewer wave jobs than slots are queued the round is
+    held until the owners are done (as it was when the scheduler finished
+    their jobs itself), and each one that retires starts the linger anew."""
+    from yugabyte_tpu.tserver import compaction_pool
+    from yugabyte_tpu.utils.cancellation import CancellationToken
+    monkeypatch.setattr(compaction_pool, "_WAVE_LINGER_S", 0.1)
+    pool = CompactionPool(make_mesh(4))
+    job = compaction_pool._Job("t", None, compaction_pool.PoolJobHandle(
+        "t", CancellationToken()))
+    try:
+        monkeypatch.setattr(pool, "_wave_jobs_queued_unlocked", lambda: 3)
+        with pool._cond:
+            pool._running["t"] = [job]
+        threading.Timer(0.5, pool._retire, args=(job,)).start()
+        with pool._cond:
+            t0 = time.monotonic()
+            pool._linger_for_full_wave_unlocked()
+            assert 0.5 + 0.1 <= time.monotonic() - t0 < 3.0
     finally:
         pool.shutdown()
 

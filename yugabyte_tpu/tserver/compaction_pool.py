@@ -33,10 +33,32 @@ Per-slot merge products stay device-resident: each job's output spans
 gather on ITS slot's device and install into the tablet's cache
 partition (storage/device_cache.ShardPartition), so the resident
 L0->L1->L2 chain survives sharding.
+
+Threads. A job's own host stages run on the thread that submitted it:
+`submit()` queues the job and then stages it (`_stage_job`: the job's own
+files, its own slot; one staging at a time, `_staging_turn`), and the
+thread waiting in `PoolJobHandle.result()` finishes it
+(`_finish_wave_job`: its own slot of the wave handle, its own outputs)
+once the wave's decisions are on the host, beside the other owners. A
+job nobody waits for is finished by the scheduler. The pool's one
+scheduler thread keeps the queue, the round, the wave dispatch and the
+mesh-sized job.
+
+ONE THREAD OWNS THE MESH: only the scheduler thread launches a program
+that spans more than one device (`pooled_merge_gc`, the mesh job's step
+and its `_dist_gather_span`). Each device runs what it is handed in
+order, so two threads that enqueue multi-device programs in different
+orders on different devices can leave every device waiting for a peer
+that is busy with the other program: a deadlock. Owner threads launch
+single-device programs only (`PoolWaveHandle.gather_span` on the slot's
+device, `stage_runs_from_staged` on the partition's device), which wait
+for no peer.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
 import time
 from collections import deque
@@ -48,7 +70,7 @@ import numpy as np
 from yugabyte_tpu.utils import flags
 from yugabyte_tpu.utils.cancellation import (CancellationToken,
                                              OperationCancelled)
-from yugabyte_tpu.utils.metrics import pool_span
+from yugabyte_tpu.utils.metrics import pipeline_span, pool_span
 from yugabyte_tpu.utils.trace import TRACE
 
 # Longest the scheduler holds a round back while fewer wave jobs than
@@ -56,6 +78,10 @@ from yugabyte_tpu.utils.trace import TRACE
 # arrive within a few milliseconds of each other; a wave job takes some
 # hundreds.
 _WAVE_LINGER_S = 0.020
+
+# How often the scheduler, waiting for a picked job's owner to end its
+# staging, looks at the job's cancel token.
+_STAGING_POLL_S = 0.050
 
 
 @dataclass
@@ -77,12 +103,17 @@ class PoolRequest:
 
 
 class PoolJobHandle:
-    """Caller's side of a submitted job: wait for the result, or cancel."""
+    """Caller's side of a submitted job: wait for the result, or cancel.
+    The thread that waits in `result()` is the job's owner: the pool hands
+    it the job's finishing work (`_offer`) and it runs that work there."""
 
     def __init__(self, tablet_id: str, cancel: CancellationToken):
         self.tablet_id = tablet_id
         self.cancel_token = cancel
+        self._cv = threading.Condition()
         self._done = threading.Event()
+        self._waiters = 0                 # guarded-by: _cv
+        self._owner_work = None           # guarded-by: _cv
         self._result = None
         self._exc: Optional[BaseException] = None
         self.submitted_at = time.monotonic()
@@ -96,31 +127,64 @@ class PoolJobHandle:
         return self._done.is_set()
 
     def result(self, timeout: Optional[float] = None):
-        if not self._done.wait(timeout):
-            raise TimeoutError("pool job still running")
+        deadline = None if timeout is None else time.monotonic() + timeout
+        with self._cv:
+            self._waiters += 1
+            try:
+                while self._owner_work is None and not self._done.is_set():
+                    left = None if deadline is None \
+                        else deadline - time.monotonic()
+                    if left is not None and left <= 0:
+                        raise TimeoutError("pool job still running")
+                    self._cv.wait(left)
+                work, self._owner_work = self._owner_work, None
+            finally:
+                self._waiters -= 1
+        if work is not None:
+            work()      # resolves this handle, whatever becomes of the job
         if self._exc is not None:
             raise self._exc
         return self._result
 
+    def _offer(self, work) -> bool:
+        """Hand the job's finishing work to a thread waiting in
+        `result()`. False when none waits: the caller runs it."""
+        with self._cv:
+            if not self._waiters or self._done.is_set():
+                return False
+            self._owner_work = work
+            self._cv.notify_all()
+            return True
+
     def _resolve(self, result=None, exc: Optional[BaseException] = None
                  ) -> None:
-        self._result = result
-        self._exc = exc
-        self.finished_at = time.monotonic()
-        self._done.set()
+        with self._cv:
+            self._result = result
+            self._exc = exc
+            self.finished_at = time.monotonic()
+            self._done.set()
+            self._cv.notify_all()
 
 
-@dataclass
+@dataclass(eq=False)
 class _Job:
     tablet_id: str
     request: PoolRequest
     handle: PoolJobHandle
-    # set during wave staging
+    # set during wave staging, by the submitting thread alone until it
+    # sets `staged_mark`
     filtered_inputs: List = field(default_factory=list)
     slabs: List = field(default_factory=list)
     staged: object = None
     dropped_rows: int = 0
     pins: List[int] = field(default_factory=list)
+    # staging has ended, however it ended / the handle's outcome is
+    # decided. A job that will not run loses its pins to whichever side
+    # finds the other's mark set, under the pool's lock
+    staged_mark: bool = False         # guarded-by: CompactionPool._lock
+    finished: bool = False            # guarded-by: CompactionPool._lock
+    # its finishing work went to the thread waiting in result()
+    with_owner: bool = False          # scheduler thread only
 
 
 def _bucket_name(bucket: Tuple[int, int]) -> str:
@@ -144,8 +208,10 @@ class CompactionPool:
         self._cond = threading.Condition(self._lock)
         self._queues: Dict[str, deque] = {}       # guarded-by: _lock
         self._credits: Dict[str, float] = {}      # rows served; _lock
-        self._running: Dict[str, int] = {}        # guarded-by: _lock
+        self._running: Dict[str, List[_Job]] = {}  # guarded-by: _lock
         self._shutdown = False                    # guarded-by: _lock
+        self._staging = False                     # guarded-by: _lock
+        self._stage_waiters: List[_Job] = []      # guarded-by: _lock
         self._last_fill = 0.0                     # guarded-by: _lock
         e = ROOT_REGISTRY.entity("server", "compaction_pool")
         self._c_jobs = e.counter(
@@ -156,6 +222,14 @@ class CompactionPool:
         self._c_wave_jobs = e.counter(
             "compaction_pool_wave_jobs_total",
             "jobs whose device stage rode a pooled wave slot")
+        self._c_owner_staged = e.counter(
+            "compaction_pool_owner_staged_total",
+            "wave jobs staged on the thread that submitted them")
+        self._c_owner_finished = e.counter(
+            "compaction_pool_owner_finished_total",
+            "wave jobs finished on the thread waiting for their result "
+            "(compaction_pool_wave_jobs_total less this: finished by the "
+            "scheduler, nobody waiting)")
         self._c_native = e.counter(
             "compaction_pool_native_completions_total",
             "pool jobs completed on the native path (bucket demoted, "
@@ -210,7 +284,62 @@ class CompactionPool:
             self._c_jobs.increment()
             self._g_queue.set(self._queue_depth_unlocked())
             self._cond.notify_all()
+        if not self._is_mesh_job(job):
+            self._stage_on_this_thread(job)
         return handle
+
+    def _stage_on_this_thread(self, job: _Job) -> None:
+        """The submitter's half of a wave job: stage it while it waits in
+        the queue. Nothing to merge, an expired-input result, a
+        cancellation and a failure resolve this job alone."""
+        try:
+            with self._staging_turn(job):
+                job.handle.cancel_token.check()
+                with pool_span("stage"):
+                    self._stage_job(job)
+            if job.staged is not None:
+                self._c_owner_staged.increment()
+        except BaseException as e:  # yblint: contained(per-job: the job's handle carries it and result() raises it)  # noqa: BLE001
+            self._finish(job, exc=e)
+        with self._cond:
+            job.staged_mark = True
+            wanted = not job.finished
+            if not wanted:
+                q = self._queues.get(job.tablet_id)
+                if q and job in q:
+                    q.remove(job)
+                    self._g_queue.set(self._queue_depth_unlocked())
+            self._cond.notify_all()
+        if not wanted:
+            self._unpin(job)
+
+    @contextlib.contextmanager
+    def _staging_turn(self, job: _Job):
+        """One staging at a time, a job the scheduler has picked and
+        waits for before one still queued. Staging is Python block by
+        block under the interpreter lock: side by side, eight stagings
+        took ten times as long each, and longer together than one after
+        another (PERF.md, PR 30). What they run beside is the
+        scheduler's dispatches and the other owners' finishes, which wait
+        on the device, on files and in the native library."""
+        with pipeline_span("pool_wait"), self._cond:
+            self._stage_waiters.append(job)
+            while self._staging or (
+                    not self._picked_unlocked(job)
+                    and any(self._picked_unlocked(j)
+                            for j in self._stage_waiters)):
+                self._cond.wait()
+            self._stage_waiters.remove(job)
+            self._staging = True
+        try:
+            yield
+        finally:
+            with self._cond:
+                self._staging = False
+                self._cond.notify_all()
+
+    def _picked_unlocked(self, job: _Job) -> bool:
+        return job in self._running.get(job.tablet_id, ())
 
     def submit_compaction(self, tablet_id: str, *, inputs, out_dir,
                           new_file_id, history_cutoff_ht, is_major,
@@ -232,16 +361,15 @@ class CompactionPool:
 
     def cancel_tablet(self, tablet_id: str,
                       reason: str = "tablet cancelled") -> int:
-        """Cancel every queued and running job of one tablet. Queued jobs
-        resolve immediately; running ones abort at their next stage
-        boundary. Returns how many jobs were signalled."""
-        n = 0
+        """Cancel every queued and running job of one tablet: each aborts
+        at its next stage boundary, on whichever thread runs it. Returns
+        how many jobs were signalled."""
         with self._cond:
-            for job in list(self._queues.get(tablet_id, ())):
-                job.handle.cancel(reason)
-                n += 1
-        # running jobs: their token is shared with the handle
-        return n
+            jobs = list(self._queues.get(tablet_id, ())) \
+                + list(self._running.get(tablet_id, ()))
+        for job in jobs:
+            job.handle.cancel(reason)
+        return len(jobs)
 
     def shutdown(self) -> None:
         with self._cond:
@@ -252,9 +380,15 @@ class CompactionPool:
             self._g_queue.set(0)
             self._cond.notify_all()
         for job in queued:
-            job.handle._resolve(exc=OperationCancelled(
+            self._abandon(job, OperationCancelled(
                 "compaction pool shut down"))
+        deadline = time.monotonic() + 10
         self._thread.join(timeout=10)
+        # jobs their owners are still finishing
+        with self._cond:
+            self._cond.wait_for(
+                lambda: not any(self._running.values()),
+                timeout=max(0.0, deadline - time.monotonic()))
 
     def snapshot(self) -> dict:
         """The /compactionz "pool" block: queue depth, per-tablet
@@ -274,12 +408,12 @@ class CompactionPool:
         with self._lock:
             tablets = {}
             for tid, q in self._queues.items():
-                r = self._running.get(tid, 0)
+                r = len(self._running.get(tid, ()))
                 if q or r:
                     tablets[tid] = {"queued": len(q), "running": r}
             for tid, r in self._running.items():
                 if r and tid not in tablets:
-                    tablets[tid] = {"queued": 0, "running": r}
+                    tablets[tid] = {"queued": 0, "running": len(r)}
             return {
                 "mesh_slots": self.n_slots,
                 "queue_depth": self._queue_depth_unlocked(),
@@ -288,6 +422,8 @@ class CompactionPool:
                 "bucket_rates": rates,
                 "waves": self._c_waves.value(),
                 "wave_jobs": self._c_wave_jobs.value(),
+                "owner_staged": self._c_owner_staged.value(),
+                "owner_finished": self._c_owner_finished.value(),
                 "native_completions": self._c_native.value(),
                 "wave_faults": self._c_faults.value(),
                 "cancelled": self._c_cancelled.value(),
@@ -296,6 +432,9 @@ class CompactionPool:
     # ------------------------------------------------------------- scheduling
     def _queue_depth_unlocked(self) -> int:
         return sum(len(q) for q in self._queues.values())
+
+    def _running_count_unlocked(self) -> int:
+        return sum(len(r) for r in self._running.values())
 
     def _is_mesh_job(self, job: _Job) -> bool:
         """A job at or above `distributed_compaction_min_rows` takes the
@@ -309,16 +448,24 @@ class CompactionPool:
                    if not self._is_mesh_job(job))
 
     def _linger_for_full_wave_unlocked(self) -> None:
-        """Hold the round back, for `_WAVE_LINGER_S` at most, while fewer
-        wave jobs than slots are queued: a round taken at the first
-        arrival would dispatch a one-job wave."""
+        """Hold the round back while fewer wave jobs than slots are
+        queued: for `_WAVE_LINGER_S` (a round taken at the first arrival
+        would dispatch a one-job wave), and for as long as jobs of
+        earlier waves are still with their owners, whose threads bring
+        the next jobs when they are done; each one that retires starts
+        the linger anew. The scheduler used to finish those jobs itself
+        before it came here, so this never starts a round later than
+        that did."""
         deadline = time.monotonic() + _WAVE_LINGER_S
         while not self._shutdown \
                 and self._wave_jobs_queued_unlocked() < self.n_slots:
+            with_owners = self._running_count_unlocked()
             left = deadline - time.monotonic()
-            if left <= 0:
+            if left <= 0 and not with_owners:
                 return
-            self._cond.wait(timeout=left)
+            self._cond.wait(timeout=left if left > 0 else _WAVE_LINGER_S)
+            if self._running_count_unlocked() < with_owners:
+                deadline = time.monotonic() + _WAVE_LINGER_S
 
     def _take_round(self) -> List[_Job]:
         """Pop queue heads in deficit-fair order: tablets sorted by rows
@@ -349,10 +496,9 @@ class CompactionPool:
                 if not progressed:
                     break
             for job in picked:
-                self._running[job.tablet_id] = \
-                    self._running.get(job.tablet_id, 0) + 1
+                self._running.setdefault(job.tablet_id, []).append(job)
             self._g_queue.set(self._queue_depth_unlocked())
-            self._g_running.set(sum(self._running.values()))
+            self._g_running.set(self._running_count_unlocked())
             return picked
 
     def _loop(self) -> None:
@@ -368,55 +514,87 @@ class CompactionPool:
             except Exception as e:  # noqa: BLE001 — scheduler must survive
                 TRACE("compaction pool: round failed: %s", e)
                 for job in jobs:
-                    if not job.handle.done:
-                        job.handle._resolve(exc=e)
+                    if not job.with_owner:
+                        self._abandon(job, e)
             finally:
-                with self._lock:
-                    for job in jobs:
-                        self._running[job.tablet_id] = max(
-                            0, self._running.get(job.tablet_id, 0) - 1)
-                    self._g_running.set(sum(self._running.values()))
+                # a job with its owner leaves `_running` when the owner's
+                # finish has returned
+                for job in jobs:
+                    if not job.with_owner:
+                        self._retire(job)
 
     # -------------------------------------------------------------- execution
     def _finish(self, job: _Job, result=None,
                 exc: Optional[BaseException] = None) -> None:
-        if job.handle.done:
-            return
-        if isinstance(exc, OperationCancelled):
-            self._c_cancelled.increment()
+        """Resolve the job's handle, once: the first caller's outcome
+        stands (the scheduler abandoning a cancelled job can meet its
+        owner's staging failure)."""
         rows = 0
         if result is not None:
             rows = getattr(result, "rows_in", 0) or \
                 (sum(s.n for s in job.slabs) if job.slabs else 0)
         with self._lock:
+            if job.finished:
+                return
+            job.finished = True
             self._credits[job.tablet_id] = \
                 self._credits.get(job.tablet_id, 0.0) + float(rows or 1)
+        if isinstance(exc, OperationCancelled):
+            self._c_cancelled.increment()
         self._h_wall.increment(
             (time.monotonic() - job.handle.submitted_at) * 1e3)
         job.handle._resolve(result=result, exc=exc)
+
+    def _abandon(self, job: _Job, exc: BaseException) -> None:
+        """Resolve a job that will not run. Staged, its pins go here;
+        still staging, its owner releases them when it finds the job
+        finished."""
+        self._finish(job, exc=exc)
+        with self._lock:
+            staged = job.staged_mark
+        if staged:
+            self._unpin(job)
+
+    def _retire(self, job: _Job) -> None:
+        """The job has left the pool: out of `_running`."""
+        with self._cond:
+            running = self._running.get(job.tablet_id, ())
+            if job in running:
+                running.remove(job)
+                self._g_running.set(self._running_count_unlocked())
+                self._cond.notify_all()
+
+    def _await_staging(self, job: _Job) -> bool:
+        """Wait for a picked job's owner to end its staging. False when
+        the job is resolved already: by its staging (nothing to merge, a
+        failure) or by its cancel token, which ends the wait early."""
+        token = job.handle.cancel_token
+        with self._cond:
+            while not job.staged_mark and not token.cancelled:
+                self._cond.wait(_STAGING_POLL_S)
+        if token.cancelled:
+            try:
+                token.check()
+            except OperationCancelled as e:
+                self._abandon(job, e)
+        return not job.handle.done
 
     def _run_round(self, jobs: List[_Job]) -> None:
         from yugabyte_tpu.ops.merge_gc import GCParams
         from yugabyte_tpu.storage import compaction as compaction_mod
 
-        # stage every job (filter, read, pack / cache restage, pin);
-        # failures and cancellations here affect only their own job
+        # every wave job was staged by its submitter (filter, read, pack
+        # / cache restage, pin): wait for those this round picked
         staged_jobs: List[_Job] = []
         big_jobs: List[_Job] = []
         for job in jobs:
-            try:
-                job.handle.cancel_token.check()
-                if self._is_mesh_job(job):
-                    big_jobs.append(job)
-                    continue
-                with pool_span("stage"):
-                    self._stage_job(job)
-                if job.staged is None:      # nothing to merge
-                    continue
+            if self._is_mesh_job(job):
+                big_jobs.append(job)
+                continue
+            with pool_span("sched_wait"):
+                wanted = self._await_staging(job)
+            if wanted:
                 staged_jobs.append(job)
-            except BaseException as e:  # noqa: BLE001 — per-job containment
-                self._unpin(job)
-                self._finish(job, exc=e)
 
         # shape-bucketed wave groups: (k_pad, m, w, is_major,
         # retain_deletes) — each group is one shard_map dispatch
@@ -501,11 +679,12 @@ class CompactionPool:
         job.staged = stage_pool_slot(job.slabs, *b)
 
     def _unpin(self, job: _Job) -> None:
+        with self._lock:    # once: an abandoned job's two sides can meet
+            pins, job.pins = job.pins, []
         cache = job.request.device_cache
         if cache is not None:
-            for fid in job.pins:
+            for fid in pins:
                 cache.unpin(fid)
-        job.pins = []
 
     def _run_wave(self, bucket: Tuple[int, int, int], is_major: bool,
                   retain_deletes: bool, group: List[_Job]) -> None:
@@ -559,19 +738,36 @@ class CompactionPool:
                                 (bucket[0], bucket[1]), rows, wall)
             for slot, job in enumerate(wave):
                 self._c_wave_jobs.increment()
-                try:
-                    with pool_span("finish"):
-                        self._finish_wave_job(job, handle, slot)
-                except BaseException as e:  # noqa: BLE001 — per-job
-                    self._finish(job, exc=e)
-                finally:
-                    self._unpin(job)
+                # a merge-only job's finish is its decisions themselves
+                job.with_owner = job.request.slabs is None \
+                    and job.handle._offer(functools.partial(
+                        self._finish_slot, job, handle, slot))
+                if not job.with_owner:
+                    self._finish_slot(job, handle, slot)
 
-    def _finish_wave_job(self, job: _Job, handle, slot: int) -> None:
+    def _finish_slot(self, job: _Job, handle, slot: int) -> None:
+        """One wave job from its slot's decisions to a resolved handle,
+        on its owner's thread or, nobody waiting, on the scheduler's."""
+        by_owner = threading.current_thread() is not self._thread
+        result = exc = None
+        try:
+            with pool_span("finish"):
+                result = self._finish_wave_job(job, handle, slot)
+        except BaseException as e:  # noqa: BLE001 — per-job containment
+            exc = e
+        self._unpin(job)
+        if by_owner:
+            self._c_owner_finished.increment()
+        self._finish(job, result=result, exc=exc)
+        if by_owner:
+            self._retire(job)
+
+    def _finish_wave_job(self, job: _Job, handle, slot: int):
         """Stage C of one wave job: write outputs from the slot's
         decisions through the sequential writer rules (byte-identical),
         installing survivor spans from the slot's device into the
-        tablet's cache partition as each SST hits disk."""
+        tablet's cache partition as each SST hits disk. Returns the
+        job's result."""
         from yugabyte_tpu.storage.compaction import (
             CompactionResult, run_compaction_job_with_decisions)
         job.handle.cancel_token.check()
@@ -581,8 +777,7 @@ class CompactionPool:
         req = job.request
         if req.slabs is not None:
             # merge-only job: the decisions ARE the result
-            self._finish(job, result=(surv, mk_surv))
-            return
+            return surv, mk_surv
         rows_in = sum(s.n for s in job.slabs) + job.dropped_rows
         on_span = None
         cache = req.device_cache
@@ -607,13 +802,12 @@ class CompactionPool:
                 if integrity.maybe_verify_resident_entry(st, base_path):
                     cache.put(fid, st, level=_lvl)
                     _installed.append(fid)
-        result = run_compaction_job_with_decisions(
+        return run_compaction_job_with_decisions(
             job.filtered_inputs, job.slabs, req.out_dir, req.new_file_id,
             req.history_cutoff_ht, req.is_major, req.retain_deletes,
             req.block_entries, surv, mk_surv, rows_in,
             frontier_inputs=req.inputs, cancel=job.handle.cancel_token,
             on_span=on_span)
-        self._finish(job, result=result)
 
     def _complete_natively(self, job: _Job, record_rate: bool) -> None:
         """Byte-identical native completion of one pool job (demoted

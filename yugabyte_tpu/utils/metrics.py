@@ -433,10 +433,17 @@ def publish_compile_surface(counts: Dict[str, int]) -> None:
 #   job = device + write + shadow + decode + encode + <the rest> + job_other
 # on the job's thread. `host` is the legacy inclusive slice (raw-byte
 # ingest + merge staging + decision decode); it overlaps the ingest
-# stages and is in no sum. The `pool_*` names are the mesh pool's
-# scheduler thread (tserver/compaction_pool.py, `pool_span`): self times
-# too, so that what runs on that thread sums to its busy wall, while the
-# submitting job's thread sits in `pool_wait`.
+# stages and is in no sum. The `pool_*` names are the mesh pool's spans
+# (tserver/compaction_pool.py, `pool_span`), self times too, on two kinds
+# of thread. `pool_stage` and `pool_finish` run on the job's own thread
+# (the one that submitted it: staging before it waits in `pool_wait`,
+# one job at a time; finishing inside it, a wave's jobs side by side),
+# so they are sums over threads with each other's waits for the
+# interpreter lock inside; a job nobody waits for is finished on the
+# scheduler's. `pool_wave`, `pool_exclusive`, `pool_native` and
+# `pool_sched_wait` (the scheduler waiting for a picked job's owner to
+# end its staging) are the pool's one scheduler thread, and with the job
+# stages opened under them sum to its busy wall.
 _PIPELINE_STAGES = (
     "host", "device", "write", "shadow", "decode", "encode",
     "job", "job_other",
@@ -451,7 +458,7 @@ _PIPELINE_STAGES = (
     "native_ingest", "native_merge",
     "version_install", "reader_open", "input_delete",
     "pool_stage", "pool_wave", "pool_finish", "pool_exclusive",
-    "pool_native")
+    "pool_native", "pool_sched_wait")
 
 _stage_metrics: Dict[str, Tuple[Histogram, Gauge]] = {}
 
@@ -527,10 +534,12 @@ def pipeline_span(name: str, inclusive: Optional[str] = None,
 
 
 def pool_span(name: str) -> span:
-    """The span "yb/pool/<name>" of the mesh compaction pool's scheduler
-    thread; its self time is the pipeline stage `pool_<name>`. The job
-    spans opened under it (`write`, `encode`, ...) keep their own
-    stages, as on a job's own thread."""
+    """The span "yb/pool/<name>" of the mesh compaction pool, on whichever
+    thread runs that part of a job (`stage` and `finish`: the job's own
+    thread; `wave`, `exclusive`, `native`, `sched_wait`: the pool's
+    scheduler thread); its self time is the pipeline stage
+    `pool_<name>`. The job spans opened under it (`write`, `encode`,
+    ...) keep their own stages, as on a job's own thread."""
     return span("pool/" + name, _pipeline_sink("pool_" + name, None))
 
 
