@@ -552,3 +552,93 @@ def test_scan_over_resident_slabs_matches_and_skips_decode(tmp_path):
     narrow2 = list(db.scan_visible(read_ht, lower_key=lo, upper_key=hi))
     assert narrow == narrow2
     db.close()
+
+
+def test_flush_fed_chain_job_stays_resident_and_takes_the_pallas_merge(
+        tmp_path, monkeypatch):
+    """The chain every tablet of a write-heavy table runs: four runs
+    through write_batch_columns + flush() on a fresh DB (each flush writes
+    its slab through to the tserver's shared slab cache), then ONE
+    compact_all(). Steered as the benchmark's rehearsal steers the CPU
+    backend (a cold bucket goes to the device, the merge is the Pallas
+    kernel): the job is a device decision and a Pallas merge, finds all
+    four inputs resident, decodes no block, misses no slab, and its output
+    is byte-identical to the native job over the same flushed files."""
+    from yugabyte_tpu.storage import DB, DBOptions, bucket_health
+    from yugabyte_tpu.storage.compaction import run_compaction_job
+    from yugabyte_tpu.storage.sst import BlockCache, data_file_name
+    from yugabyte_tpu.utils.metrics import kernel_metrics, \
+        pipeline_stage_totals
+
+    monkeypatch.setenv("YBTPU_MERGE_IMPL", "pallas")
+    monkeypatch.setattr(bucket_health, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flags, "get_flag",
+                        lambda name, _real=flags.get_flag:
+                        0.0 if name == "shadow_verify_sample"
+                        else _real(name))
+    bucket_health.health_board().reset()
+    rng = np.random.default_rng(23)
+    n, key_space = 1 << 11, 1 << 12
+    batches = []
+    for g in range(4):
+        keys = [b"S" + b"user%08d" % k + b"\x00\x00!" + b"K\x00\x01"
+                for k in rng.integers(0, key_space, size=n).tolist()]
+        ht = ((np.uint64(1_000_000 * (g + 1))
+               + rng.permutation(n).astype(np.uint64)) << np.uint64(12))
+        values = [b"S" + bytes(rng.integers(97, 123, size=24,
+                                            dtype=np.uint8)) + b"\x00\x00"
+                  for _ in range(n)]
+        batches.append((keys, ht, np.zeros(n, np.uint32), values))
+
+    def write_runs(db):
+        for g, (keys, ht, wid, values) in enumerate(batches):
+            db.write_batch_columns(keys, ht, wid, values, op_id=(1, g + 1))
+            db.flush()
+
+    plain = DB(str(tmp_path / "plain"), DBOptions(auto_compact=False))
+    write_runs(plain)
+    readers = [SSTReader(fm.path) for fm in plain.versions.live_files()]
+    ids = iter(range(1000, 2000))
+    os.makedirs(tmp_path / "native_out")
+    native = run_compaction_job(readers, str(tmp_path / "native_out"),
+                                lambda: next(ids), CUTOFF, True,
+                                device="native")
+    for r in readers:
+        r.close()
+    plain.close()
+
+    cache = DeviceSlabCache(_device())
+    block_cache = BlockCache(64 << 20)
+    counters = offload_policy._offload_counters()
+    pallas = kernel_metrics().counter("kernel_pallas_merge_total", "")
+    for chain in range(2):         # the second with everything compiled
+        db = DB(str(tmp_path / f"chain{chain}"), DBOptions(
+            offload_policy=bucket_health.health_board(), device=_device(),
+            device_cache=cache, block_cache=block_cache,
+            retention_policy=lambda: CUTOFF, auto_compact=False))
+        write_runs(db)
+        inputs = list(db.versions.live_files())
+        assert len(inputs) == 4
+        assert all(db._device_cache.contains(fm.file_id) for fm in inputs)
+        before = (counters["device"].value(), counters["native"].value(),
+                  pallas.value(), _block_decode_counter().value(),
+                  cache.misses)
+        stages = dict(pipeline_stage_totals())
+        db.compact_all()
+        assert db.background_error is None
+        after = (counters["device"].value(), counters["native"].value(),
+                 pallas.value(), _block_decode_counter().value(),
+                 cache.misses)
+        assert tuple(a - b for a, b in zip(after, before)) \
+            == (1, 0, 1, 0, 0)
+        moved = pipeline_stage_totals()
+        for stage in ("raw_read", "raw_parse", "decode"):
+            assert moved.get(stage, 0.0) == stages.get(stage, 0.0), stage
+        outs = [fm.path for fm in db.versions.live_files()]
+        assert len(outs) == len(native.outputs)
+        for mine, (_fid, theirs, _props) in zip(outs, native.outputs):
+            with open(data_file_name(mine), "rb") as a, \
+                    open(data_file_name(theirs), "rb") as b:
+                assert a.read() == b.read()
+        db.close()
+    bucket_health.health_board().reset()

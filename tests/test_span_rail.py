@@ -268,7 +268,7 @@ def test_budget_subs_stay_outside_measured_ms_and_ride_the_wire():
 
 def test_sub_stage_tables_agree():
     subs = set(latency._READ_SUB_HISTOGRAMS) | set(
-        latency._WRITE_SUB_HISTOGRAMS)
+        latency._WRITE_SUB_HISTOGRAMS) | set(latency._SCAN_SUB_HISTOGRAMS)
     assert subs == set(latency._SUB_OF)
     assert set(latency._SUB_OF.values()) == {latency.STAGE_DEVICE_DISPATCH,
                                              latency.STAGE_SERVER_OTHER}

@@ -194,7 +194,8 @@ class TestServePathAttribution:
         s.flush()
         page = cluster.tservers[0].servez()
         attr = page["attribution"]
-        assert set(attr) == {latency.OP_WRITE, latency.OP_MULTI_READ}
+        assert set(attr) == {latency.OP_WRITE, latency.OP_MULTI_READ,
+                             latency.OP_SCAN}
         wr = attr[latency.OP_WRITE]
         assert wr["e2e"]["count"] > 0
         for stage, snap in wr["stages"].items():

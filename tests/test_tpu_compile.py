@@ -149,6 +149,21 @@ def _survivor_positions(n):
     return run_merge._survivor_positions, (sdt((n,), jnp.bool_),), {}
 
 
+def _scan_group_agg(n_pad, presorted=True):
+    """The typed, grouped aggregate at Q1's shape class."""
+    from yugabyte_tpu.ops import scan_group as sg
+    from yugabyte_tpu.ops.scan import VAL_WORDS
+    c_pad, t_pad = sg.SHAPE_CLASSES[-1]
+    p, f = sg.PRED_PAD, sg.MAX_FACTORS
+    u32, i32 = jnp.uint32, jnp.int32
+    return sg._scan_group_agg_fused, _pushdown_args(n_pad, 1)[:14] + (
+        sdt((c_pad,), u32), sdt((p,), i32), sdt((p,), i32), sdt((p,), u32),
+        sdt((p,), u32), sdt((p, VAL_WORDS), u32), sdt((p,), i32),
+        sdt((2,), i32), sdt((t_pad, f), i32), sdt((t_pad, f), i32),
+        sdt((t_pad, f), u32), sdt((t_pad, f), u32)), dict(
+        w=_W, c_pad=c_pad, t_pad=t_pad, minmax=False, presorted=presorted)
+
+
 def _gather_staged(n, n_out_pad):
     from yugabyte_tpu.ops import run_merge
     return run_merge._gather_staged_output, (
@@ -194,6 +209,7 @@ def single_chip_specs(k_pad=2, m=1 << 16, n_scan=1 << 16):
         "scan_filtered": _scan_filtered(n_scan),
         "scan_filtered-merge": _scan_filtered(n_scan, presorted=False),
         "scan_agg": _scan_agg(n_scan),
+        "scan_group_agg": _scan_group_agg(n_scan),
         "point_read_probe-fnv64": _fnv64(),
         "point_read_probe-bloom": _bloom_probe(),
         "point_read_locate": _locate(n_scan),

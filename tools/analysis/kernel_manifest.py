@@ -51,6 +51,7 @@ MANIFEST_RELPATH = "tools/analysis/kernel_manifest.json"
 _RUN_MERGE = "yugabyte_tpu/ops/run_merge.py"
 _MERGE_GC = "yugabyte_tpu/ops/merge_gc.py"
 _SCAN = "yugabyte_tpu/ops/scan.py"
+_SCAN_GROUP = "yugabyte_tpu/ops/scan_group.py"
 _PALLAS = "yugabyte_tpu/ops/pallas_merge.py"
 _DIST = "yugabyte_tpu/parallel/dist_compact.py"
 _POLICY = "yugabyte_tpu/storage/offload_policy.py"
@@ -133,6 +134,27 @@ FAMILIES: Dict[str, dict] = {
                     "_key_byte_at", "_cmp_words", "_pack_bound",
                     "VAL_WORDS", "_VAL_ROWS", "PRED_SLOTS", "AGG_SLOTS",
                     "pred_slot_bucket", "agg_slot_bucket",
+                    "_PREWARM_NPADS", "_PREWARM_W"],
+            _MERGE_GC: ["sort_and_gc", "gc_over_sorted", "bucket_size"],
+        },
+    },
+    "scan_group_agg": {
+        # the typed, grouped aggregate (TPC-H Q1 / Q6): one dispatch a
+        # tablet lifts the referenced columns to row level, evaluates the
+        # predicates, finds the groups and reduces in integer limbs.
+        # Column, predicate, group and term selectors are DATA; the static
+        # axes are the two shape classes (column slots, term slots), the
+        # min/max outputs and the presorted single-source form.
+        "budget": 16,
+        "anchor": _SCAN_GROUP,
+        "symbols": {
+            _SCAN_GROUP: ["_scan_group_agg_fused", "_segmented_sum",
+                          "_mul64", "_add64", "_neg64", "_u32",
+                          "GROUP_SLOTS", "PRED_PAD", "SHAPE_CLASSES",
+                          "MAX_FACTORS", "KEY_WORDS", "_WIDE",
+                          "_TAG_INT64", "shape_class"],
+            _SCAN: ["_pushdown_base", "_doc_segments", "_key_byte_at",
+                    "_cmp_words", "VAL_WORDS", "_VAL_ROWS",
                     "_PREWARM_NPADS", "_PREWARM_W"],
             _MERGE_GC: ["sort_and_gc", "gc_over_sorted", "bucket_size"],
         },
@@ -776,6 +798,63 @@ def _gen_scan_agg() -> dict:
     return {"entries": entries}
 
 
+def _scan_group_args(jax, jnp, n_pad: int, w: int, c_pad: int, t_pad: int):
+    from yugabyte_tpu.ops import scan_group as sg
+    from yugabyte_tpu.ops.scan import VAL_WORDS
+    sdt = jax.ShapeDtypeStruct
+    u32, i32 = jnp.uint32, jnp.int32
+    p = sg.PRED_PAD
+    return _scan_pushdown_args(jax, jnp, n_pad, w, 1, True)[:14] + (
+        sdt((c_pad,), u32), sdt((p,), i32), sdt((p,), i32), sdt((p,), u32),
+        sdt((p,), u32), sdt((p, VAL_WORDS), u32), sdt((p,), i32),
+        sdt((2,), i32), sdt((t_pad, sg.MAX_FACTORS), i32),
+        sdt((t_pad, sg.MAX_FACTORS), i32), sdt((t_pad, sg.MAX_FACTORS), u32),
+        sdt((t_pad, sg.MAX_FACTORS), u32))
+
+
+def _gen_scan_group_agg() -> dict:
+    import jax
+    import jax.numpy as jnp
+    from yugabyte_tpu.ops import scan as scan_mod
+    from yugabyte_tpu.ops import scan_group as sg
+    from yugabyte_tpu.utils.jax_setup import lowering_text
+
+    entries = []
+    w = scan_mod._PREWARM_W
+    for n_pad in scan_mod._PREWARM_NPADS:
+        for c_pad, t_pad in sg.SHAPE_CLASSES:
+            for minmax, presorted in ((False, True), (False, False),
+                                      (True, True)):
+                args = _scan_group_args(jax, jnp, n_pad, w, c_pad, t_pad)
+                statics = dict(w=w, c_pad=c_pad, t_pad=t_pad, minmax=minmax,
+                               presorted=presorted)
+                out = jax.eval_shape(
+                    lambda *a: sg._scan_group_agg_fused(*a, **statics),
+                    *args)
+                text = lowering_text(sg._scan_group_agg_fused, args,
+                                     statics)
+                bucket = {"c_pad": c_pad, "n_pad": n_pad, "t_pad": t_pad,
+                          "w": w}
+                impl = ("minmax" if minmax else "sums") + (
+                    "-presorted" if presorted else "-merge")
+                entries.append({
+                    "key": "scan_group_agg " + entry_key(bucket, impl),
+                    "bucket": bucket,
+                    "impl": impl,
+                    "static_args": statics,
+                    "in_avals": [_aval_str(a) for a in args],
+                    "out_avals": [_aval_str(o) for o in
+                                  jax.tree_util.tree_leaves(out)],
+                    "donation": None,
+                    "variant_axes": {},
+                    "executables": 1,
+                    "prewarmed": False,
+                    "quarantine_key": [1, n_pad],
+                    "lowering_sha256": _lowering_sha256(text),
+                })
+    return {"entries": entries}
+
+
 def _gen_gather_staged() -> dict:
     """Write-through gather lattice, derived from _PREWARM_SHAPES: every
     prewarm bucket's merge is immediately followed by one survivor scan
@@ -1295,6 +1374,7 @@ _GENERATORS = {
     "scan_fused": _gen_scan_fused,
     "scan_filtered": _gen_scan_filtered,
     "scan_agg": _gen_scan_agg,
+    "scan_group_agg": _gen_scan_group_agg,
     "gather_staged": _gen_gather_staged,
     "restage_concat": _gen_restage_concat,
     "pallas_merge": _gen_pallas_merge,

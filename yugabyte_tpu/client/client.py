@@ -703,7 +703,9 @@ class YBClient:
                        partition_key: Optional[bytes] = None,
                        lower_doc_key: bytes = b"",
                        upper_doc_key: Optional[bytes] = None,
-                       row_cb=None, page_size: int = 4096):
+                       row_cb=None, page_size: int = 4096,
+                       group_by: Optional[Sequence[str]] = None,
+                       walk_stats: Optional[dict] = None):
         """Aggregate pushdown walk (ROADMAP item 5): per tablet, ask the
         scan RPC to compute [[fn, col], ...] over the filtered row set in
         ONE fused device dispatch. Tablets that cannot push (intents,
@@ -715,7 +717,14 @@ class YBClient:
         partition_key pins the walk to one tablet (the partition-prefix
         scan shape); otherwise every tablet of the table is visited at
         one pinned snapshot. Returns (combined_partial_or_None, read_ht)
-        — None when NO tablet answered with a device partial."""
+        — None when NO tablet answered with a device partial.
+
+        group_by (value-column names), product terms (`[fn, [[kind,
+        col], ...]]`) and DECIMAL / DATE / CHAR columns select the typed,
+        grouped kernel: the combined partial is then {"groups": [{"key",
+        "rows", "terms"}]}, merged group by group in exact integers.
+        walk_stats, when given, receives {"tablets", "from_rows"}: the
+        tablets visited and those that answered in rows."""
         from yugabyte_tpu.docdb.scan_spec import combine_agg_partials
         pinned = read_ht.value if read_ht else None
         cursor = partition_key if partition_key is not None else b""
@@ -726,14 +735,19 @@ class YBClient:
         flts = [list(f) for f in filters] if filters else None
         lower = lower_doc_key
         ask_agg = True   # first page per tablet tries the fused path
+        extra = {"group_by": list(group_by)} if group_by else {}
+        visited = from_rows = 0
         while True:
             tablet = self.meta_cache.lookup_tablet(table.table_id, cursor)
             try:
-                resp = self._tablet_call(
-                    table, tablet, "scan", refresh_key=cursor,
-                    lower_doc_key=lower, upper_doc_key=upper_doc_key,
-                    read_ht=pinned, limit=page_size, filters=flts,
-                    aggregates=aggs if ask_agg else None)
+                # serve-path attribution: one budget a tablet call, as
+                # multi_read has one a tablet group
+                with latency.budget_scope(latency.OP_SCAN):
+                    resp = self._tablet_call(
+                        table, tablet, "scan", refresh_key=cursor,
+                        lower_doc_key=lower, upper_doc_key=upper_doc_key,
+                        read_ht=pinned, limit=page_size, filters=flts,
+                        aggregates=aggs if ask_agg else None, **extra)
             except RemoteError as e:
                 retryable = (e.extra.get("tablet_split")
                              or e.extra.get("wrong_tablet")
@@ -753,9 +767,11 @@ class YBClient:
             backoff = Backoff(base_s=0.1, cap_s=1.0)
             if pinned is None:
                 pinned = resp.get("read_ht")
+            visited += ask_agg
             if "agg" in resp and resp["agg"] is not None:
                 partials.append(resp["agg"])
             else:
+                from_rows += ask_agg
                 for w in resp["rows"]:
                     if row_cb is not None:
                         row_cb(row_from_wire(w))
@@ -770,6 +786,8 @@ class YBClient:
             if partition_key is not None or not tablet.partition.end:
                 break
             cursor = tablet.partition.end
+        if walk_stats is not None:
+            walk_stats.update(tablets=visited, from_rows=from_rows)
         combined = combine_agg_partials(partials) if partials else None
         return combined, pinned
 

@@ -27,6 +27,17 @@ class DataType(enum.Enum):
     # (ref: src/yb/common/jsonb.h:40-44). Path navigation happens in the
     # query layer (-> / ->> operators).
     JSONB = "jsonb"
+    # Exact fixed-point and calendar types (TPC-H's lineitem): the stored
+    # primitive is an int in both cases, so the payload is kInt64 and
+    # memcmp order == numeric order like every other integer column.
+    #   DECIMAL(p, s): the unscaled integer (value * 10**s); `type_params`
+    #     = (p, s). No float anywhere: a decimal is exact or it is wrong.
+    #   DATE: days since 1970-01-01.
+    #   CHAR(n): a fixed-length string, stored as its bytes (kString);
+    #     `type_params` = (n,).
+    DECIMAL = "decimal"
+    DATE = "date"
+    CHAR = "char"
 
 
 class SortingType(enum.Enum):
@@ -53,6 +64,16 @@ class ColumnSchema:
     # default when an INSERT omits the column (ref: PG pg_attrdef +
     # sequence.c; YSQL's serial -> nextval default)
     default_seq: Optional[str] = None
+    # DECIMAL: (precision, scale); CHAR: (length,); None otherwise
+    type_params: Optional[Tuple[int, ...]] = None
+
+    @property
+    def scale(self) -> int:
+        """Digits right of the point of a DECIMAL column (0 otherwise):
+        the stored integer is value * 10**scale."""
+        if self.type is DataType.DECIMAL and self.type_params:
+            return int(self.type_params[1])
+        return 0
 
 
 @dataclass

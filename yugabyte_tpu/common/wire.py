@@ -24,7 +24,8 @@ def schema_to_wire(schema: Schema) -> dict:
     return {
         "columns": [[c.name, c.type.value, c.nullable, c.sorting.value,
                      c.dropped, list(c.collection) if c.collection else None,
-                     c.default_seq]
+                     c.default_seq,
+                     list(c.type_params) if c.type_params else None]
                     for c in schema.columns],
         "num_hash": schema.num_hash_key_columns,
         "num_range": schema.num_range_key_columns,
@@ -32,7 +33,7 @@ def schema_to_wire(schema: Schema) -> dict:
 
 
 def schema_from_wire(w: dict) -> Schema:
-    # elements 5 (dropped) and 6 (collection) are optional for wire /
+    # elements 4 (dropped) .. 7 (type_params) are optional for wire /
     # sys-catalog back-compat
     return Schema(
         columns=[ColumnSchema(col[0], DataType(col[1]), col[2],
@@ -40,7 +41,9 @@ def schema_from_wire(w: dict) -> Schema:
                               bool(col[4]) if len(col) > 4 else False,
                               tuple(col[5]) if len(col) > 5 and col[5]
                               else None,
-                              col[6] if len(col) > 6 else None)
+                              col[6] if len(col) > 6 else None,
+                              tuple(col[7]) if len(col) > 7 and col[7]
+                              else None)
                  for col in w["columns"]],
         num_hash_key_columns=w["num_hash"],
         num_range_key_columns=w["num_range"])

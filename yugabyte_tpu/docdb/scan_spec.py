@@ -57,8 +57,13 @@ class PushdownUnsupported(Exception):
 PUSHDOWN_OPS = ("=", "!=", "<", "<=", ">", ">=")
 OP_CODES = {op: i + 1 for i, op in enumerate(PUSHDOWN_OPS)}  # 0 = inactive
 
-# integer-family column types: stored payloads are kInt64 + 8B biased BE
-_INT_TYPES = (DataType.INT32, DataType.INT64, DataType.TIMESTAMP)
+# integer-family column types: stored payloads are kInt64 + 8B biased BE.
+# DECIMAL (the unscaled integer; literals arrive unscaled too — the query
+# layer owns the scale) and DATE (days) are integers like the rest.
+_INT_TYPES = (DataType.INT32, DataType.INT64, DataType.TIMESTAMP,
+              DataType.DECIMAL, DataType.DATE)
+# the typed columns that route an aggregate to the grouped kernel
+_TYPED = (DataType.DECIMAL, DataType.DATE, DataType.CHAR)
 
 AGG_FNS = ("count", "sum", "avg", "min", "max")
 
@@ -134,6 +139,14 @@ def _value_tags(col_type: DataType, value) -> Optional[Tuple[int, int]]:
         if not isinstance(value, bool):
             return None
         return (int(ValueType.kFalse), int(ValueType.kTrue))
+    if col_type is DataType.CHAR:
+        # short fixed-length strings: the whole encoded payload must sit
+        # inside the staged value words, or byte order would be compared
+        # on a truncated prefix
+        if not isinstance(value, str) \
+                or len(encode_literal(value)) > VAL_WORDS * 4:
+            return None
+        return (int(ValueType.kString), int(ValueType.kString))
     return None
 
 
@@ -239,7 +252,10 @@ def compile_filters(schema: Schema, filters: Optional[Sequence[Sequence]],
 
 def combine_agg_partials(partials: Sequence[dict]) -> dict:
     """Merge per-tablet aggregate partials (disjoint row sets): counts
-    and sums add, mins/maxes reduce, None means "no qualifying rows"."""
+    and sums add, mins/maxes reduce, None means "no qualifying rows".
+    Grouped partials (the typed kernel's) combine group by group."""
+    if any("groups" in p for p in partials):
+        return combine_group_partials(partials)
     out = {"rows": 0, "cols": {}}
     for p in partials:
         out["rows"] += int(p.get("rows", 0))
@@ -255,3 +271,230 @@ def combine_agg_partials(partials: Sequence[dict]) -> dict:
                     continue
                 dst[k] = v if dst[k] is None else pick(dst[k], v)
     return out
+
+
+# ---------------------------------------------------------------------------
+# The typed, grouped aggregate (TPC-H Q1 / Q6 shape): product terms over
+# exact fixed-point columns, a short group list, predicates over the typed
+# columns. One dispatch a tablet in ops/scan_group.py; everything is integer
+# arithmetic, and a DECIMAL answer is exact or refused.
+
+FACTOR_KINDS = ("col", "1-", "1+")      # col, (1 - col), (1 + col)
+MAX_FACTORS = 3
+MAX_GROUP_COLS = 2
+_GROUP_TYPES = _INT_TYPES + (DataType.BOOL, DataType.CHAR)
+
+
+@dataclass(frozen=True)
+class Factor:
+    """One factor of a product term. `one` is 1 at the column's scale:
+    `(1 - col)` over a DECIMAL(15,2) is `100 - stored`."""
+    kind: str
+    col: str
+    cid: int
+    scale: int
+
+    @property
+    def one(self) -> int:
+        return 10 ** self.scale
+
+
+@dataclass(frozen=True)
+class TermAgg:
+    """fn over a product of up to MAX_FACTORS factors; `factors` is ()
+    for COUNT(*). `term` indexes GroupAggSpec.terms (the distinct factor
+    tuples: SUM and AVG of one term share its sum), -1 for COUNT(*).
+    `scale` is the sum of the factors' scales: Q1's charge is scale 6."""
+    fn: str
+    factors: Tuple[Factor, ...] = ()
+    term: int = -1
+    scale: int = 0
+
+
+@dataclass(frozen=True)
+class GroupCol:
+    col: str
+    cid: int
+
+
+@dataclass(frozen=True)
+class GroupAggSpec:
+    """What the grouped kernel evaluates: the predicate conjunction, the
+    distinct product terms, the aggregates over them, the group list."""
+    predicates: Tuple[ColPredicate, ...] = ()
+    aggregates: Tuple[TermAgg, ...] = ()
+    terms: Tuple[Tuple[Factor, ...], ...] = ()
+    group_by: Tuple[GroupCol, ...] = ()
+    needs_vals = True
+
+    @property
+    def cids(self) -> Tuple[int, ...]:
+        """Distinct value columns the kernel lifts to row level, in
+        first-use order (predicates, groups, factors)."""
+        seen: List[int] = []
+        for cid in ([p.cid for p in self.predicates]
+                    + [g.cid for g in self.group_by]
+                    + [f.cid for t in self.terms for f in t]):
+            if cid not in seen:
+                seen.append(cid)
+        return tuple(seen)
+
+    @property
+    def wants_minmax(self) -> bool:
+        return any(a.fn in ("min", "max") for a in self.aggregates)
+
+
+def _value_column(schema: Schema, name):
+    """A non-key, non-collection column, or None."""
+    c = _column(schema, name)
+    if c is None or c.collection is not None:
+        return None
+    if name in {k.name for k in schema.hash_columns} | \
+            {k.name for k in schema.range_columns}:
+        return None
+    return c
+
+
+def _term_of(agg) -> Optional[list]:
+    """The wire aggregate's term as [[kind, col], ...]; [] for COUNT(*);
+    None when malformed."""
+    t = agg[1] if len(agg) > 1 else None
+    if t is None:
+        return []
+    if isinstance(t, str):
+        return [["col", t]]
+    if isinstance(t, (list, tuple)) and t and all(
+            isinstance(f, (list, tuple)) and len(f) == 2 for f in t):
+        return [list(f) for f in t]
+    return None
+
+
+def wants_group_kernel(schema: Schema, filters, aggregates,
+                       group_by) -> bool:
+    """True when the (filters, aggregates, group list) triple is the
+    typed kernel's to answer: a group list, a product term, or any
+    DECIMAL / DATE / CHAR column. Everything else stays with the scalar
+    kernel it had."""
+    if group_by:
+        return True
+    names = [f[0] for f in filters or () if isinstance(f[0], str)]
+    for a in aggregates or ():
+        t = _term_of(a)
+        if t is None or len(t) > 1 or any(k != "col" for k, _c in t):
+            return True
+        names += [c for _k, c in t]
+    for n in names:
+        c = _column(schema, n)
+        if c is not None and c.type in _TYPED:
+            return True
+    return False
+
+
+def compile_group_aggregate(schema: Schema, filters, aggregates, group_by
+                            ) -> Tuple[Optional[GroupAggSpec], str]:
+    """(spec, "") or (None, reason). Nothing half-pushes: one aggregate,
+    predicate or group column outside the subset refuses the whole."""
+    preds: List[ColPredicate] = []
+    for f in filters or ():
+        p = compile_predicate(schema, f[0], f[1], f[2])
+        if p is None:
+            return None, "op" if f[1] not in PUSHDOWN_OPS else "type"
+        preds.append(p)
+    groups: List[GroupCol] = []
+    for name in group_by or ():
+        c = _value_column(schema, name)
+        if c is None or c.type not in _GROUP_TYPES:
+            return None, "group_type"
+        groups.append(GroupCol(name, schema.column_id(name)))
+    if len(groups) > MAX_GROUP_COLS:
+        return None, "group_width"
+    terms: List[Tuple[Factor, ...]] = []
+    aggs: List[TermAgg] = []
+    for a in aggregates or ():
+        fn = str(a[0]).lower()
+        raw = _term_of(a)
+        if fn not in AGG_FNS or raw is None:
+            return None, "agg_type"
+        if not raw:
+            if fn != "count":
+                return None, "agg_type"
+            aggs.append(TermAgg("count"))
+            continue
+        if len(raw) > MAX_FACTORS:
+            return None, "agg_width"
+        factors = []
+        for kind, name in raw:
+            c = _value_column(schema, name)
+            if kind not in FACTOR_KINDS or c is None \
+                    or c.type not in _INT_TYPES:
+                return None, "agg_type"
+            factors.append(Factor(kind, name, schema.column_id(name),
+                                  c.scale))
+        key = tuple(factors)
+        if key not in terms:
+            terms.append(key)
+        scale = sum(f.scale for f in factors)
+        aggs.append(TermAgg(fn, key, terms.index(key), scale))
+    if not aggs:
+        return None, "agg_type"
+    return GroupAggSpec(tuple(preds), tuple(aggs), tuple(terms),
+                        tuple(groups)), ""
+
+
+def empty_term_stats() -> dict:
+    return {"nonnull": 0, "sum": 0, "min": None, "max": None}
+
+
+def combine_group_partials(partials: Sequence[dict]) -> dict:
+    """Grouped partials ({"groups": [{"key", "rows", "terms"}]}) from
+    disjoint row sets, merged group by group: exact Python integers."""
+    merged: dict = {}
+    for p in partials:
+        for g in p.get("groups") or ():
+            key = tuple(g["key"])
+            dst = merged.get(key)
+            if dst is None:
+                dst = merged[key] = {
+                    "key": list(key), "rows": 0,
+                    "terms": [empty_term_stats() for _ in g["terms"]]}
+            dst["rows"] += int(g["rows"])
+            for d, st in zip(dst["terms"], g["terms"]):
+                d["nonnull"] += int(st["nonnull"])
+                d["sum"] += int(st["sum"])
+                for k, pick in (("min", min), ("max", max)):
+                    v = st.get(k)
+                    if v is not None:
+                        d[k] = v if d[k] is None else pick(d[k], v)
+    return {"groups": list(merged.values())}
+
+
+def group_partial_from_dicts(spec: GroupAggSpec, dicts) -> dict:
+    """The rows path's twin of the kernel's partial: the same groups and
+    the same statistics from decoded row dicts (rows that already passed
+    the predicates), in Python integers. The grouped kernel is held to
+    this, and a refused spec is answered by it."""
+    merged: dict = {}
+    for d in dicts:
+        key = tuple(d.get(g.col) for g in spec.group_by)
+        dst = merged.get(key)
+        if dst is None:
+            dst = merged[key] = {
+                "key": list(key), "rows": 0,
+                "terms": [empty_term_stats() for _ in spec.terms]}
+        dst["rows"] += 1
+        for st, factors in zip(dst["terms"], spec.terms):
+            v = 1
+            for f in factors:
+                x = d.get(f.col)
+                if x is None:
+                    v = None
+                    break
+                v *= x if f.kind == "col" else \
+                    (f.one - x if f.kind == "1-" else f.one + x)
+            if v is None:
+                continue
+            st["nonnull"] += 1
+            st["sum"] += v
+            st["min"] = v if st["min"] is None else min(st["min"], v)
+            st["max"] = v if st["max"] is None else max(st["max"], v)
+    return {"groups": list(merged.values())}

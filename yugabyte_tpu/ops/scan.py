@@ -814,9 +814,12 @@ def _stage_pushdown(sources, spec, device):
     if any(s.slab is not None and bool((s.slab.flags & FLAG_DEEP).any())
            for s in live):
         raise PushdownUnsupported("deep")
-    if pred_slot_bucket(len(spec.predicates)) is None:
+    from yugabyte_tpu.docdb.scan_spec import GroupAggSpec
+    grouped = isinstance(spec, GroupAggSpec)    # scan_group.py sizes its own
+    if not grouped and pred_slot_bucket(len(spec.predicates)) is None:
         raise PushdownUnsupported("predicates")
-    if spec.agg_cids and agg_slot_bucket(len(spec.agg_cids)) is None:
+    if not grouped and spec.agg_cids \
+            and agg_slot_bucket(len(spec.agg_cids)) is None:
         raise PushdownUnsupported("agg_width")
     staged_list = []
     vals_list = []
@@ -922,10 +925,16 @@ def aggregate_sources(sources, read_ht_value: int, spec,
     {"rows": <count of passing rows>, "cols": {cid: {"nonnull", "sum",
     "min", "max"}}}. Sums/extremes are exact arbitrary-precision ints
     reconstructed from the device's byte-column sums / biased limbs."""
-    from yugabyte_tpu.docdb.scan_spec import PushdownUnsupported
+    from yugabyte_tpu.docdb.scan_spec import (GroupAggSpec,
+                                              PushdownUnsupported)
     from yugabyte_tpu.ops import device_faults
     from yugabyte_tpu.utils.metrics import record_kernel_dispatch
 
+    if isinstance(spec, GroupAggSpec):
+        # the typed, grouped aggregate has a kernel of its own
+        from yugabyte_tpu.ops.scan_group import group_aggregate_sources
+        return group_aggregate_sources(sources, read_ht_value, spec,
+                                       lower_key, upper_key, device=device)
     staged, vals, _live, presorted = _stage_pushdown(sources, spec, device)
     if staged is None:
         return {"rows": 0,

@@ -1278,7 +1278,7 @@ class DB:
         exact MVCC visibility across memtables and SSTs. Scalars only —
         host memory is touched once per RESULT, not once per row."""
         from yugabyte_tpu.ops.scan import aggregate_sources
-        sources, readers = self._pushdown_sources(spec)
+        sources, readers = self._pushdown_sources_spanned(spec)
         try:
             return aggregate_sources(sources, read_ht_value, spec,
                                      lower_key, upper_key,
@@ -1672,6 +1672,29 @@ class DB:
     @property
     def n_live_files(self) -> int:
         return len(self.versions.files)
+
+    # (appended at the class's end: a line that moves above the compaction
+    # methods re-keys the Pallas merge's compile cache, PERF.md section 7)
+    def _pushdown_sources_spanned(self, spec):
+        """`_pushdown_sources` under the serve path's `stage_lookup` span,
+        with a slab or value words staged inside the request counted
+        (the grouped aggregate's stage-miss counter)."""
+        from yugabyte_tpu.docdb.scan_spec import GroupAggSpec
+        from yugabyte_tpu.utils import latency
+        with latency.sub_span("stage_lookup"):
+            if isinstance(spec, GroupAggSpec) \
+                    and self._device_cache is not None:
+                with self._lock:
+                    fids = list(self._readers)
+                missing = 0
+                for fid in fids:
+                    st = self._device_cache.peek(fid)
+                    missing += st is None or st.vals_dev is None
+                if missing:
+                    from yugabyte_tpu.ops.scan_group import group_metrics
+                    group_metrics()["stage_miss"].increment(missing)
+            return self._pushdown_sources(spec)
+
 
 
 def _dedup_ikeys(stream: Iterator[Tuple[bytes, bytes]]

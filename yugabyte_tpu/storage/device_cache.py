@@ -110,6 +110,12 @@ class DeviceSlabCache:
         with self._lock:
             return key in self._map
 
+    def peek(self, key: CacheKey) -> Optional[StagedCols]:
+        """Metrics-neutral `get`: no hit or miss counted, no LRU touch."""
+        with self._lock:
+            ent = self._map.get(key)
+            return None if ent is None else ent.staged
+
     def level_of(self, key: CacheKey) -> Optional[int]:
         """Resident entry's LSM level, or None when absent (metrics-neutral:
         compaction derives its output level from the input levels)."""
@@ -320,6 +326,9 @@ class NamespacedSlabCache:
 
     def contains(self, file_id: int) -> bool:
         return self._shared.contains((self.namespace, file_id))
+
+    def peek(self, file_id: int):
+        return self._shared.peek((self.namespace, file_id))
 
     def level_of(self, file_id: int) -> Optional[int]:
         return self._shared.level_of((self.namespace, file_id))

@@ -835,9 +835,11 @@ class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit g
         if reason is not None:
             count_pushdown_fallback(reason)
             return None
+        from yugabyte_tpu.utils import latency
         try:
-            return self.regular_db.scan_aggregate(
-                ht.value, spec, lower_doc_key or None, upper_doc_key)
+            with latency.stage_span(latency.STAGE_DEVICE_DISPATCH):
+                return self.regular_db.scan_aggregate(
+                    ht.value, spec, lower_doc_key or None, upper_doc_key)
         except PushdownUnsupported as e:  # yblint: contained(typed refusal: caller re-aggregates rows host-side, result-identical; reason counted)
             count_pushdown_fallback(e.reason)
             return None
@@ -878,6 +880,28 @@ class Tablet:  # yblint: disable=ybsan-coverage (composition root: the .submit g
                  self.intents_db.oldest_memstore_write_s()]
         times = [t for t in times if t is not None]
         return min(times) if times else None
+
+    def import_packed(self, keys_blob: bytes, key_offs, wid,
+                      vals_blob: bytes, val_offs,
+                      ht: Optional[HybridTime] = None) -> HybridTime:
+        """The tserver's ImportData on this replica: one packed run
+        (tools/bulk_load.py packs it) installed as an L0 SST of the
+        regular DB (`DB.ingest_packed`), outside raft, at ONE hybrid time:
+        `ht`, or this replica's clock now when the caller names none (the
+        first replica of an import does; the others are given its answer).
+        The clock is moved past it, so every later write of this tablet is
+        newer and a read at any later time sees the import whole. The
+        file's frontier carries op id (0, 0): the flushed frontier, which
+        WAL replay starts from, does not move, and a later flush,
+        compaction or restart treats the file as any other L0 file."""
+        import numpy as np
+        ht = ht if ht is not None else self.clock.now()
+        self.clock.update(ht)
+        n = len(key_offs) - 1
+        self.regular_db.ingest_packed(
+            keys_blob, key_offs, np.full(n, ht.value, dtype=np.uint64),
+            wid, vals_blob, val_offs, op_id=(0, 0))
+        return ht
 
     def flush(self) -> None:
         self.regular_db.flush()

@@ -9,6 +9,7 @@ way the reference embeds TabletServerErrorPB::NOT_THE_LEADER + leader host.
 
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional
 
 from yugabyte_tpu.common.hybrid_time import HybridTime
@@ -27,6 +28,32 @@ _flags.define_flag(
     "fused device scan over resident slabs; default off — the per-page "
     "dispatch only wins once the working set is resident (a scan-heavy "
     "deployment turns it on)")
+
+
+def _cmp_keys_to_bound(blob, offs, bound: bytes):
+    """Sign of the bytewise comparison of every packed key (blob[offs[i]:
+    offs[i+1]]) with `bound`, over the bound's length: -1 below it, 0 the
+    key starts with it (so it is at or above), +1 above. One numpy pass:
+    an import names a few hundred thousand keys."""
+    import numpy as np
+    width = np.arange(len(bound))
+    lens = np.diff(offs)
+    at = np.minimum(offs[:-1, None] + width[None, :], max(len(blob) - 1, 0))
+    # a key shorter than the bound sorts before any byte there: -1
+    mat = np.where(width[None, :] < lens[:, None],
+                   blob[at].astype(np.int16), np.int16(-1))
+    diff = mat - np.frombuffer(bound, dtype=np.uint8).astype(np.int16)
+    first = (diff != 0).argmax(axis=1)
+    return np.sign(diff[np.arange(len(lens)), first])
+
+
+def _bulk_import_counters():
+    from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
+    e = ROOT_REGISTRY.entity("server", "bulk_import")
+    return (e.counter("bulk_import_rows_total",
+                      "DocDB entries installed by import_data"),
+            e.counter("bulk_import_replicas_total",
+                      "replica imports (one packed run into one replica)"))
 
 
 def _scan_page_counters(pushed: bool) -> None:
@@ -218,7 +245,8 @@ class TabletServiceImpl:
              limit: int = 10_000,
              filters: Optional[List[List]] = None,
              txn_id: Optional[bytes] = None,
-             aggregates: Optional[List[List]] = None) -> dict:
+             aggregates: Optional[List[List]] = None,
+             group_by: Optional[List[str]] = None) -> dict:
         """Bounded range scan; returns rows + a resume key when `limit` is
         hit (the reference pages exactly this way, ref
         pgsql_operation.cc:1040 paging state).
@@ -235,7 +263,13 @@ class TabletServiceImpl:
         {"agg": {rows, cols}, "read_ht"} computed by ONE fused device
         dispatch; otherwise rows return as usual and the caller
         aggregates them (the byte/result-identical fallback, counted by
-        reason in scan_pushdown_fallback_*_total)."""
+        reason in scan_pushdown_fallback_*_total).
+
+        group_by: optional value-column names. With it, with a product
+        term (`[fn, [[kind, col], ...]]`, kind one of col / 1- / 1+) or
+        with a DECIMAL / DATE / CHAR column the pair goes to the typed,
+        grouped kernel (ops/scan_group.py) and "agg" is {"groups": [...]};
+        a refusal is answered in rows like any other."""
         from yugabyte_tpu.docdb import scan_spec as SS
         from yugabyte_tpu.ops.scan import count_pushdown_fallback
         peer = self._tablets.get_tablet(tablet_id)
@@ -255,7 +289,13 @@ class TabletServiceImpl:
         proj = tuple(projection) if projection else None
         spec = None
         host_filters = filters
-        if filters or aggregates:
+        if aggregates and SS.wants_group_kernel(schema, filters,
+                                                aggregates, group_by):
+            spec, reason = SS.compile_group_aggregate(
+                schema, filters, aggregates, group_by)
+            if spec is None:
+                count_pushdown_fallback(reason)
+        elif filters or aggregates:
             spec, leftover, reason = SS.compile_filters(
                 schema, filters, aggregates)
             if spec is None:
@@ -299,20 +339,25 @@ class TabletServiceImpl:
         rows = []
         resume_key = None
         scanned = 0
-        for row in it:
-            scanned += 1
-            if host_filters and not _row_matches(row.to_dict(schema),
-                                                 host_filters):
-                # a filtered-out row still advances the paging cursor so a
-                # highly-selective predicate can't pin the scan in place
-                if scanned >= limit * 4:
+        # an aggregate answered from rows is the serve path's
+        # host_fallback stage: the page's row loop runs under it
+        with (_latency.stage_span(_latency.STAGE_HOST_FALLBACK)
+              if aggregates else contextlib.nullcontext()):
+            for row in it:
+                scanned += 1
+                if host_filters and not _row_matches(row.to_dict(schema),
+                                                     host_filters):
+                    # a filtered-out row still advances the paging cursor
+                    # so a highly-selective predicate can't pin the scan
+                    # in place
+                    if scanned >= limit * 4:
+                        resume_key = row.doc_key.encode() + b"\xff"
+                        break
+                    continue
+                rows.append(row_to_wire(row))
+                if len(rows) >= limit:
                     resume_key = row.doc_key.encode() + b"\xff"
                     break
-                continue
-            rows.append(row_to_wire(row))
-            if len(rows) >= limit:
-                resume_key = row.doc_key.encode() + b"\xff"
-                break
         return {"rows": rows, "resume_key": resume_key, "read_ht": ht.value,
                 "pushdown": pushed}
 
@@ -606,6 +651,40 @@ class TabletServiceImpl:
         with open(p, "rb") as f:
             f.seek(offset)
             return f.read(min(length, 1 << 20))
+
+    def import_data(self, tablet_id: str, n: int, keys_blob: bytes,
+                    key_offs: bytes, vals_blob: bytes, val_offs: bytes,
+                    wid: bytes, ht: Optional[int] = None) -> dict:
+        """ImportData (ref: tserver/tablet_service.cc ImportData, fed by
+        yb_bulk_load): install one packed run in THIS replica of the
+        tablet, leader or follower alike; the tool calls every replica.
+        Offsets are int64 and write ids uint32, little-endian, as bytes."""
+        import numpy as np
+        peer = self._tablets.get_tablet(tablet_id)
+        # sidecars arrive as bytearrays; the native encoder takes bytes
+        keys_blob, vals_blob = bytes(keys_blob), bytes(vals_blob)
+        offs = np.frombuffer(key_offs, dtype=np.int64)
+        if len(offs) != n + 1:
+            raise StatusError(Status.InvalidArgument(
+                f"import_data: {len(offs) - 1} key offsets for {n} entries"))
+        lo = peer.tablet.opts.lower_bound_key
+        hi = peer.tablet.opts.upper_bound_key
+        blob = np.frombuffer(keys_blob, dtype=np.uint8)
+        if (lo and (_cmp_keys_to_bound(blob, offs, lo) < 0).any()) or (
+                hi is not None
+                and (_cmp_keys_to_bound(blob, offs, hi) >= 0).any()):
+            err = StatusError(Status.IllegalState(
+                f"key outside tablet range of {tablet_id}"))
+            err.extra = {"wrong_tablet": True}
+            raise err
+        at = peer.tablet.import_packed(
+            keys_blob, offs, np.frombuffer(wid, dtype=np.uint32),
+            vals_blob, np.frombuffer(val_offs, dtype=np.int64),
+            ht=HybridTime(ht) if ht else None)
+        rows, replicas = _bulk_import_counters()
+        rows.increment(n)
+        replicas.increment()
+        return {"ht": at.value, "entries": n}
 
     def flush_tablet(self, tablet_id: str) -> bool:
         self._tablets.get_tablet(tablet_id).tablet.flush()

@@ -62,6 +62,7 @@ from yugabyte_tpu.utils.trace import AMBIENT, span
 
 OP_WRITE = "write"
 OP_MULTI_READ = "multi_read"
+OP_SCAN = "scan"    # one tablet's call of an aggregate-pushdown walk
 
 # Stage names (the vocabulary /servez and the README document).
 STAGE_CLIENT_QUEUE = "client_queue"      # op waited in the session batcher
@@ -98,6 +99,14 @@ _READ_STAGE_HISTOGRAMS = {
     STAGE_ROW_ASSEMBLY: "serve_path_multi_read_row_assembly_ms",
     STAGE_SERVER_OTHER: "serve_path_multi_read_server_other_ms",
 }
+_SCAN_STAGE_HISTOGRAMS = {
+    STAGE_WIRE_ENCODE: "serve_path_scan_wire_encode_ms",
+    STAGE_WIRE_TRANSFER: "serve_path_scan_wire_transfer_ms",
+    STAGE_RPC_QUEUE: "serve_path_scan_rpc_queue_ms",
+    STAGE_DEVICE_DISPATCH: "serve_path_scan_device_dispatch_ms",
+    STAGE_HOST_FALLBACK: "serve_path_scan_host_fallback_ms",
+    STAGE_SERVER_OTHER: "serve_path_scan_server_other_ms",
+}
 # Sub-stages: stage -> {sub-stage: per-op histogram names}. A sub-stage
 # is a slice INSIDE its stage (outside measured_ms()); `<stage>_other` /
 # `server_other_rest` is what the named slices leave.
@@ -130,7 +139,19 @@ _WRITE_SUB_HISTOGRAMS = {
     "batch_encode": "serve_path_write_batch_encode_ms",
     SUB_SERVER_REST: "serve_path_write_server_other_rest_ms",
 }
+_SCAN_SUB_HISTOGRAMS = {
+    # of device_dispatch (tablet/tablet.py, storage/db.py,
+    # ops/scan_group.py): the aggregate pushdown's dispatch
+    "stage_lookup": "serve_path_scan_stage_lookup_ms",
+    "query_pack": "serve_path_scan_query_pack_ms",
+    "device_enqueue": "serve_path_scan_device_enqueue_ms",
+    SUB_DEVICE_WAIT: "serve_path_scan_device_wait_ms",
+    "partial_build": "serve_path_scan_partial_build_ms",
+    SUB_DISPATCH_OTHER: "serve_path_scan_device_dispatch_other_ms",
+    SUB_SERVER_REST: "serve_path_scan_server_other_rest_ms",
+}
 _SUB_OF = {
+    "partial_build": STAGE_DEVICE_DISPATCH,
     "stage_lookup": STAGE_DEVICE_DISPATCH,
     "stage_miss": STAGE_DEVICE_DISPATCH,
     "query_pack": STAGE_DEVICE_DISPATCH,
@@ -152,14 +173,17 @@ _SUB_OF = {
 _E2E_HISTOGRAMS = {
     OP_WRITE: "serve_path_write_e2e_ms",
     OP_MULTI_READ: "serve_path_multi_read_e2e_ms",
+    OP_SCAN: "serve_path_scan_e2e_ms",
 }
 _STAGE_TABLES = {
     OP_WRITE: _WRITE_STAGE_HISTOGRAMS,
     OP_MULTI_READ: _READ_STAGE_HISTOGRAMS,
+    OP_SCAN: _SCAN_STAGE_HISTOGRAMS,
 }
 _SUB_TABLES = {
     OP_WRITE: _WRITE_SUB_HISTOGRAMS,
     OP_MULTI_READ: _READ_SUB_HISTOGRAMS,
+    OP_SCAN: _SCAN_SUB_HISTOGRAMS,
 }
 SUB_WIRE_KEY = "sub"   # the sub-stage map's key inside the wire stage map
 

@@ -31,6 +31,7 @@ from yugabyte_tpu.docdb.doc_operations import QLWriteOp, WriteOpKind
 from yugabyte_tpu.utils.status import Status, StatusError
 from yugabyte_tpu.yql import bfunc
 from yugabyte_tpu.yql import index_maintenance as IM
+from yugabyte_tpu.yql.cql import grouped as _grouped
 from yugabyte_tpu.yql.cql import parser as P
 
 _CQL_TYPES = {
@@ -42,7 +43,19 @@ _CQL_TYPES = {
     "TIMESTAMP": DataType.TIMESTAMP, "UUID": DataType.STRING,
     "TIMEUUID": DataType.STRING, "VARINT": DataType.INT64,
     "JSONB": DataType.JSONB,
+    # exact fixed-point, calendar and fixed-length text (TPC-H's types):
+    # DECIMAL(p,s) / CHAR(n) carry their parameters in the type text
+    "DECIMAL": DataType.DECIMAL, "NUMERIC": DataType.DECIMAL,
+    "DATE": DataType.DATE, "CHAR": DataType.CHAR,
 }
+
+
+def _split_type_params(cql_t: str):
+    """'DECIMAL(15,2)' -> ('DECIMAL', (15, 2)); 'INT' -> ('INT', None)."""
+    if cql_t.endswith(")") and "(" in cql_t and "<" not in cql_t:
+        base, _, rest = cql_t.partition("(")
+        return base, tuple(int(x) for x in rest[:-1].split(","))
+    return cql_t, None
 
 
 _CQL_AGGS = ("count", "sum", "avg", "min", "max")
@@ -516,8 +529,12 @@ class QLProcessor:
                     raise StatusError(Status.NotSupported(
                         "aggregates over system tables"))
                 return self._select_system(ks, stmt, params, cursor)
-            return self._select(stmt, params, cursor, page_size=page_size,
-                                page_state=paging_state)
+            rs = self._select(stmt, params, cursor, page_size=page_size,
+                              page_state=paging_state)
+            if not hasattr(rs, "pushdown"):     # grouped cells are typed
+                _grouped.typed_result(
+                    self._table(stmt.keyspace, stmt.table).schema, rs)
+            return rs
         if isinstance(stmt, (P.Insert, P.Update, P.Delete)):
             if getattr(stmt, "if_not_exists", False) \
                     or getattr(stmt, "if_exists", False) \
@@ -952,6 +969,7 @@ class QLProcessor:
                 columns.append(ColumnSchema(n, DataType.BINARY,
                                             collection=coll))
                 continue
+            cql_t, type_params = _split_type_params(cql_t)
             if cql_t not in _CQL_TYPES:
                 raise StatusError(Status.NotSupported(f"type {cql_t}"))
             if _CQL_TYPES[cql_t] is DataType.JSONB and n in key_order:
@@ -959,7 +977,15 @@ class QLProcessor:
                 # reference likewise rejects jsonb primary keys)
                 raise StatusError(Status.NotSupported(
                     f"jsonb column {n} cannot be a key"))
-            columns.append(ColumnSchema(n, _CQL_TYPES[cql_t]))
+            dtype = _CQL_TYPES[cql_t]
+            if dtype is DataType.DECIMAL:
+                type_params = (tuple(type_params) + (0,))[:2] \
+                    if type_params else (38, 0)
+            elif dtype is DataType.CHAR:
+                type_params = type_params or (1,)
+            else:
+                type_params = None
+            columns.append(ColumnSchema(n, dtype, type_params=type_params))
         schema = Schema(columns=columns,
                         num_hash_key_columns=len(stmt.hash_keys),
                         num_range_key_columns=len(stmt.range_keys))
@@ -979,6 +1005,8 @@ class QLProcessor:
             schema = table.schema
             bound = {c: self._bind(v, params, cursor)
                      for c, v in zip(stmt.columns, stmt.values)}
+            bound = {c: _grouped.stored_value(schema, c, v)
+                     for c, v in bound.items()}
             key_names = [c.name for c in schema.hash_columns] + \
                 [c.name for c in schema.range_columns]
             missing = [k for k in key_names if k not in bound]
@@ -1172,13 +1200,19 @@ class QLProcessor:
                 raise StatusError(Status.InvalidArgument(
                     f"token() arguments must be the partition key "
                     f"columns {hash_col_names} in order"))
+        if _grouped.wants_grouped(P, schema, stmt, out_items):
+            # GROUP BY, product terms, DECIMAL / DATE / CHAR aggregates:
+            # the typed, grouped pushdown (yql/cql/grouped.py)
+            return _grouped.select_grouped(self, P, stmt, out_items,
+                                           params, cursor)
         aggs = _extract_cql_aggregates(out_items)
         if aggs is not None:
             return self._select_aggregate(stmt, aggs, params, cursor)
         if stmt.distinct:
             return self._select_distinct(stmt, params, cursor,
                                          page_size, page_state)
-        where = self._bind_where(stmt.where, params, cursor)
+        where = _grouped.typed_where(
+            schema, self._bind_where(stmt.where, params, cursor))
         known = {c.name: c.type for c in schema.columns}
         where = self._canon_jsonb_where(where, known)
 

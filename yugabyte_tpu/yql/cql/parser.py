@@ -103,6 +103,25 @@ class CreateIndex:
     if_not_exists: bool = False
 
 
+class DecimalText(float):
+    """A numeric literal with a point: a float to everything that took
+    one before, with the literal's own text beside it, so that a DECIMAL
+    column reads the digits as written (no float on that path)."""
+
+    def __new__(cls, text: str):
+        obj = super().__new__(cls, text)
+        obj.text = text
+        return obj
+
+
+@dataclass(frozen=True)
+class Product:
+    """An aggregate's argument that is a product of up to three factors
+    `col`, `(1 - col)`, `(1 + col)`: ((kind, column), ...), kind one of
+    col / 1- / 1+ (TPC-H Q1's `l_extendedprice * (1 - l_discount)`)."""
+    factors: Tuple[Tuple[str, str], ...]
+
+
 @dataclass
 class FuncCall:
     """Builtin invocation in a select list or value expression (ref: the
@@ -163,6 +182,11 @@ class Select:
     # ORDER BY clustering_col [ASC|DESC] — valid only with the partition
     # key restricted (CQL semantics; ref: sem/analyzer order-by checks)
     order_by: List[Tuple[str, bool]] = field(default_factory=list)
+    # GROUP BY value columns: the typed, grouped aggregate (an extension
+    # over YCQL, which has none; yql/cql/grouped.py)
+    group_by: List[str] = field(default_factory=list)
+    # select item position -> its AS alias
+    aliases: Dict[int, str] = field(default_factory=dict)
 
 
 @dataclass
@@ -287,7 +311,42 @@ class Parser:
                 inner.append(self._column_type())
             self.expect_op(">")
             return f"{t}<{','.join(inner)}>"
+        if t in ("DECIMAL", "NUMERIC", "CHAR") and self.accept_op("("):
+            params = [str(int(self.literal()))]
+            while self.accept_op(","):
+                params.append(str(int(self.literal())))
+            self.expect_op(")")
+            return f"{t}({','.join(params)})"
         return t
+
+    def _date_literal(self):
+        """`date 'YYYY-MM-DD' [(+|-) interval 'n' (day|month|year)]`, as
+        TPC-H's query text has it: a datetime.date."""
+        import datetime
+        try:
+            d = datetime.date.fromisoformat(self.next()[1][1:-1])
+        except ValueError as e:
+            raise ParseError(f"bad date literal: {e}")
+        while self.peek() in (("op", "+"), ("op", "-")):
+            sign = 1 if self.next()[1] == "+" else -1
+            self.expect_kw("INTERVAL")
+            tok = self.next()
+            if tok[0] not in ("string", "number"):
+                raise ParseError("interval needs a count")
+            n = sign * int(tok[1].strip("'"))
+            unit = self.name().upper().rstrip("S")
+            if unit == "DAY":
+                d += datetime.timedelta(days=n)
+            elif unit in ("MONTH", "YEAR"):
+                months = d.year * 12 + d.month - 1 + (
+                    n if unit == "MONTH" else 12 * n)
+                d = d.replace(year=months // 12, month=months % 12 + 1)
+            else:
+                raise ParseError(f"unknown interval unit {unit!r}")
+            if self.accept_op("("):         # `day (3)`: a precision
+                self.literal()
+                self.expect_op(")")
+        return d
 
     def literal(self):
         # collection literals: [e, ...] list, {e, ...} set, {k: v, ...} map
@@ -328,7 +387,10 @@ class Parser:
         if kind == "string":
             return text[1:-1].replace("''", "'")
         if kind == "number":
-            return float(text) if "." in text else int(text)
+            return DecimalText(text) if "." in text else int(text)
+        if kind == "name" and text.upper() == "DATE" \
+                and self.peek() and self.peek()[0] == "string":
+            return self._date_literal()
         if kind == "blob":
             return bytes.fromhex(text[2:])
         if kind == "op" and text == "?":
@@ -470,12 +532,38 @@ class Parser:
 
     def _func_arg(self):
         tok = self.peek()
+        if tok == ("op", "("):
+            return self._product(self._paren_factor())
         if tok and tok[0] == "name" and \
                 tok[1].upper() not in ("TRUE", "FALSE", "NULL"):
             if self._peek2() == ("op", "("):
                 return self._func_call()
-            return ColumnRef(self.name())
+            ref = ColumnRef(self.name())
+            if self.peek() == ("op", "*"):
+                return self._product(("col", ref.name))
+            return ref
         return self.literal()
+
+    def _paren_factor(self) -> Tuple[str, str]:
+        """`(1 - col)` or `(1 + col)`."""
+        self.expect_op("(")
+        if self.next() != ("number", "1"):
+            raise ParseError("a product factor is col, (1 - col) or "
+                             "(1 + col)")
+        tok = self.next()
+        if tok not in (("op", "-"), ("op", "+")):
+            raise ParseError(f"expected + or - in a factor, got {tok[1]!r}")
+        col = self.name()
+        self.expect_op(")")
+        return ("1" + tok[1], col)
+
+    def _product(self, first: Tuple[str, str]) -> Product:
+        factors = [first]
+        while self.accept_op("*"):
+            factors.append(self._paren_factor()
+                           if self.peek() == ("op", "(")
+                           else ("col", self.name()))
+        return Product(tuple(factors))
 
     def _value_expr(self):
         """literal, or a builtin call over literals — INSERT ... VALUES
@@ -582,15 +670,25 @@ class Parser:
 
     def _select(self) -> Select:
         distinct = bool(self.accept_kw("DISTINCT"))
+        aliases: Dict[int, str] = {}
         if self.accept_op("*"):
             cols = None
         else:
-            cols = [self._select_item()]
-            while self.accept_op(","):
+            cols = []
+            while True:
                 cols.append(self._select_item())
+                if self.accept_kw("AS"):
+                    aliases[len(cols) - 1] = self.name()
+                if not self.accept_op(","):
+                    break
         self.expect_kw("FROM")
         ks, table = self.qualified_name()
         where = self._where() if self.accept_kw("WHERE") else []
+        group_by: List[str] = []
+        if self.accept_kw("GROUP", "BY"):
+            group_by.append(self.name())
+            while self.accept_op(","):
+                group_by.append(self.name())
         order_by: List[Tuple[str, bool]] = []
         if self.accept_kw("ORDER", "BY"):
             while True:
@@ -606,7 +704,8 @@ class Parser:
             limit = int(self.literal())
         self.accept_kw("ALLOW", "FILTERING")
         return Select(ks, table, cols, where, limit, order_by=order_by,
-                      distinct=distinct)
+                      distinct=distinct, group_by=group_by,
+                      aliases=aliases)
 
     def _where(self) -> List[Tuple[str, str, object]]:
         conds = []
@@ -625,6 +724,11 @@ class Parser:
                     vals.append(self.literal())
                 self.expect_op(")")
                 conds.append((col, "in", vals))
+            elif self.accept_kw("BETWEEN"):
+                lo = self.literal()
+                self.expect_kw("AND")
+                conds.append((col, ">=", lo))
+                conds.append((col, "<=", self.literal()))
             else:
                 tok = self.next()
                 if tok[0] != "op" or tok[1] not in ("=", "<", ">", "<=",
