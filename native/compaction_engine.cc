@@ -345,6 +345,50 @@ int64_t ce_job_sort_all(void* jp) {
   return n;
 }
 
+int32_t ce_job_stride(void* jp) { return ((Job*)jp)->stride; }
+
+// The job's columns in survivor order, as the columns of a KVSlab
+// (ops/slabs.py): what ce_job_add_raw derived is handed out, so that no
+// second parser walks the run to describe it. key_words is [n, stride / 4]:
+// the key bytes as big-endian words, zero-padded (the stride is the slab's
+// width: a multiple of 4, at least 4). perm[i] is the input row of survivor
+// i, for the caller's gather of the values. Valid after ce_job_sort_all (a
+// flush keeps every row and rewrites none). Returns 1 where the survivors
+// are not the input order (perm is not the identity), 0 where they are.
+int32_t ce_job_export_columns(void* jp, uint32_t* key_words,
+                              int32_t* key_len, int32_t* dkl,
+                              uint32_t* ht_hi, uint32_t* ht_lo,
+                              uint32_t* wid, uint32_t* flags,
+                              int64_t* ttl_ms, int64_t* perm) {
+  Job* j = (Job*)jp;
+  int64_t n = (int64_t)j->surv.size();
+  int32_t w = j->stride / 4;
+  std::atomic<bool> reordered{false};
+  // chunks, not rows: pfor hands out one index at a time
+  constexpr int64_t kChunk = 8192;
+  pfor((n + kChunk - 1) / kChunk, j->n_threads, [&](int64_t c) {
+    int64_t end = (c + 1) * kChunk < n ? (c + 1) * kChunk : n;
+    bool moved = false;
+    for (int64_t i = c * kChunk; i < end; ++i) {
+      int64_t r = j->surv[i];
+      moved |= r != i;
+      const uint8_t* k = &j->keys[r * j->stride];
+      for (int32_t x = 0; x < w; ++x)
+        key_words[i * w + x] = __builtin_bswap32(rd_u32(k + 4 * x));
+      key_len[i] = j->key_len[r];
+      dkl[i] = j->dkl[r];
+      ht_hi[i] = (uint32_t)(j->ht[r] >> 32);
+      ht_lo[i] = (uint32_t)j->ht[r];
+      wid[i] = j->wid[r];
+      flags[i] = j->flags[r];
+      ttl_ms[i] = j->ttl_ms[r];
+      perm[i] = r;
+    }
+    if (moved) reordered.store(true);
+  });
+  return reordered.load() || n != j->n ? 1 : 0;
+}
+
 // Whole-file props the base file needs (valid after add_raw or prepare):
 // max_expire_us (0 unless EVERY entry has a TTL) and has_deep.
 void ce_job_props(void* jp, uint64_t* max_expire_us, int32_t* has_deep) {

@@ -295,3 +295,142 @@ def test_chunked_write_through_matches_host(tmp_path, monkeypatch):
             host_cols[:r_common, :host_staged.n])
     for r in readers:
         r.close()
+
+
+# ---------------------------------------------------------------------------
+# DB.flush's write-through: the slab comes from the job that wrote the file
+# ---------------------------------------------------------------------------
+
+class _RecordingCache(DeviceSlabCache):
+    """Keeps the host slab each stage() was handed."""
+
+    def __init__(self, device=None):
+        super().__init__(device=device)
+        self.slabs = {}
+
+    def stage(self, key, slab, **kw):
+        self.slabs[key] = slab
+        return super().stage(key, slab, **kw)
+
+
+def _flush_items(n=1500, key_space=400):
+    """Column writes with several versions a key, row tombstones, TTLs,
+    an object marker and a deep document."""
+    from yugabyte_tpu.common.hybrid_time import DocHybridTime, HybridTime
+    from yugabyte_tpu.docdb.doc_key import DocKey, SubDocKey
+    from yugabyte_tpu.docdb.value import Value
+    rng = np.random.default_rng(21)
+    items = []
+    for i in range(n):
+        dk = DocKey(range_components=("user%08d" % rng.integers(key_space),))
+        kind = i % 7
+        if kind == 0:
+            key, val = dk.encode(), Value.tombstone()
+        elif kind == 1:
+            key = SubDocKey(dk, (("col", 1), "m", i % 5)).encode(
+                include_ht=False)
+            val = Value(primitive=i)
+        elif kind == 2:
+            key = SubDocKey(dk, (("col", 2),)).encode(include_ht=False)
+            val = Value(primitive="t" * 20, ttl_ms=1000 + i)
+        elif kind == 3:
+            key = SubDocKey(dk, (("col", 1),)).encode(include_ht=False)
+            val = Value(is_object=True)
+        else:
+            key = SubDocKey(dk, (("col", 3),)).encode(include_ht=False)
+            val = Value(primitive="v%037d" % i)
+        items.append((key, DocHybridTime(HybridTime((1000 + i) << 12), i % 2),
+                      val.encode()))
+    return items
+
+
+def _flushed_db(path, items, **opts):
+    from yugabyte_tpu.storage.db import DB, DBOptions
+    db = DB(path, DBOptions(auto_compact=False, **opts))
+    db.write_batch(items)
+    fid = db.flush()
+    assert fid is not None and db.background_error is None
+    return db, fid
+
+
+def test_flush_stages_the_slab_of_the_job_that_wrote_the_file(tmp_path):
+    import sys
+    sys.path.insert(0, os.path.dirname(__file__))
+    from test_flush_slab import assert_slabs_equal
+    from yugabyte_tpu.storage.db import flush_slab_metrics
+    from yugabyte_tpu.storage.memtable import MemTable
+    from yugabyte_tpu.storage.sst import data_file_name
+    items = _flush_items()
+    meters = flush_slab_metrics()
+    before = {k: c.value() for k, c in meters.items()}
+    cache = _RecordingCache(device=_device())
+    db, fid = _flushed_db(str(tmp_path / "cached"), items,
+                          device=_device(), device_cache=cache)
+    moved = {k: c.value() - before[k] for k, c in meters.items()}
+    assert moved == {"native": 1, "python": 0}
+    assert db._device_cache.contains(fid)
+    (staged_slab,) = cache.slabs.values()
+    # the Python memtable's slab over the same writes: pack_kvs entry by
+    # entry, the oracle
+    oracle = MemTable()
+    oracle.add_batch(items)
+    assert_slabs_equal(staged_slab, oracle.to_slab())
+    # and what a later merge reads of the staged entry is a restage of
+    # the file
+    rdr = SSTReader(db.versions.files[fid].path)
+    host = stage_slab(rdr.read_all())
+    rdr.close()
+    dev = db._device_cache.get(fid)
+    assert dev.n == host.n == len(items)
+    np.testing.assert_array_equal(np.asarray(dev.cols_dev),
+                                  np.asarray(host.cols_dev))
+    # the file is what a flush without the slab writes
+    # (write_sst_from_packed alone: the parent's path), byte for byte
+    plain, plain_fid = _flushed_db(str(tmp_path / "plain"), items)
+    assert plain_fid == fid
+    for name in (lambda p: p, data_file_name):
+        with open(name(db.versions.files[fid].path), "rb") as a, \
+                open(name(plain.versions.files[fid].path), "rb") as b:
+            assert a.read() == b.read()
+    assert {k: c.value() - before[k] for k, c in meters.items()} == \
+        {"native": 1, "python": 0}, "a flush with no device cache counted"
+    db.close()
+    plain.close()
+
+
+def test_flush_of_a_python_memtable_still_takes_the_job_s_columns(tmp_path):
+    """memtable_native off: to_packed() is the Python memtable's, the slab
+    still the job's; without the compaction engine the flush packs entry
+    by entry and says so."""
+    import sys
+    sys.path.insert(0, os.path.dirname(__file__))
+    from test_flush_slab import assert_slabs_equal
+    from yugabyte_tpu.storage import native_engine
+    from yugabyte_tpu.storage.db import flush_slab_metrics
+    from yugabyte_tpu.storage.memtable import MemTable
+    items = _flush_items(300, 80)
+    oracle = MemTable()
+    oracle.add_batch(items)
+    meters = flush_slab_metrics()
+    old = flags.get_flag("memtable_native")
+    flags.set_flag("memtable_native", False)
+    try:
+        before = {k: c.value() for k, c in meters.items()}
+        cache = _RecordingCache(device=_device())
+        db, fid = _flushed_db(str(tmp_path / "a"), items,
+                              device=_device(), device_cache=cache)
+        assert_slabs_equal(*cache.slabs.values(), oracle.to_slab())
+        assert {k: c.value() - before[k] for k, c in meters.items()} == \
+            {"native": 1, "python": 0}
+        db.close()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(native_engine, "available", lambda: False)
+            cache = _RecordingCache(device=_device())
+            db, fid = _flushed_db(str(tmp_path / "b"), items,
+                                  device=_device(), device_cache=cache)
+            assert_slabs_equal(*cache.slabs.values(), oracle.to_slab())
+            db.close()
+        assert {k: c.value() - before[k] for k, c in meters.items()} == \
+            {"native": 1, "python": 1}
+    finally:
+        flags.set_flag("memtable_native", old)

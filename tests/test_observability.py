@@ -306,6 +306,46 @@ def test_compaction_stats_versions_gcd(tmp_path):
     assert stats["write_amplification"] > 1.0
 
 
+FLUSH_STAGES = ("flush_pack", "flush_sst_write", "flush_slab_build",
+                "flush_device_stage", "flush_install")
+
+
+@pytest.mark.requires_native("compaction_engine")
+def test_a_flush_is_the_sum_of_its_five_stages(tmp_path):
+    """DB.flush's stages on the span rail (what /compactionz and the
+    benchmark's stage_ms_per_job show of a flush): all five move on a
+    flush with a device cache, and together they are no more than the
+    flush()'s wall (self times on one thread) and most of it."""
+    import jax
+    from yugabyte_tpu.storage.db import DB, DBOptions
+    from yugabyte_tpu.storage.device_cache import DeviceSlabCache
+    from yugabyte_tpu.utils.metrics import pipeline_stage_totals
+    dev = jax.devices()[0]
+    db = DB(str(tmp_path / "db"), DBOptions(
+        auto_compact=False, device=dev, device_cache=DeviceSlabCache(dev)))
+
+    def flush_rows(first):
+        db.write_batch([
+            (SubDocKey(DocKey(range_components=("row%06d" % i,)),
+                       (("col", 1),)).encode(include_ht=False),
+             DocHybridTime(HybridTime((1 + i) << 12), 0),
+             Value(primitive="v%039d" % i).encode())
+            for i in range(first, first + 4096)])
+        before = pipeline_stage_totals()
+        t0 = time.monotonic()
+        assert db.flush() is not None
+        wall_ms = (time.monotonic() - t0) * 1e3
+        after = pipeline_stage_totals()
+        return wall_ms, {k: after[k] - before[k] for k in after}
+
+    flush_rows(0)           # the staging program's compile, imports
+    wall_ms, moved = flush_rows(4096)
+    db.close()
+    assert all(moved[s] > 0 for s in FLUSH_STAGES), moved
+    total = sum(moved[s] for s in FLUSH_STAGES)
+    assert 0.5 * wall_ms <= total <= wall_ms, (wall_ms, moved)
+
+
 # ---------------------------------------------------------------------------
 # Live mini-cluster: endpoint smoke + /compactionz + kernel histograms
 # ---------------------------------------------------------------------------
@@ -383,6 +423,11 @@ def test_endpoint_smoke_and_compactionz(tmp_path):
         assert stages["stage_job_ms"] > 0
         assert "stage_job_other_ms" in stages
         assert "stage_version_install_ms" in stages
+        # a flush's stages and whose columns its device slab came from
+        assert stages["stage_flush_sst_write_ms"] > 0
+        assert "stage_flush_slab_build_ms" in stages
+        assert stages["flush_slab_native_total"] > 0
+        assert "flush_slab_python_total" in stages
 
         prom = _get(addr, "/prometheus-metrics").decode()
         errs = validate_prometheus_text(prom)

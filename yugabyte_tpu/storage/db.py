@@ -67,6 +67,25 @@ def _storage_metrics():
                         "batched point-read latency through DB.multi_get"))
 
 
+def flush_slab_metrics():
+    """Where the slab that a flush stages in the device cache came from,
+    in flushes: the native encoder's columns, or `pack_kvs` entry by entry
+    in Python (where the compaction engine did not build, or a Python
+    MemTable on an encrypted env): beside a compiler on default flags the
+    second stays 0."""
+    from yugabyte_tpu.utils.metrics import ROOT_REGISTRY
+    e = ROOT_REGISTRY.entity("server", "storage")
+    return {
+        "native": e.counter(
+            "flush_slab_native_total",
+            "flushes whose slab came from the native job's columns"),
+        "python": e.counter(
+            "flush_slab_python_total",
+            "flushes whose slab came from pack_kvs, an entry at a time "
+            "in Python"),
+    }
+
+
 class CompactionStats:
     """Per-DB compaction/flush accounting — the `/compactionz` analogue of
     RocksDB's GetProperty("rocksdb.stats") (ref: rocksdb/db/
@@ -1305,6 +1324,7 @@ class DB:
             imm = self._imm
             last_op = getattr(self, "_last_op_id", (0, 0))
         fid = path = None
+        from yugabyte_tpu.utils.metrics import pipeline_span
         try:
             if self.pre_flush_hook is not None:
                 self.pre_flush_hook()
@@ -1320,34 +1340,44 @@ class DB:
                 # parsing in C++ (the write-path hot loop, ref:
                 # db/flush_job.cc WriteLevel0Table), with run-cache
                 # write-through so the first compaction over this output
-                # skips read+decode. Device staging (below) still needs
-                # the slab form — a second memtable walk, much cheaper
-                # than the Python block encoder it replaces.
-                packed = imm.to_packed()
+                # skips read+decode, and the slab that device staging
+                # (below) needs handed back from the same job's columns
+                with pipeline_span("flush_pack"):
+                    packed = imm.to_packed()
                 frontier = Frontier(op_id_min=last_op, op_id_max=last_op,
                                     history_cutoff=0)
                 from yugabyte_tpu.storage.sst import write_sst_from_packed
-                props = write_sst_from_packed(
-                    path, *packed, frontier=frontier,
-                    block_entries=self.opts.block_entries,
-                    run_cache=self._run_cache, file_id=fid)
+                def take_slab(job):
+                    nonlocal slab
+                    with pipeline_span("flush_slab_build"):
+                        slab = job.export_slab()
+                    flush_slab_metrics()["native"].increment()
+                with pipeline_span("flush_sst_write"):
+                    props = write_sst_from_packed(
+                        path, *packed, frontier=frontier,
+                        block_entries=self.opts.block_entries,
+                        run_cache=self._run_cache, file_id=fid,
+                        on_job=take_slab
+                        if self._device_cache is not None else None)
                 n_flushed = len(packed[1]) - 1
-                if self._device_cache is not None:
-                    slab = imm.to_slab()
             else:
-                slab = imm.to_slab()
+                with pipeline_span("flush_slab_build"):
+                    slab = imm.to_slab()
+                flush_slab_metrics()[imm.slab_source].increment()
                 ht = slab.ht_hi.astype("u8") << 32 | slab.ht_lo
                 frontier = Frontier(op_id_min=last_op, op_id_max=last_op,
                                     ht_min=int(ht.min()) if slab.n else 0,
                                     ht_max=int(ht.max()) if slab.n else 0,
                                     history_cutoff=0)
-                props = SSTWriter(path, block_entries=self.opts.block_entries).write(slab, frontier)
+                with pipeline_span("flush_sst_write"):
+                    props = SSTWriter(path, block_entries=self.opts.block_entries).write(slab, frontier)
                 n_flushed = slab.n
             from yugabyte_tpu.utils import sync_point
             sync_point.hit("db.flush:before_manifest")
             if self._device_cache is not None and slab is not None:
-                self._device_cache.stage(fid, slab)  # write-through to HBM
-            with self._lock:
+                with pipeline_span("flush_device_stage"):
+                    self._device_cache.stage(fid, slab)  # write-through to HBM
+            with pipeline_span("flush_install"), self._lock:
                 self.versions.add_file(fid, path, props)
                 self.versions.set_flushed_frontier(frontier)
                 self._readers[fid] = SSTReader(path, self.opts.block_cache)

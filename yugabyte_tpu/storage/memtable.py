@@ -152,6 +152,10 @@ class MemTable:
             k = snap[i]
             yield k, self._data[k]
 
+    # how to_slab builds (DB.flush counts its slabs by this): entry by
+    # entry through `pack_kvs`
+    slab_source = "python"
+
     def to_slab(self) -> KVSlab:
         """Flush path: produce a sorted slab (ref: db/flush_job.cc)."""
         snap = self._sorted_snapshot()
@@ -484,16 +488,33 @@ class NativeMemTable:
             keys, koffs, ht, wid, vals, voffs = self._export(0, n, False)
         return keys.tobytes(), koffs, ht, wid, vals.tobytes(), voffs
 
+    @property
+    def slab_source(self) -> str:
+        """How to_slab builds: "native", from the native encoder's columns;
+        "python" (as the Python MemTable) where the compaction engine did
+        not build."""
+        from yugabyte_tpu.storage import native_engine
+        return "native" if native_engine.available() else "python"
+
     def to_slab(self) -> KVSlab:
-        with self._lock:
-            n = int(self._lib.mt_n(self._h))
-            keys, koffs, ht, wid, vals, voffs = self._export(0, n, False)
-        triples = []
-        for i in range(n):
-            packed = (int(ht[i]) << 32) | int(wid[i])
-            triples.append((keys[koffs[i]: koffs[i + 1]].tobytes(), packed,
-                            vals[voffs[i]: voffs[i + 1]].tobytes()))
-        return pack_kvs(triples)
+        """The arena as a slab (a scan's memtable source, built under the
+        DB lock; the flush of an encrypted env): the native encoder's
+        columns over to_packed()'s export, or `pack_kvs` entry by entry."""
+        packed = self.to_packed()
+        if self.slab_source == "native":
+            from yugabyte_tpu.storage import native_engine
+            return native_engine.slab_from_packed(*packed)
+        return pack_kvs(packed_triples(*packed))
+
+
+def packed_triples(keys_blob: bytes, key_offs, ht, wid, vals_blob: bytes,
+                   val_offs) -> List[Tuple[bytes, int, bytes]]:
+    """A packed run as `pack_kvs` takes it: (key_prefix, packed_doc_ht,
+    value) an entry. Python per entry: the fallback where the compaction
+    engine did not build, and the tests' oracle for the native slab."""
+    ko, vo = key_offs.tolist(), val_offs.tolist()
+    return [(keys_blob[ko[i]: ko[i + 1]], (int(ht[i]) << 32) | int(wid[i]),
+             vals_blob[vo[i]: vo[i + 1]]) for i in range(len(ko) - 1)]
 
 
 def new_memtable():

@@ -33,6 +33,7 @@ flags.define_flag("compaction_native_threads",
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _u8p = ctypes.POINTER(ctypes.c_uint8)
+_u32p = ctypes.POINTER(ctypes.c_uint32)
 _u64p = ctypes.POINTER(ctypes.c_uint64)
 
 
@@ -49,12 +50,18 @@ def _bind(lib) -> None:
     lib.ce_job_prepare.restype = ctypes.c_int64
     lib.ce_job_prepare.argtypes = [ctypes.c_void_p]
     lib.ce_job_add_raw.argtypes = [
-        ctypes.c_void_p, _u8p, _i64p, ctypes.c_int64, _u64p,
-        ctypes.POINTER(ctypes.c_uint32), _u8p, _i64p]
+        ctypes.c_void_p, _u8p, _i64p, ctypes.c_int64, _u64p, _u32p, _u8p,
+        _i64p]
     lib.ce_job_sort_all.restype = ctypes.c_int64
     lib.ce_job_sort_all.argtypes = [ctypes.c_void_p]
     lib.ce_job_props.argtypes = [ctypes.c_void_p, _u64p,
                                  _i32p]
+    lib.ce_job_stride.restype = ctypes.c_int32
+    lib.ce_job_stride.argtypes = [ctypes.c_void_p]
+    lib.ce_job_export_columns.restype = ctypes.c_int32
+    lib.ce_job_export_columns.argtypes = [
+        ctypes.c_void_p, _u32p, _i32p, _i32p, _u32p, _u32p, _u32p, _u32p,
+        _i64p, _i64p]
     lib.ce_job_merge.restype = ctypes.c_int64
     lib.ce_job_merge.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.c_int32, ctypes.c_int32]
@@ -158,6 +165,20 @@ def runcache_entry_bytes(run_id: int) -> int:
     return int(_load().ce_runcache_entry_bytes(ctypes.c_int64(run_id)))
 
 
+def slab_from_packed(keys_blob: bytes, key_offs, ht, wid, vals_blob: bytes,
+                     val_offs):
+    """The KVSlab of one packed run (a native memtable's export, a bulk
+    run), in internal-key order, from the native encoder's columns; the
+    empty run opens no job."""
+    if len(key_offs) - 1 == 0:
+        from yugabyte_tpu.ops.slabs import pack_kvs
+        return pack_kvs([])
+    with NativeCompactionJob() as job:
+        job.add_raw(keys_blob, key_offs, ht, wid, vals_blob, val_offs)
+        job.sort_all()
+        return job.export_slab()
+
+
 class NativeCompactionJob:
     """One compaction: add inputs -> prepare -> merge (or inject) -> write.
 
@@ -230,11 +251,12 @@ class NativeCompactionJob:
         wid = np.ascontiguousarray(wid, dtype=np.uint32)
         val_offs = np.ascontiguousarray(val_offs, dtype=np.int64)
         self._keepalive += [keys_blob, key_offs, ht, wid, vals_blob, val_offs]
+        self._raw_values = (vals_blob, val_offs)
         self._lib.ce_job_add_raw(
             self._job, ctypes.cast(ctypes.c_char_p(keys_blob), _u8p),
             key_offs.ctypes.data_as(_i64p), ctypes.c_int64(n),
             ht.ctypes.data_as(_u64p),
-            wid.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+            wid.ctypes.data_as(_u32p),
             ctypes.cast(ctypes.c_char_p(vals_blob), _u8p),
             val_offs.ctypes.data_as(_i64p))
         self.rows_in = n
@@ -253,6 +275,35 @@ class NativeCompactionJob:
         self._lib.ce_job_props(self._job, ctypes.byref(mx),
                                ctypes.byref(deep))
         return int(mx.value), bool(deep.value)
+
+    def export_slab(self):
+        """The KVSlab of the run given to add_raw, in the order sort_all
+        left it (a flush: every row kept as written): the columns are the
+        ones add_raw derived (doc_key_len, flags, ttl_ms: the encoder's
+        own, so slab and file cannot disagree), copied out in one native
+        pass; no entry is visited in Python. The values are add_raw's
+        blob, adopted as it lies, and gathered only where sort_all
+        re-ordered the run. Equal to `pack_kvs` over the same entries in
+        every column (tests/test_flush_slab.py)."""
+        from yugabyte_tpu.ops.slabs import KVSlab, ValueArray
+        n = self.n_survivors
+        w = int(self._lib.ce_job_stride(self._job)) // 4
+        key_words = np.empty((n, w), dtype=np.uint32)
+        key_len, dkl = (np.empty(n, dtype=np.int32) for _ in range(2))
+        ht_hi, ht_lo, wid, flags = (np.empty(n, dtype=np.uint32)
+                                    for _ in range(4))
+        ttl_ms, perm = (np.empty(n, dtype=np.int64) for _ in range(2))
+        reordered = int(self._lib.ce_job_export_columns(
+            self._job, key_words.ctypes.data_as(_u32p),
+            key_len.ctypes.data_as(_i32p), dkl.ctypes.data_as(_i32p),
+            ht_hi.ctypes.data_as(_u32p), ht_lo.ctypes.data_as(_u32p),
+            wid.ctypes.data_as(_u32p), flags.ctypes.data_as(_u32p),
+            ttl_ms.ctypes.data_as(_i64p), perm.ctypes.data_as(_i64p)))
+        values = ValueArray.from_blob(*self._raw_values)
+        if reordered:
+            values = values.gather(perm)
+        return KVSlab(key_words, key_len, dkl, ht_hi, ht_lo, wid, flags,
+                      ttl_ms, np.arange(n, dtype=np.int32), values)
 
     def merge(self, cutoff_ht: int, is_major: bool,
               retain_deletes: bool = False) -> int:

@@ -230,13 +230,16 @@ def write_sst_from_packed(base_path: str, keys_blob: bytes, key_offs,
                           compress: Optional[bool] = None,
                           presorted_hint: bool = True,
                           run_cache=None,
-                          file_id: Optional[int] = None) -> SSTProps:
+                          file_id: Optional[int] = None,
+                          on_job=None) -> SSTProps:
     """Native-encoded SST from one packed run (the flush / bulk-load hot
     path, ref: db/flush_job.cc WriteLevel0Table + memtable.cc iteration).
     Block encode, bloom hashing and doc-key parsing run in C++
     (ce_job_add_raw → ce_job_sort_all → ce_job_write_output); Python
     assembles the base file as usual. Caller guarantees native_engine is
-    available."""
+    available. `on_job(job)` is called with the job still open, once the
+    file is written and the run cache fed, for what else the caller wants
+    of the same add_raw (a flush takes its device slab: job.export_slab)."""
     import numpy as np
     from yugabyte_tpu.storage import native_engine
     if block_entries is None:
@@ -259,6 +262,8 @@ def write_sst_from_packed(base_path: str, keys_blob: bytes, key_offs,
             rid = job.export_run(0, n, b"X")
             run_cache.put(file_id, rid,
                           native_engine.runcache_entry_bytes(rid))
+        if on_job is not None:
+            on_job(job)
     ht_arr = np.asarray(ht, dtype=np.uint64)
     fr = frontier or Frontier()
     if n and fr.ht_min == 0 and fr.ht_max == 0:

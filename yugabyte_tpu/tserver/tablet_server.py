@@ -230,6 +230,11 @@ class TabletServer:
             "kernel_compile_bucket_misses_total",
             "first launches of a shape bucket (compile or persistent-"
             "cache load)").value()
+        # whose columns a flush's device slab came from: the native
+        # encoder's, or pack_kvs entry by entry (0 beside a compiler)
+        from yugabyte_tpu.storage.db import flush_slab_metrics
+        for source, counter in flush_slab_metrics().items():
+            pipeline[f"flush_slab_{source}_total"] = counter.value()
         # device block codec (ops/block_codec.py): blocks decoded/encoded
         # on device vs jobs that wrote through the native shell encode
         from yugabyte_tpu.ops.block_codec import codec_metrics

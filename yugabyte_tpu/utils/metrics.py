@@ -444,6 +444,11 @@ def publish_compile_surface(counts: Dict[str, int]) -> None:
 # `pool_sched_wait` (the scheduler waiting for a picked job's owner to
 # end its staging) are the pool's one scheduler thread, and with the job
 # stages opened under them sum to its busy wall.
+# The `flush_*` names are DB.flush's five steps, self times on the thread
+# that flushes, outside any job: the memtable's packed export, the native
+# encode + file write + run-cache export + index fit (the Python writer on
+# an encrypted env), the slab for the device cache, its upload, and the
+# install under the DB lock; together a flush()'s wall.
 _PIPELINE_STAGES = (
     "host", "device", "write", "shadow", "decode", "encode",
     "job", "job_other",
@@ -458,7 +463,9 @@ _PIPELINE_STAGES = (
     "native_ingest", "native_merge",
     "version_install", "reader_open", "input_delete",
     "pool_stage", "pool_wave", "pool_finish", "pool_exclusive",
-    "pool_native", "pool_sched_wait")
+    "pool_native", "pool_sched_wait",
+    "flush_pack", "flush_sst_write", "flush_slab_build",
+    "flush_device_stage", "flush_install")
 
 _stage_metrics: Dict[str, Tuple[Histogram, Gauge]] = {}
 
