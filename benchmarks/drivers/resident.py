@@ -17,11 +17,18 @@ there.
 `failed` counts events: a native routing decision, a `ZERO_COUNTERS`
 movement, or a job during which a decode meter moved (an input missing from
 the slab cache, a raw read, parse or decode stage entered, or a block
-decoded). One thing that decodes blocks is NOT the job's: the program's
-shadow verifier (`shadow_verify_sample`, 2% of device jobs on default
-flags) re-reads a sampled job's inputs for its native oracle, on a thread
-of its own. A job it sampled is judged by the other meters alone, and the
-tally says how many it sampled.
+decoded). Two samplers of the program decode blocks that are NOT the job's
+own reading:
+- the shadow verifier (`shadow_verify_sample`, 2% of device jobs on default
+  flags) re-reads a sampled job's inputs for its native oracle, on a thread
+  of its own: a job it sampled is judged by the other meters alone;
+- the write-through digest check (`resident_digest_sample`, 2% of cache
+  installs) re-reads an output file the job just wrote, every data block of
+  it: a job it checked is let off that many blocks, the data blocks of its
+  largest outputs, one file a check, and nothing else. A decode beyond them,
+  and every other meter, counts as before. A digest MISMATCH fails the job:
+  the program found a write-through entry that diverged from its file.
+The tally says how many jobs each sampled, and how many blocks were let off.
 """
 
 import os
@@ -165,6 +172,9 @@ class Driver:
                         f"compaction parked the DB: {db.background_error}")
             outs = [(fm.file_id, fm.path, None)
                     for fm in db.versions.live_files()]
+            # read after the meters above, so that reading cannot move them
+            digest_checked = moved.pop("resident_digest_checked_total", 0)
+            exempted = _digest_exemption(moved, digest_checked, outs)
             with ctx.span("chain_close"):
                 db.close()
             now = time.monotonic()
@@ -172,6 +182,8 @@ class Driver:
                          "flush_s": flush_s, "chain_s": now - t_chain,
                          "inputs_resident": resident,
                          "shadow_sampled": sampled,
+                         "digest_checked": digest_checked,
+                         "digest_blocks_exempted": exempted,
                          "decode_meters_moved": moved})
             tracer.note(bench_rows_in=self.expect["rows_in"], bench_jobs=1,
                         bench_jobs_wall_ms=job_s * 1e3,
@@ -186,19 +198,25 @@ class Driver:
     def _decode_meters(self) -> dict:
         """What moves when a job reads an input it should have found
         resident: slab-cache misses, the pipeline's raw-read, parse and
-        decode stages, SST blocks decoded; and whether the shadow verifier
-        sampled the job (module docstring)."""
+        decode stages, SST blocks decoded; whether the shadow verifier
+        sampled the job, and the digest check's checks and mismatches
+        (module docstring)."""
         from yugabyte_tpu.storage import integrity
         from yugabyte_tpu.storage.sst import _block_decode_counter
         from yugabyte_tpu.utils.metrics import pipeline_stage_totals
         stages = pipeline_stage_totals()
-        shadow = integrity.integrity_metrics()
+        integ = integrity.integrity_metrics()
         out = {"sst_block_decode_total": _block_decode_counter().value(),
                "slab_cache_misses": self.device_cache.misses,
                "shadow_verifier_sampled":
-                   shadow.counter("shadow_verify_jobs_total", "").value()
-                   + shadow.counter("shadow_verify_skipped_total",
-                                    "").value()}
+                   integ.counter("shadow_verify_jobs_total", "").value()
+                   + integ.counter("shadow_verify_skipped_total",
+                                   "").value(),
+               "resident_digest_checked_total":
+                   integ.counter("resident_digest_checked_total",
+                                 "").value(),
+               "resident_digest_mismatch_total":
+                   integrity.resident_digest_mismatch_counter().value()}
         for s in DECODE_STAGES:
             out[f"stage_{s}_ms"] = stages.get(s, 0.0)
         return out
@@ -231,6 +249,10 @@ class Driver:
             "jobs_that_left_the_resident_path": left_resident,
             "jobs_the_shadow_verifier_sampled": sum(
                 j["shadow_sampled"] for j in jobs),
+            "jobs_the_digest_check_sampled": sum(
+                1 for j in jobs if j["digest_checked"]),
+            "digest_blocks_exempted": sum(
+                j["digest_blocks_exempted"] for j in jobs),
             "decode_meters_moved": [j["decode_meters_moved"] for j in jobs
                                     if j["decode_meters_moved"]][:4],
             "pallas_merges": counters["kernel_pallas_merge_total"],
@@ -264,6 +286,28 @@ class Driver:
     def close(self) -> None:
         if self.native_db is not None:
             self.native_db.close()
+
+
+def _digest_exemption(moved: dict, checked: int, outs: list) -> int:
+    """Takes the blocks that `checked` digest checks decoded off `moved`'s
+    `sst_block_decode_total`: at most the data blocks of the job's
+    `checked` largest output files (each check reads one file whole, with
+    no block cache). Returns the blocks taken off."""
+    from yugabyte_tpu.storage import SSTReader
+    decoded = moved.get("sst_block_decode_total", 0)
+    if not checked or not decoded:
+        return 0
+    blocks = []
+    for _fid, path, _ in outs:
+        reader = SSTReader(path)
+        blocks.append(reader.n_blocks)
+        reader.close()
+    exempted = min(decoded, sum(sorted(blocks, reverse=True)[:checked]))
+    if decoded == exempted:
+        del moved["sst_block_decode_total"]
+    else:
+        moved["sst_block_decode_total"] = decoded - exempted
+    return exempted
 
 
 def _as_batch(run: dict):

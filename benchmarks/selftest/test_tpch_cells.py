@@ -65,11 +65,41 @@ def test_the_resident_cells_control_is_not_correct(capsys):
         "value"] == 0
 
 
+@pytest.fixture
+def digest_sample(request):
+    """`resident_digest_sample` at the test's parameter (1.0, every
+    write-through install digest-checked, unless parametrised; None leaves
+    the default), restored after."""
+    from yugabyte_tpu.storage import integrity  # noqa: F401 (defines the flag)
+    from yugabyte_tpu.utils import flags
+    old = flags.get_flag("resident_digest_sample")
+    value = getattr(request, "param", 1.0)
+    if value is not None:
+        flags.set_flag("resident_digest_sample", value)
+    yield
+    flags.set_flag("resident_digest_sample", old)
+
+
+def test_a_digest_checked_job_is_let_off_the_blocks_its_check_decodes(
+        capsys, digest_sample):
+    """Every job's outputs digest-checked: each check decodes its file's
+    blocks, and none of those makes the job a failed one."""
+    line, lines = rehearse(capsys, RESIDENT, 2**31 + 121, 1)
+    assert line["correct"] and line["failed"] == 0
+    tally = next(l for l in lines if "window_jobs" in l)
+    assert tally["jobs_the_digest_check_sampled"] == tally["window_jobs"]
+    assert tally["digest_blocks_exempted"] > 0
+    assert tally["jobs_that_left_the_resident_path"] == 0
+
+
+@pytest.mark.parametrize("digest_sample", [None, 1.0], indirect=True,
+                         ids=["default_digest_sample", "every_job_checked"])
 def test_fault_a_flushs_write_through_dropped_is_a_failed_job(
-        capsys, monkeypatch):
+        capsys, monkeypatch, digest_sample):
     """One flush of the window does not reach the slab cache: its job finds
     an input missing and ingests it from the file. The answer is still
-    right; the job left the resident path, and is counted."""
+    right; the job left the resident path, and is counted, also where the
+    digest check's own blocks are let off."""
     from yugabyte_tpu.storage.device_cache import DeviceSlabCache
     real = DeviceSlabCache.stage
     calls = []
@@ -86,6 +116,34 @@ def test_fault_a_flushs_write_through_dropped_is_a_failed_job(
     assert line["failed"] >= 1
     tally = next(l for l in lines if "window_jobs" in l)
     assert tally["jobs_that_left_the_resident_path"] >= 1
+    assert any(m.get("slab_cache_misses")
+               for m in tally["decode_meters_moved"])
+
+
+def test_fault_a_digest_mismatch_is_a_failed_job(capsys, monkeypatch,
+                                                 digest_sample):
+    """One digest check of the window finds the write-through entry
+    diverged from its file: the program drops the entry, the files on disk
+    are right, and the job is counted under `failed`."""
+    from yugabyte_tpu.storage import integrity
+    real = integrity.verify_resident_entry
+    calls = []
+
+    def diverged(staged, base_path):
+        calls.append(base_path)
+        errors = real(staged, base_path)     # the check's decode is made
+        if len(calls) == 8:                  # past the warm-up chains
+            return ["planted divergence"]
+        return errors
+
+    monkeypatch.setattr(integrity, "verify_resident_entry", diverged)
+    line, lines = rehearse(capsys, RESIDENT, 53, 2)
+    assert len(calls) > 8 and line["correct"]
+    assert all(c["value"] == 0 for c in line["compared"].values())
+    assert line["failed"] >= 1
+    tally = next(l for l in lines if "window_jobs" in l)
+    assert any(m.get("resident_digest_mismatch_total") == 1
+               for m in tally["decode_meters_moved"])
 
 
 def test_tpch_is_correct_and_every_tablet_answers_from_the_device(capsys):
